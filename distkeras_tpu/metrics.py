@@ -93,8 +93,12 @@ def annotate(name: str):
 
 # -- analytic FLOPs + MFU ----------------------------------------------------
 
-# bf16 peak FLOP/s per chip by device_kind substring (public spec sheets);
-# first match wins, so more specific entries come first
+# bf16 peak FLOP/s per chip by ``device_kind`` substring; first match wins,
+# so more specific entries come first.  Source: Google Cloud TPU
+# documentation, the per-generation system-architecture pages ("TPU v5e":
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s; likewise "TPU
+# v6e", "TPU v5p", "TPU v4", "TPU v3", "TPU v2"; v4i from Jouppi et al.
+# 2021, "Ten Lessons From Three Generations...").
 _PEAK_FLOPS = (
     ("TPU v6 lite", 918e12),   # Trillium
     ("TPU v5 lite", 197e12),   # v5e
@@ -107,13 +111,16 @@ _PEAK_FLOPS = (
 )
 
 
-def peak_flops(device_kind: str) -> Optional[float]:
-    """bf16 peak FLOP/s for a ``jax.devices()[0].device_kind`` string, or
-    None when unknown (CPU, new hardware) — callers emit mfu=null then."""
+def peak_flops(device_kind: str) -> float:
+    """bf16 peak FLOP/s for a ``jax.devices()[0].device_kind`` string.  A
+    device that is not in the table is an error, not a default: a
+    utilization against an unknown peak is no number at all."""
     for key, val in _PEAK_FLOPS:
         if key.lower() in str(device_kind).lower():
             return val
-    return None
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {device_kind!r}; add it to "
+        "distkeras_tpu.metrics._PEAK_FLOPS with its source")
 
 
 def _attention_flops(layer, in_shape) -> float:

@@ -10,7 +10,8 @@ into the traced program, so its ops do):
  - ``out_struct``: pallas_call output avals must declare their vma (a
    kernel output varies exactly as its inputs do);
  - ``match_vma``: kernel-internal constants (iota position grids, masks)
-   are unvarying and must be ``pvary``'d before meeting varying refs.
+   are unvarying and must be ``pcast`` to varying before meeting varying
+   refs.
 
 Both are no-ops outside shard_map and in compiled kernels.
 """
@@ -21,16 +22,9 @@ import jax
 
 _EMPTY = frozenset()
 
-# jax < 0.6 has neither ``jax.typeof`` nor vma tracking in shard_map
-# (check_vma arrived with the vma-typed shard_map) — there is nothing to
-# plumb, so every value reads as unvarying and both helpers no-op.
-_typeof = getattr(jax, "typeof", None)
-
 
 def _vma_of(x):
-    if _typeof is None:
-        return _EMPTY
-    return getattr(_typeof(x), "vma", None) or _EMPTY
+    return getattr(jax.typeof(x), "vma", None) or _EMPTY
 
 
 def out_struct(shape, dtype, like):
@@ -45,4 +39,4 @@ def match_vma(x, like):
     """Lift ``x`` (typically an iota/mask built in-kernel) to ``like``'s
     varying axes so elementwise ops between them type-check."""
     missing = tuple(a for a in _vma_of(like) if a not in _vma_of(x))
-    return jax.lax.pvary(x, missing) if missing else x
+    return jax.lax.pcast(x, missing, to="varying") if missing else x

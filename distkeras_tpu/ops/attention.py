@@ -268,8 +268,8 @@ def _pallas_eligible(q, k) -> bool:
     block-tileable sequence: a multiple of the 128-lane block, or a single
     block whose rows satisfy the strictest (bf16: 16) sublane tile.
     head_dim is unconstrained — the kernel's blocks span the whole (d) dim,
-    which TPU tiling always allows (d=64 exercised by the hardware smoke
-    test, tests/test_tpu_smoke.py)."""
+    which TPU tiling always allows (d=64 exercised on the chip by
+    ``chip_smoke.py``, phase ``kernels``)."""
     if jax.default_backend() != "tpu":
         return False
     if q.shape[1] != k.shape[1]:

@@ -33,8 +33,10 @@ MXU:
 On non-TPU backends the kernels run in Pallas interpret mode (tests); the
 ``ops.attention.attention`` dispatcher only routes here on TPU.  The XLA
 reference (``ops.attention.dot_product_attention``) stays the correctness
-oracle — gradient parity is asserted in tests/test_flash_attention.py, and
-``tests/test_tpu_smoke.py`` checks the compiled kernels on real hardware.
+oracle — gradient parity is asserted in tests/test_flash_attention.py
+(interpret mode); tests/test_chip_compile.py compiles the kernels for a
+described v5e, and ``chip_smoke.py`` checks them against the oracle on
+the chip.
 """
 
 from __future__ import annotations

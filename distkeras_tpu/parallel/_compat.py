@@ -1,59 +1,15 @@
-"""jax version compatibility for the parallel stack.
-
-``shard_map`` was promoted from ``jax.experimental.shard_map`` to a
-top-level ``jax.shard_map`` around jax 0.6; every call site here uses the
-keyword form (``mesh=``/``in_specs=``/``out_specs=``), which both accept.
-Resolve once so the modules under ``parallel/`` run on either.
+"""The vma-typed ``shard_map`` surface the parallel stack is written
+against (jax >= 0.9, ``setup.py``), resolved in one place: every call site
+uses the keyword form (``mesh=``/``in_specs=``/``out_specs=``).
 """
 
 import jax
 
-if hasattr(jax, "typeof"):  # the vma-typed shard_map generation
-    shard_map = jax.shard_map
-    pcast = jax.lax.pcast
-    axis_size = jax.lax.axis_size
+shard_map = jax.shard_map
+pcast = jax.lax.pcast
+axis_size = jax.lax.axis_size
 
-    def vma_of(x):
-        """Mesh axes ``x`` varies over (empty tuple when untyped)."""
-        return getattr(jax.typeof(x), "vma", ()) or ()
-else:  # jax < 0.6: no vma typing — every value is implicitly varying,
-    # pcast has nothing to record, and shard_map lives in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, *, mesh, in_specs, out_specs):
-        # Replication checking stays ON by default: check_rep=False also
-        # disables the psum-aware transpose, which CHANGES gradients of
-        # pure-jnp bodies (ZeRO/FSDP paths regress).  But the old checker
-        # has no rule for pallas_call — bodies with Pallas kernels (the
-        # vma plumbing in ops/_vma.py is how the NEW checker passes them)
-        # raise NotImplementedError at trace time, and only those fall
-        # back to the unchecked form.
-        checked = _shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-        unchecked = _shard_map(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-
-        def call(*args):
-            try:
-                return checked(*args)
-            except NotImplementedError:
-                # no replication rule for pallas_call in the old checker
-                return unchecked(*args)
-            except ValueError as e:
-                if "check_rep=False" not in str(e):
-                    raise
-                # out_specs replication the old checker can't infer
-                return unchecked(*args)
-
-        return call
-
-    def pcast(x, axis_name, *, to="varying"):
-        return x
-
-    def vma_of(x):
-        return ()
-
-    def axis_size(axis_name):
-        # psum of a unit constant constant-folds to the bound axis size
-        # (a Python int, so shape math downstream stays static)
-        return jax.lax.psum(1, axis_name)
+def vma_of(x):
+    """Mesh axes ``x`` varies over (empty tuple when untyped)."""
+    return getattr(jax.typeof(x), "vma", ()) or ()

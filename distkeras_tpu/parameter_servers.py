@@ -2110,6 +2110,13 @@ def _run_process_elastic(trainer, x, y, n: int, blob: dict, kw: dict,
     return fitted
 
 
+class ChipHeldByDriver(RuntimeError):
+    """``execution='process_ps'`` asked for same-host worker processes from
+    a driver whose jax backend is a TPU.  A chip belongs to one process at
+    a time and the driver already holds this host's: every worker would
+    fail or hang at its first jit.  Raised before anything is launched."""
+
+
 def run_process_ps_training(trainer, dataset, shuffle: bool = False
                             ) -> FittedModel:
     """Execute a DistributedTrainer with workers as separate OS PROCESSES.
@@ -2133,6 +2140,13 @@ def run_process_ps_training(trainer, dataset, shuffle: bool = False
     path and a PS bound on a routable interface — same-host processes are
     what this function wires up today.  Checkpoint/resume stays on the
     in-process engines.
+
+    One process per chip: a driver whose own backend is a TPU already holds
+    this host's chips, so same-host workers could never get one — that
+    combination raises :class:`ChipHeldByDriver` before anything launches
+    (``_run_process_elastic`` is reached only through here, and
+    ``ps_shard_main`` imports jax but never initialises a backend: its
+    center and apply rule are NumPy).
     """
     import json
     import tempfile
@@ -2140,6 +2154,18 @@ def run_process_ps_training(trainer, dataset, shuffle: bool = False
     from .job_deployment import Job, LocalJobRunner
     from .ps_worker_main import save_model_blob
 
+    if jax.default_backend() == "tpu":
+        # the trainer's mesh already initialised the backend in THIS
+        # process; LocalJobRunner starts the workers on this same host
+        raise ChipHeldByDriver(
+            "execution='process_ps' starts its workers as processes on "
+            "this host, but this driver process has initialised the TPU "
+            "backend and holds the chip(s): the workers could never get a "
+            "device.  process_ps is the one-process-per-host topology — "
+            "run the driver with JAX_PLATFORMS=cpu (it only hosts the "
+            "parameter server) and one worker process per TPU host "
+            "(docs/DEPLOY.md), or use execution='host_ps' (worker threads "
+            "inside the process that holds the chip)")
     algorithm = trainer.ALGORITHM
     if algorithm not in WORKER_CLASSES:
         raise ValueError(

@@ -62,8 +62,6 @@ def main(argv=None) -> int:
         print("usage: python -m distkeras_tpu.ps_shard_main <config.json> "
               "[shard_id]", file=sys.stderr)
         return 2
-    from .utils import honor_platform_env
-    honor_platform_env()
 
     with open(argv[1]) as f:
         cfg = json.load(f)

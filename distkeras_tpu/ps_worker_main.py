@@ -44,8 +44,6 @@ def main(argv=None) -> int:
         print("usage: python -m distkeras_tpu.ps_worker_main <config.json> "
               "[worker_id]", file=sys.stderr)
         return 2
-    from .utils import honor_platform_env
-    honor_platform_env()
     from .job_deployment import initialize_from_env
     initialize_from_env()
 

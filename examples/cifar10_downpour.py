@@ -23,8 +23,6 @@ from distkeras_tpu.models.zoo import cifar10_convnet
 
 
 def main():
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()  # JAX_PLATFORMS=cpu simulation support
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=16384)
     ap.add_argument("--test-rows", type=int, default=2048)

@@ -38,8 +38,6 @@ def evaluate(fitted, test):
 
 
 def main():
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()  # JAX_PLATFORMS=cpu simulation support
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", type=int, default=65536)
     ap.add_argument("--test-rows", type=int, default=8192)

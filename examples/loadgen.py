@@ -739,9 +739,6 @@ def build_spec_engine(num_slots: int = 4, max_len: int = 32,
 
 
 def main():
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()
-
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--slots", type=int, default=4)

@@ -35,9 +35,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def main():
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()
-
     import jax
     import numpy as np
 

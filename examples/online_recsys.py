@@ -65,8 +65,6 @@ def make_stream(vocab, seq_len, chunks, rows, drift_at, seed):
 
 
 def main():
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()  # JAX_PLATFORMS=cpu simulation support
     ap = argparse.ArgumentParser()
     ap.add_argument("--vocab", type=int, default=16)
     ap.add_argument("--seq-len", type=int, default=8)

@@ -61,8 +61,6 @@ def make_stream(vocab, classes, chunks, rows, drift_at, drift_frac, seed):
 
 
 def main():
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()  # JAX_PLATFORMS=cpu simulation support
     ap = argparse.ArgumentParser()
     ap.add_argument("--vocab", type=int, default=50000)
     ap.add_argument("--dim", type=int, default=32)

@@ -22,9 +22,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def main():
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()  # JAX_PLATFORMS=cpu simulation support
-
     import jax
     import jax.numpy as jnp
     import numpy as np

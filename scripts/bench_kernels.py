@@ -29,10 +29,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from distkeras_tpu.utils import honor_platform_env
-
-honor_platform_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -24,10 +24,6 @@ sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.abspath(
         globals().get("__file__", "scripts/x"))), ".."))
 
-from distkeras_tpu.utils import honor_platform_env  # noqa: E402
-
-honor_platform_env()
-
 
 def emit(metric, value, unit, **extra):
     line = {"metric": metric, "value": round(float(value), 2), "unit": unit}

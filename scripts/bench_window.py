@@ -40,10 +40,6 @@ if os.environ.get("DISTKERAS_WINDOW_PLATFORM", "cpu8") == "cpu8":
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from distkeras_tpu.utils import honor_platform_env  # noqa: E402
-
-honor_platform_env()
-
 
 def _median(ts):
     import numpy as np

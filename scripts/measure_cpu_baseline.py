@@ -25,10 +25,6 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from distkeras_tpu.utils import honor_platform_env
-
-honor_platform_env()
-
 import jax
 
 from distkeras_tpu.core.train import init_state, make_train_step

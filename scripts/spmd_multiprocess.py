@@ -59,8 +59,6 @@ def main():
         f"--xla_force_host_platform_device_count={per}")
 
     sys.path.insert(0, _REPO)
-    from distkeras_tpu.utils import honor_platform_env
-    honor_platform_env()
     from distkeras_tpu.job_deployment import initialize_from_env
     initialize_from_env()  # joins the jax.distributed group (no-op solo)
 

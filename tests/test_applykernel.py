@@ -7,17 +7,16 @@ reproduces ``np.add.at``'s sequential array-order accumulation.  Fuzzed
 over dense/bf16/int8/SparseDelta apply paths and over BOTH buffer
 alignments (numpy-aligned arrays and byte-offset unaligned views).
 
-Mirrors the wirecodec test guard: builds the extension in place when a
-toolchain exists, skips gracefully otherwise.  The fallback smoke test is
-tier-1 safe — it monkeypatches the native module away and proves the
-numpy path serves every apply.
+Mirrors the wirecodec test guard: builds the extension in place (the
+shared, locked ``tests/native_build.py``) when a toolchain exists, skips
+otherwise.  The fallback smoke test is tier-1 safe — it monkeypatches the
+native module away and proves the numpy path serves every apply.
 """
-
-import subprocess
-import sys
 
 import numpy as np
 import pytest
+
+from native_build import ensure_built
 
 from distkeras_tpu import applykernel, networking
 from distkeras_tpu.networking import SparseDelta
@@ -28,16 +27,13 @@ from distkeras_tpu.parameter_servers import (ADAGParameterServer,
 
 
 def _ensure_native():
-    if applykernel._native is not None:
-        return applykernel._native
-    r = subprocess.run(
-        [sys.executable, "setup.py", "build_ext", "--inplace"],
-        cwd=applykernel.__file__.rsplit("/", 2)[0], capture_output=True)
-    if r.returncode != 0:
-        pytest.skip(f"no native toolchain: {r.stderr[-200:]}")
-    import distkeras_tpu._applykernel as native
-    applykernel._native = native
-    return native
+    if applykernel._native is None:
+        error = ensure_built()
+        if error is not None:
+            pytest.skip(f"no native toolchain: {error}")
+        import distkeras_tpu._applykernel as native
+        applykernel._native = native
+    return applykernel._native
 
 
 @pytest.fixture()

@@ -1,0 +1,162 @@
+"""The main path's Pallas kernels compile for the chip, without the chip.
+
+libtpu's compiler is installed in the CPU sandbox and compiles for a
+DESCRIBED ``v5e:2x2`` topology (``jax.experimental.topologies``): nothing
+runs, but Mosaic refuses here exactly what it would refuse on the device —
+misaligned slices, too much VMEM, a kernel that cannot be partitioned —
+which interpret mode (every other kernel test in this suite) cannot see.
+Shapes are the real widths ``chip_smoke.py`` runs.
+
+Everything that touches the topology lives in the module-scoped fixtures
+below (never at import time: under pytest-xdist every worker imports this
+file, but only the one that RUNS it may load libtpu), and the kernels are
+compiled in this process with ``interpret=False`` passed by the test — the
+``interpret=None`` rule asks ``jax.default_backend()``, which is the CPU
+here.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from distkeras_tpu.ops.flash_attention import flash_attention
+from distkeras_tpu.ops.fused_ce import fused_softmax_cross_entropy
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu, or it is held by another process
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable can be written to the persistent
+    # cache but never read back; keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *structs):
+    return jax.jit(fn).lower(*structs).compile().as_text()
+
+
+# -- flash attention ---------------------------------------------------------
+
+FLASH_SHAPES = [  # (B, S, H, Dh), dtype
+    ((2, 2048, 16, 128), jnp.bfloat16),
+    ((2, 2048, 16, 64), jnp.bfloat16),
+    ((1, 8192, 16, 128), jnp.bfloat16),
+    ((2, 1024, 8, 64), jnp.float32),
+]
+
+
+def _flash(window, q, k, v):
+    return flash_attention(q, k, v, True, None, 128, 128, False, window)
+
+
+def _flash_grads(q, k, v):
+    return jax.grad(lambda *a: _flash(None, *a).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("mode", ["fwd", "bwd", "window512"])
+@pytest.mark.parametrize("shape,dtype", FLASH_SHAPES,
+                         ids=lambda x: "x".join(map(str, x))
+                         if isinstance(x, tuple) else np.dtype(x).name)
+def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype, mode):
+    qkv = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)] * 3
+    fn = {"fwd": functools.partial(_flash, None),
+          "bwd": _flash_grads,
+          "window512": functools.partial(_flash, 512)}[mode]
+    text = _compiled_text(fn, *qkv)
+    # forward is one kernel; backward is dq + dkv (+ the recomputed fwd)
+    assert text.count(KERNEL) >= (2 if mode == "bwd" else 1)
+
+
+# -- fused cross-entropy -----------------------------------------------------
+
+CE_SHAPES = [  # (T, V), dtype
+    ((8192, 50304), jnp.bfloat16),
+    ((8192, 32000), jnp.bfloat16),
+    ((1000, 50257), jnp.bfloat16),   # ragged rows AND ragged vocab
+    ((4096, 50257), jnp.float32),
+]
+
+
+def _ce(logits, labels):
+    return fused_softmax_cross_entropy(logits, labels, interpret=False)
+
+
+def _ce_grad(logits, labels):
+    return jax.grad(lambda lg: _ce(lg, labels).sum())(logits)
+
+
+@pytest.mark.parametrize("mode", ["fwd", "bwd"])
+@pytest.mark.parametrize("shape,dtype", CE_SHAPES,
+                         ids=lambda x: "x".join(map(str, x))
+                         if isinstance(x, tuple) else np.dtype(x).name)
+def test_fused_ce_compiles_for_v5e(one_chip, shape, dtype, mode):
+    logits = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    labels = jax.ShapeDtypeStruct(shape[:1], jnp.int32, sharding=one_chip)
+    text = _compiled_text(_ce if mode == "fwd" else _ce_grad, logits,
+                          labels)
+    assert KERNEL in text
+
+
+# -- kernels inside shard_map on the 2x2 mesh --------------------------------
+
+@pytest.fixture(scope="module")
+def mesh2x2(topo):
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+
+def test_flash_inside_shard_map_compiles_for_2x2(mesh2x2):
+    """Batch over 'data', heads over 'model': the kernel's outputs must
+    declare their varying mesh axes (ops/_vma.out_struct) or the compiled
+    — not interpreted — pallas_call is refused by shard_map's checker."""
+    spec = P("data", None, "model", None)
+    sh = NamedSharding(mesh2x2, spec)
+    qkv = [jax.ShapeDtypeStruct((4, 1024, 12, 64), jnp.bfloat16,
+                                sharding=sh)] * 3
+    fn = jax.shard_map(_flash_grads, mesh=mesh2x2, in_specs=(spec,) * 3,
+                       out_specs=(spec,) * 3)
+    assert _compiled_text(fn, *qkv).count(KERNEL) >= 2
+
+
+def test_fused_ce_inside_shard_map_compiles_for_2x2(mesh2x2):
+    """Tokens over both axes; loss psum'd like ParallelTransformerLM."""
+    sh = lambda *s: NamedSharding(mesh2x2, P(*s))
+    axes = ("data", "model")
+
+    def local(logits, labels):
+        loss, g = jax.value_and_grad(
+            lambda lg: _ce(lg, labels).sum())(logits)
+        return jax.lax.psum(loss, axes), g
+
+    fn = jax.shard_map(local, mesh=mesh2x2,
+                       in_specs=(P(axes, None), P(axes)),
+                       out_specs=(P(), P(axes, None)))
+    text = _compiled_text(
+        fn,
+        jax.ShapeDtypeStruct((8192, 50257), jnp.float32,
+                             sharding=sh(axes, None)),
+        jax.ShapeDtypeStruct((8192,), jnp.int32, sharding=sh(axes)))
+    assert text.count(KERNEL) >= 2 and "all-reduce" in text

@@ -67,3 +67,24 @@ def test_process_ps_downpour_and_validation():
         ADAG(make_model(), num_workers=2, execution="process_ps",
              checkpoint_dir="/tmp/nope",
              label_col="label_encoded").train(ds)
+
+
+def test_process_ps_refuses_a_driver_that_holds_the_chip(monkeypatch):
+    """One process per chip: a driver whose backend is a TPU holds this
+    host's chips, so same-host worker processes could never get one — a
+    typed error, raised before anything is launched (the platform check
+    is steered here, in the test; the program has no option for it)."""
+    import jax
+
+    from distkeras_tpu import job_deployment
+    from distkeras_tpu.parameter_servers import ChipHeldByDriver
+
+    launched = []
+    monkeypatch.setattr(job_deployment.LocalJobRunner, "launch",
+                        lambda self, *a, **kw: launched.append(a))
+    t = ADAG(make_model(), num_workers=2, batch_size=16,
+             label_col="label_encoded", execution="process_ps")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ChipHeldByDriver, match="one-process-per-host"):
+        t.train(make_dataset(n=64))
+    assert not launched

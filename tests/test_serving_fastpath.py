@@ -60,6 +60,26 @@ def windowed():
     return _fitted(seed=1, attention_window=6)
 
 
+@pytest.fixture(scope="module")
+def trained():
+    """The x+1 LM of tests/test_quant.py: its greedy margins dwarf int8
+    rounding, so two LOSSY programs can be held to token equality (the
+    untrained ``fitted`` has top-2 logit gaps of ~0.002 on a range of 4,
+    which any change of rounding order flips)."""
+    from distkeras_tpu.data.dataset import Dataset
+    from distkeras_tpu.trainers import SingleTrainer
+    model = transformer_lm(vocab_size=VOCAB, seq_len=32, d_model=32,
+                           num_heads=4, num_layers=2, mlp_dim=64,
+                           compute_dtype="float32")
+    toks = np.random.default_rng(2).integers(
+        0, VOCAB, (256, 32)).astype(np.int32)
+    t = SingleTrainer(model, batch_size=32, num_epoch=25,
+                      loss="sparse_categorical_crossentropy_from_logits",
+                      worker_optimizer="adam", learning_rate=3e-3)
+    return t.train(Dataset({"features": toks,
+                            "label": (toks + 1) % VOCAB}))
+
+
 def _want(fitted, h, **kw):
     return np.asarray(fitted.generate(
         h.prompt[None], h.num_steps, max_len=kw.pop("max_len"),
@@ -913,7 +933,7 @@ def test_paged_default_off_is_dense(fitted):
 
 
 @pytest.mark.paged
-def test_paged_pool_byte_accounting(fitted):
+def test_paged_pool_byte_accounting(fitted, trained):
     """kv_pool_bytes counts the arena (blocks + the null block), shrinks
     with kv_blocks, and the int8 arena pages codes + scales identically
     (fewer bytes than the f32 arena at the same block count)."""
@@ -930,14 +950,22 @@ def test_paged_pool_byte_accounting(fitted):
     blk = quant_mod.kv_block_bytes(big.caches, big.block_size)
     assert blk * (big.kv_blocks + 1) == big.kv_pool_bytes
     # and the int8 paged engine still decodes exactly like the dense
-    # int8 engine (lossy vs f32, but layout-exact between pools)
+    # int8 engine.  Both are lossy and they are NOT the same program: the
+    # dense prefill attends its full-precision rows and quantizes on
+    # commit (_commit_rows), the paged prefill attends the quantized
+    # arena (shared prefix blocks exist in no other form).  So equality
+    # is held on the trained model, where both must also keep the rule
+    q8 = ServingEngine(trained, num_slots=2, max_len=24, paged=True,
+                       block_size=4, kv_dtype="int8")
     h = q8.submit(PROMPT, 6)
     q8.run_until_idle()
-    dense8 = ServingEngine(fitted, num_slots=2, max_len=24,
+    dense8 = ServingEngine(trained, num_slots=2, max_len=24,
                            kv_dtype="int8")
     h2 = dense8.submit(PROMPT, 6)
     dense8.run_until_idle()
     np.testing.assert_array_equal(h.result(), h2.result())
+    np.testing.assert_array_equal(
+        h.result()[len(PROMPT):], (PROMPT[-1] + 1 + np.arange(6)) % VOCAB)
     _assert_no_block_leaks(q8)
 
 

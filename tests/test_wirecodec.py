@@ -2,7 +2,8 @@
 
 The two implementations must be byte-identical on the wire (either end of a
 host-PS connection may run either one).  Builds the extension in place if it
-isn't already built; skips gracefully where no toolchain exists.
+isn't already built (the shared, locked ``tests/native_build.py``); skips
+where no toolchain exists.
 
 The ``codec`` fixture parametrizes the shared contract tests over BOTH
 implementations — forcing ``networking._native = None`` routes every encode,
@@ -12,28 +13,24 @@ machines where the native extension is always importable.
 """
 
 import socket
-import subprocess
-import sys
 import threading
 
 import numpy as np
 import pytest
 
+from native_build import ensure_built
+
 from distkeras_tpu import networking
 
 
 def _ensure_native():
-    if networking._native is not None:
-        return networking._native
-    r = subprocess.run(
-        [sys.executable, "setup.py", "build_ext", "--inplace"],
-        cwd=networking.__file__.rsplit("/", 2)[0], capture_output=True)
-    if r.returncode != 0:
-        pytest.skip(f"no native toolchain: {r.stderr[-200:]}")
-    import importlib
-    import distkeras_tpu._wirecodec as native
-    networking._native = native
-    return native
+    if networking._native is None:
+        error = ensure_built()
+        if error is not None:
+            pytest.skip(f"no native toolchain: {error}")
+        import distkeras_tpu._wirecodec as native
+        networking._native = native
+    return networking._native
 
 
 @pytest.fixture()
