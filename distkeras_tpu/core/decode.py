@@ -32,7 +32,8 @@ tmap = jax.tree_util.tree_map
 
 from .layers import (Dense, Embedding, LayerNormalization,
                      MultiHeadAttention, PositionalEmbedding,
-                     TransformerBlock, _apply_activation, _project)
+                     TransformerBlock, _apply_activation, _project,
+                     scope_names)
 
 _STATELESS = (LayerNormalization, Dense)
 
@@ -356,6 +357,13 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
         q = apply_rope(q, positions, mha.rope_theta, mha.rope_scale)
         k_t = apply_rope(k_t, positions, mha.rope_theta, mha.rope_scale)
     new_cache = None
+
+    def attend(k, v, **where):
+        with jax.named_scope("attn_core"):
+            return dot_product_attention(q, k, v, causal=True,
+                                         window=mha.attention_window,
+                                         **where)
+
     if paged is not None:
         # -- paged arena: block-table-indexed scatter write, gathered read
         bs, view = paged.page, paged.view
@@ -382,20 +390,23 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
         if paged.ceil is not None:
             phys = jnp.where(idx < jnp.reshape(paged.ceil, (-1, 1)),
                              phys, null_phys)
-        new_cache = _kv_write(cache, (phys,), k_t, v_t)
-        if _kv_quantized(new_cache):
-            from .quant import dequantize_kv
-            k = dequantize_kv(
-                paged_gather(new_cache["k"], paged.tables, bs, view),
-                paged_gather(new_cache["ks"], paged.tables, bs, view),
-                cdtype)
-            v = dequantize_kv(
-                paged_gather(new_cache["v"], paged.tables, bs, view),
-                paged_gather(new_cache["vs"], paged.tables, bs, view),
-                cdtype)
-        else:
-            k = paged_gather(new_cache["k"], paged.tables, bs, view)
-            v = paged_gather(new_cache["v"], paged.tables, bs, view)
+        with jax.named_scope("kv_write"):
+            new_cache = _kv_write(cache, (phys,), k_t, v_t)
+        with jax.named_scope("kv_gather"):
+            if _kv_quantized(new_cache):
+                from .quant import dequantize_kv
+                k = dequantize_kv(
+                    paged_gather(new_cache["k"], paged.tables, bs, view),
+                    paged_gather(new_cache["ks"], paged.tables, bs, view),
+                    cdtype)
+                v = dequantize_kv(
+                    paged_gather(new_cache["v"], paged.tables, bs, view),
+                    paged_gather(new_cache["vs"], paged.tables, bs, view),
+                    cdtype)
+            else:
+                k = paged_gather(new_cache["k"], paged.tables, bs, view)
+                v = paged_gather(new_cache["v"], paged.tables, bs, view)
+        kv_positions = kv_length = None
         if paged.ring:
             # same frontier layout as the dense ring: view slot j holds
             # the newest position <= each row's write frontier congruent
@@ -403,15 +414,10 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
             front = pos[:, None] + (length - 1)
             j = jnp.arange(view)
             kv_positions = front - jnp.mod(front - j[None, :], view)
-            out = dot_product_attention(q, k, v, causal=True,
-                                        q_positions=q_clamped,
-                                        window=mha.attention_window,
-                                        kv_positions=kv_positions)
         else:
-            out = dot_product_attention(q, k, v, causal=True,
-                                        q_positions=q_clamped,
-                                        kv_length=pos + length,
-                                        window=mha.attention_window)
+            kv_length = pos + length
+        out = attend(k, v, q_positions=q_clamped, kv_length=kv_length,
+                     kv_positions=kv_positions)
     elif per_row:
         # L >= 1: every row writes its L entries at its own offsets (the
         # serving engine's decode step at L == 1, its speculative verify
@@ -428,7 +434,9 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
                     f"{mha.attention_window + length - 1} slots, got {w} "
                     f"(init_cache(ring_slack=...)) — the oldest query's "
                     f"window would be overwritten by the newest write")
-            new_cache = _kv_write(cache, (rows[:, None], idx % w), k_t, v_t)
+            with jax.named_scope("kv_write"):
+                new_cache = _kv_write(cache, (rows[:, None], idx % w), k_t,
+                                      v_t)
             # slot j holds the newest position <= each row's write
             # frontier congruent to j mod w (negative = never written);
             # queries older than the frontier hide the just-written
@@ -436,16 +444,15 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
             front = pos[:, None] + (length - 1)
             j = jnp.arange(w)
             kv_positions = front - jnp.mod(front - j[None, :], w)
-            k, v = _kv_read(new_cache, cdtype)
-            out = dot_product_attention(q, k, v, causal=True, q_offset=pos,
-                                        window=mha.attention_window,
-                                        kv_positions=kv_positions)
+            with jax.named_scope("kv_gather"):
+                k, v = _kv_read(new_cache, cdtype)
+            out = attend(k, v, q_offset=pos, kv_positions=kv_positions)
         else:
-            new_cache = _kv_write(cache, (rows[:, None], idx), k_t, v_t)
-            k, v = _kv_read(new_cache, cdtype)
-            out = dot_product_attention(q, k, v, causal=True, q_offset=pos,
-                                        kv_length=pos + length,
-                                        window=mha.attention_window)
+            with jax.named_scope("kv_write"):
+                new_cache = _kv_write(cache, (rows[:, None], idx), k_t, v_t)
+            with jax.named_scope("kv_gather"):
+                k, v = _kv_read(new_cache, cdtype)
+            out = attend(k, v, q_offset=pos, kv_length=pos + length)
     elif rolling:
         # ring buffer of the block's window: slot p % W holds position p.
         # Single-token writes only — generate() prefills with a full cache
@@ -455,21 +462,23 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
                              "(prefill uses a full cache, then converts)")
         w = cache["k"].shape[1]
         slot = pos % w
-        k = jax.lax.dynamic_update_slice(cache["k"], k_t, (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_t, (0, slot, 0, 0))
+        with jax.named_scope("kv_write"):
+            k = jax.lax.dynamic_update_slice(cache["k"], k_t,
+                                             (0, slot, 0, 0))
+            v = jax.lax.dynamic_update_slice(cache["v"], v_t,
+                                             (0, slot, 0, 0))
         # slot j currently holds position pos - ((pos - j) mod W); slots
         # not yet written come out negative and mask themselves
         j = jnp.arange(w)
         kv_positions = pos - jnp.mod(pos - j, w)
-        out = dot_product_attention(q, k, v, causal=True, q_offset=pos,
-                                    window=mha.attention_window,
-                                    kv_positions=kv_positions)
+        out = attend(k, v, q_offset=pos, kv_positions=kv_positions)
     else:
-        k = jax.lax.dynamic_update_slice(cache["k"], k_t, (0, pos, 0, 0))
-        v = jax.lax.dynamic_update_slice(cache["v"], v_t, (0, pos, 0, 0))
-        out = dot_product_attention(q, k, v, causal=True, q_offset=pos,
-                                    kv_length=pos + length,
-                                    window=mha.attention_window)
+        with jax.named_scope("kv_write"):
+            k = jax.lax.dynamic_update_slice(cache["k"], k_t,
+                                             (0, pos, 0, 0))
+            v = jax.lax.dynamic_update_slice(cache["v"], v_t,
+                                             (0, pos, 0, 0))
+        out = attend(k, v, q_offset=pos, kv_length=pos + length)
     out = out.reshape(b, length, mha.num_heads * dh)
     bias_o = params.get("bo") if mha.use_bias else None
     y = _project(out, params["wo"], bias_o, cdtype)
@@ -481,15 +490,17 @@ def _block_forward(block: TransformerBlock, params, x, cache, pos, cdtype,
                    paged: Optional[PagedView] = None):
     """Mirrors ``TransformerBlock.apply`` (train=False) with cached MHA."""
     ln = LayerNormalization()
-    h = ln.apply(params["ln1"], x, compute_dtype=cdtype)
-    h, cache = _mha_forward(block._mha(), params["attn"], h, cache, pos,
-                            cdtype, rolling, paged)
-    x = x + h.astype(x.dtype)
-    h = ln.apply(params["ln2"], x, compute_dtype=cdtype)
-    h = _project(h, params["mlp_w1"], params["mlp_b1"], cdtype)
-    h = _apply_activation(block.activation, h).astype(cdtype)
-    h = _project(h, params["mlp_w2"], params["mlp_b2"], cdtype)
-    return x + h.astype(x.dtype), cache
+    with jax.named_scope("attn"):
+        h = ln.apply(params["ln1"], x, compute_dtype=cdtype)
+        h, cache = _mha_forward(block._mha(), params["attn"], h, cache, pos,
+                                cdtype, rolling, paged)
+        x = x + h.astype(x.dtype)
+    with jax.named_scope("mlp"):
+        h = ln.apply(params["ln2"], x, compute_dtype=cdtype)
+        h = _project(h, params["mlp_w1"], params["mlp_b1"], cdtype)
+        h = _apply_activation(block.activation, h).astype(cdtype)
+        h = _project(h, params["mlp_w2"], params["mlp_b2"], cdtype)
+        return x + h.astype(x.dtype), cache
 
 
 def _forward(model, params, caches, toks, pos, rolling: bool = False,
@@ -507,32 +518,34 @@ def _forward(model, params, caches, toks, pos, rolling: bool = False,
     cdtype = model._cdtype
     x = None
     new_caches: List[Any] = []
-    for layer, p, cache in zip(model.layers, params, caches):
-        if isinstance(layer, Embedding):
-            # jnp.asarray: trained params may live as host numpy arrays
-            # (FittedModel), which tracer-indexing rejects
-            x = jnp.asarray(p["embedding"]).astype(cdtype)[toks]
-        elif isinstance(layer, PositionalEmbedding):
-            if _per_row(pos) and toks.shape[1] == 1:
-                pe = jnp.asarray(p["embedding"])[pos]          # (B, D)
-                x = x + pe.astype(x.dtype)[:, None]
-            elif _per_row(pos):
-                # per-row multi-token (the speculative verify): row r's
-                # token i sits at absolute position pos[r] + i.  OOB rows
-                # (a request at its very end) clamp — their logits are
-                # junk the engine never commits
-                idx = pos[:, None] + jnp.arange(toks.shape[1])[None, :]
-                pe = jnp.asarray(p["embedding"])[idx]          # (B, L, D)
-                x = x + pe.astype(x.dtype)
-            else:
-                pe = jax.lax.dynamic_slice_in_dim(
-                    jnp.asarray(p["embedding"]), pos, toks.shape[1])
-                x = x + pe.astype(x.dtype)[None]
-        elif isinstance(layer, TransformerBlock):
-            x, cache = _block_forward(layer, p, x, cache, pos, cdtype,
-                                      rolling, paged)
-        else:  # LayerNormalization / Dense: position-independent
-            x = layer.apply(p, x, compute_dtype=cdtype, train=False)
+    for layer, p, cache, scope in zip(model.layers, params, caches,
+                                      scope_names(model.layers)):
+        with jax.named_scope(scope):
+            if isinstance(layer, Embedding):
+                # jnp.asarray: trained params may live as host numpy arrays
+                # (FittedModel), which tracer-indexing rejects
+                x = jnp.asarray(p["embedding"]).astype(cdtype)[toks]
+            elif isinstance(layer, PositionalEmbedding):
+                if _per_row(pos) and toks.shape[1] == 1:
+                    pe = jnp.asarray(p["embedding"])[pos]          # (B, D)
+                    x = x + pe.astype(x.dtype)[:, None]
+                elif _per_row(pos):
+                    # per-row multi-token (the speculative verify): row r's
+                    # token i sits at absolute position pos[r] + i.  OOB rows
+                    # (a request at its very end) clamp — their logits are
+                    # junk the engine never commits
+                    idx = pos[:, None] + jnp.arange(toks.shape[1])[None, :]
+                    pe = jnp.asarray(p["embedding"])[idx]          # (B, L, D)
+                    x = x + pe.astype(x.dtype)
+                else:
+                    pe = jax.lax.dynamic_slice_in_dim(
+                        jnp.asarray(p["embedding"]), pos, toks.shape[1])
+                    x = x + pe.astype(x.dtype)[None]
+            elif isinstance(layer, TransformerBlock):
+                x, cache = _block_forward(layer, p, x, cache, pos, cdtype,
+                                          rolling, paged)
+            else:  # LayerNormalization / Dense: position-independent
+                x = layer.apply(p, x, compute_dtype=cdtype, train=False)
         new_caches.append(cache)
     return x.astype(jnp.float32), new_caches
 
@@ -638,6 +651,7 @@ def _filter_logits(logits, top_k: Optional[int], top_p: Optional[float]):
     return logits
 
 
+@jax.named_scope("sample")
 def sample_logits(logits, pos, temperature: float = 0.0,
                   rng: Optional[jax.Array] = None,
                   top_k: Optional[int] = None,
@@ -684,6 +698,7 @@ def filter_logits_batched(logits, top_k, top_p):
                      -jnp.inf, logits)
 
 
+@jax.named_scope("sample")
 def sample_logits_batched(logits, positions, temperature, rngs,
                           top_k, top_p) -> jnp.ndarray:
     """Per-row ``sample_logits``: every row carries its own sampling config.

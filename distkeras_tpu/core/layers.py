@@ -619,10 +619,11 @@ class MultiHeadAttention(Layer):
             pos = jnp.arange(s)
             q = apply_rope(q, pos, self.rope_theta, self.rope_scale)
             k = apply_rope(k, pos, self.rope_theta, self.rope_scale)
-        out = attention(q, k, v,
-                        causal=self.causal, impl=self.attention_impl,
-                        window=self.attention_window,
-                        segment_ids=segment_ids)
+        with jax.named_scope("attn_core"):
+            out = attention(q, k, v,
+                            causal=self.causal, impl=self.attention_impl,
+                            window=self.attention_window,
+                            segment_ids=segment_ids)
         out = out.reshape(b, s, self.num_heads * dh)
         bias_o = params.get("bo") if self.use_bias else None
         return _project(out, params["wo"], bias_o, compute_dtype)
@@ -710,18 +711,22 @@ class TransformerBlock(Layer):
         drop_rngs = (jax.random.split(rng, 2) if rng is not None else
                      (None, None))
 
-        h = ln.apply(params["ln1"], x, compute_dtype=compute_dtype)
-        h = self._mha().apply(params["attn"], h, compute_dtype=compute_dtype,
-                              train=train, rng=None,
-                              segment_ids=segment_ids)
-        x = x + _dropout(drop_rngs[0], self.dropout, h.astype(x.dtype), train)
-
-        h = ln.apply(params["ln2"], x, compute_dtype=compute_dtype)
-        h = _project(h, params["mlp_w1"], params["mlp_b1"], compute_dtype)
-        h = _apply_activation(self.activation, h).astype(compute_dtype)
-        h = _project(h, params["mlp_w2"], params["mlp_b2"], compute_dtype)
-        return x + _dropout(drop_rngs[1], self.dropout, h.astype(x.dtype),
-                            train)
+        with jax.named_scope("attn"):
+            h = ln.apply(params["ln1"], x, compute_dtype=compute_dtype)
+            h = self._mha().apply(params["attn"], h,
+                                  compute_dtype=compute_dtype, train=train,
+                                  rng=None, segment_ids=segment_ids)
+            x = x + _dropout(drop_rngs[0], self.dropout, h.astype(x.dtype),
+                             train)
+        with jax.named_scope("mlp"):
+            h = ln.apply(params["ln2"], x, compute_dtype=compute_dtype)
+            h = _project(h, params["mlp_w1"], params["mlp_b1"],
+                         compute_dtype)
+            h = _apply_activation(self.activation, h).astype(compute_dtype)
+            h = _project(h, params["mlp_w2"], params["mlp_b2"],
+                         compute_dtype)
+            return x + _dropout(drop_rngs[1], self.dropout,
+                                h.astype(x.dtype), train)
 
 
 class Embedding(Layer):
@@ -737,3 +742,30 @@ class Embedding(Layer):
     def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
               rng=None):
         return params["embedding"].astype(compute_dtype)[x]
+
+
+def scope_names(layers: Sequence[Layer]) -> List[str]:
+    """The ``jax.named_scope`` each layer of a stack runs under, in the
+    training forward and the decode forward alike (``metrics.py`` lists
+    them): ``embed`` for the token and position tables, ``block_<i>`` for
+    the i-th ``TransformerBlock``, and — in a stack that has blocks —
+    ``final_norm`` for the normalization after the last one and ``lm_head``
+    for a closing ``Dense``.  Any other layer runs under its class name in
+    lower case."""
+    blocks = [i for i, l in enumerate(layers)
+              if isinstance(l, TransformerBlock)]
+    names = []
+    for i, layer in enumerate(layers):
+        if isinstance(layer, (Embedding, PositionalEmbedding)):
+            name = "embed"
+        elif isinstance(layer, TransformerBlock):
+            name = f"block_{blocks.index(i)}"
+        elif (blocks and i > blocks[-1]
+              and isinstance(layer, LayerNormalization)):
+            name = "final_norm"
+        elif blocks and i == len(layers) - 1 and isinstance(layer, Dense):
+            name = "lm_head"
+        else:
+            name = type(layer).__name__.lower()
+        names.append(name)
+    return names

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .layers import Layer
+from .layers import Layer, scope_names
 
 Params = Any
 
@@ -88,6 +88,7 @@ class Sequential:
                     "documents position-shifted embeddings — build the "
                     "model with positional='rope'")
         cdtype = self._cdtype
+        scopes = scope_names(self.layers)
         for i, layer in enumerate(self.layers):
             sub = None
             if rng is not None:
@@ -95,14 +96,15 @@ class Sequential:
             kw = ({"segment_ids": segment_ids}
                   if segment_ids is not None
                   and getattr(layer, "takes_segment_ids", False) else {})
-            if (train and stats_out is not None
-                    and hasattr(layer, "apply_with_stats")):
-                x, new_stats = layer.apply_with_stats(
-                    params[i], x, compute_dtype=cdtype, rng=sub)
-                stats_out[i] = new_stats
-            else:
-                x = layer.apply(params[i], x, compute_dtype=cdtype,
-                                train=train, rng=sub, **kw)
+            with jax.named_scope(scopes[i]):
+                if (train and stats_out is not None
+                        and hasattr(layer, "apply_with_stats")):
+                    x, new_stats = layer.apply_with_stats(
+                        params[i], x, compute_dtype=cdtype, rng=sub)
+                    stats_out[i] = new_stats
+                else:
+                    x = layer.apply(params[i], x, compute_dtype=cdtype,
+                                    train=train, rng=sub, **kw)
         return x
 
     @staticmethod

@@ -40,7 +40,8 @@ def make_loss_fn(model: Sequential, loss) -> Callable:
     def compute(params, x, y, rng):
         stats: dict = {}
         pred = model.apply(params, x, train=True, rng=rng, stats_out=stats)
-        return loss_fn(y, pred), stats
+        with jax.named_scope("loss"):
+            return loss_fn(y, pred), stats
 
     return compute
 
@@ -61,9 +62,11 @@ def make_masked_loss_fn(model: Sequential, loss) -> Callable:
         kw = {"segment_ids": seg} if seg is not None else {}
         pred = model.apply(params, x, train=True, rng=rng, stats_out=stats,
                            **kw)
-        losses = per_ex(y, pred)
-        w = w.astype(jnp.float32)
-        return jnp.sum(losses * w) / jnp.maximum(jnp.sum(w), 1.0), stats
+        with jax.named_scope("loss"):
+            losses = per_ex(y, pred)
+            w = w.astype(jnp.float32)
+            return (jnp.sum(losses * w) / jnp.maximum(jnp.sum(w), 1.0),
+                    stats)
 
     return compute
 
@@ -86,14 +89,17 @@ def make_masked_step(model: Sequential, loss,
     def step(params, opt_state, x, y, w, rng, seg=None):
         (l, stats), grads = jax.value_and_grad(compute, has_aux=True)(
             params, x, y, w, rng, seg)
-        updates, new_opt = tx.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = tx.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         new_params = Sequential.merge_stats(new_params, stats)
         wsum = jnp.sum(w.astype(jnp.float32))
         keep = wsum > 0.0
         pick = lambda new, old: jax.tree_util.tree_map(
             lambda a, b: jnp.where(keep, a, b), new, old)
-        return pick(new_params, params), pick(new_opt, opt_state), l, wsum
+        with jax.named_scope("optimizer"):  # the gate is part of the apply
+            return (pick(new_params, params), pick(new_opt, opt_state), l,
+                    wsum)
 
     return step
 
@@ -107,8 +113,10 @@ def make_train_step(model: Sequential, loss, tx: optax.GradientTransformation,
         x, y = batch
         (loss_val, stats), grads = jax.value_and_grad(compute, has_aux=True)(
             state.params, x, y, rng)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
         params = Sequential.merge_stats(params, stats)
         return TrainState(params, opt_state, state.step + 1), loss_val
 

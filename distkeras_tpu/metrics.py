@@ -3,9 +3,75 @@
 The reference's observability is wall-clock only —
 ``Trainer.record_training_start/stop`` plus loss-history lists collected from
 workers, and scattered ``print`` statements (SURVEY.md §5).  Here metrics are
-structured events (JSONL) with throughput derived per epoch, and ``trace()``
-wraps ``jax.profiler`` so a TensorBoard-readable device trace is one context
-manager away — required plumbing for the examples/sec/chip north-star metric.
+structured events (JSONL) with throughput derived per epoch, and the
+program's own phases are spans and scopes of ONE tracing system,
+``jax.profiler``'s: no second recorder, clock, flag or environment variable.
+
+**Host spans** (``span(name, **fields)``, a ``jax.profiler.TraceAnnotation``)
+are live while a profiler session is open — ``trace(log_dir)`` here, or any
+``jax.profiler.start_trace`` — and cost under a microsecond otherwise.  They
+land on the ``/host:CPU`` plane of the session's ``.xplane.pb``, on the
+device trace's own timeline, each with its fields as the event's stats.
+Fields are ints or short strings known when the span OPENS; a result known
+only at exit rides on the next span of the same request.  The names below
+are the contract that ``docs/serving.md`` and the benchmark's readers
+(``benchmarks/lib/spans.py``) point at.
+
+Serving (``serving.py``; the engine's thread unless said):
+
+===================== ======================================================
+``serve.submit``      ``submit``/``submit_prefilled`` on the CALLER's
+                      thread, from the id's taking to the queue push (key
+                      creation included): ``rid``, ``prompt`` (tokens),
+                      ``steps``
+``serve.iteration``   one ``step()``: ``it`` (iteration count), ``active``
+                      (running slots at entry)
+``serve.reap``        ``_reap``: cancelled / expired requests retired
+``serve.qos``         ``_balance_qos``, only when it runs
+``serve.schedule``    ``_schedule_prefills``
+``serve.admit``       one admission (block plan, radix match, slot take):
+                      ``rid``
+``serve.prefill_unit`` one dispatched prefill work unit: ``rid`` (first of
+                      a batch), ``tokens``, ``kind``
+                      (``bucket``/``chunk``/``final``), ``width``, ``hit``
+                      (prefix-hit tokens the admission found; on the first
+                      unit of a request, else 0)
+``serve.decode_dispatch`` ``_decode_once``: ``active``, ``step``
+                      (``stats["decode_steps"]`` after this dispatch)
+``serve.fetch``       the device→host read of one in-flight step — the time
+                      the host WAITS for the device: ``step`` of the entry
+                      drained (joins it to its ``serve.decode_dispatch``;
+                      a prefill entry carries the step count at dispatch)
+``serve.emit``        the token loop after the fetch (push, listeners,
+                      retirements): ``kind`` of the step drained
+                      (``decode``/``spec``/``prefill``), ``rows`` (slots it
+                      held), ``step``
+``serve.retire``      ``_retire``: ``rid``, ``reason``
+``serve.publish``     ``_publish_load``
+``serve.reload``      ``_pull_weights``
+``serve.idle_wait``   ``_loop`` waiting for work after an idle ``step()``
+``serve.warmup``      ``warmup()``, one span per program compiled:
+                      ``program``
+===================== ======================================================
+
+``rid`` is ``RequestHandle.id``: the spans of one request share it
+(submit → admit → prefill_unit... → retire).
+
+Training (``DistributedTrainer.train``, epoch and per-round paths):
+``train.epoch`` (``epoch``) with children ``train.shuffle``,
+``train.shape`` (``shape_epoch_data``), ``train.dispatch`` (host→device
+transfer and launch of ``run_epoch``/``run_round``: ``rounds``),
+``train.fetch`` (the host waits for the device's losses), ``train.log``,
+``train.checkpoint``, ``train.validate``.
+
+**Device scopes** (``jax.named_scope``; HLO metadata only, free at run
+time) name the compiled programs' phases in every profile and HLO dump:
+``embed``, ``block_<i>`` ⊃ ``attn`` ⊃ ``attn_core``, ``mlp``,
+``final_norm``, ``lm_head`` (model forward, training and decoding alike);
+``loss``, ``optimizer``, ``commit`` (train step and the SPMD round);
+``kv_write``, ``kv_gather``, ``sample`` (decode step).  Pallas kernels
+carry ``flash_fwd``/``flash_dq``/``flash_dkv`` and
+``fused_ce_fwd``/``fused_ce_bwd``.
 """
 
 from __future__ import annotations
@@ -14,6 +80,8 @@ import contextlib
 import json
 import time
 from typing import IO, Any, Dict, List, Optional
+
+import jax
 
 
 class MetricsLogger:
@@ -70,25 +138,32 @@ class EpochMetrics:
 
 @contextlib.contextmanager
 def trace(log_dir: str, enabled: bool = True):
-    """Capture a ``jax.profiler`` device trace for the enclosed block
-    (view with TensorBoard / Perfetto).  No-ops cleanly when disabled."""
+    """Capture a ``jax.profiler`` trace (device operations and the
+    program's host spans, one timeline) for the enclosed block; view with
+    TensorBoard / Perfetto, or read the ``.xplane.pb`` with
+    ``jax.profiler.ProfileData``.  The tracer levels are the benchmark's
+    (``--trace 1``), so an operator's trace and the benchmark's hold the
+    same events: runtime and annotation spans, no Python call stacks.
+    No-ops cleanly when disabled."""
     if not enabled:
         yield
         return
-    import jax
-    jax.profiler.start_trace(log_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 2
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region in the profiler timeline (jax.profiler.TraceAnnotation)."""
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield
+def span(name: str, **fields):
+    """A named host span with ``fields`` (ints / short strings, fixed at
+    entry) as its stats — a ``jax.profiler.TraceAnnotation``, so it is
+    recorded only while a profiler session is open and shares the device
+    trace's timeline.  The module docstring lists the program's spans."""
+    return jax.profiler.TraceAnnotation(name, **fields)
 
 
 # -- analytic FLOPs + MFU ----------------------------------------------------

@@ -174,6 +174,7 @@ def _flash_forward(q, k, v, scale: float, causal: bool, block_q: int,
                         pltpu.VMEM((bq, _LANES), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     out = res[0]
     lse = res[1] if save_residuals else None
@@ -291,6 +292,7 @@ def _flash_backward(q, k, v, out, lse, g, scale: float, causal: bool,
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, kj: (bh, qi, 0)),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qf, kf, vf, of, gf, lse)
 
     dk, dv = pl.pallas_call(
@@ -314,6 +316,7 @@ def _flash_backward(q, k, v, out, lse, g, scale: float, causal: bool,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(qf, kf, vf, of, gf, lse)
 
     unfold = lambda t: t.reshape(b, h, s, d).transpose(0, 2, 1, 3)

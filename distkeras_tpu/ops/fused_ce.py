@@ -141,6 +141,7 @@ def _fwd_call(logits, labels, block_t, block_v, interpret):
                         pltpu.VMEM((bt, _LANES), jnp.float32),
                         pltpu.VMEM((bt, _LANES), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(logits, labels.reshape(t, 1).astype(jnp.int32))
     return loss[:, 0], lse[:, 0]
 
@@ -197,6 +198,7 @@ def _ce_bwd(block_t, block_v, interpret, res, g):
         in_specs=[sp["logits"], sp["rows"], sp["lanes"], sp["lanes"]],
         out_specs=sp["logits"],
         interpret=interpret,
+        name="fused_ce_bwd",
     )(logits, labels.reshape(t, 1).astype(jnp.int32), lse_b, ct)
     return dlogits, None
 

@@ -218,37 +218,41 @@ class SPMDEngine:
                 # 'local' = independent training: per-worker stats persist
                 new_p, center = self._sync_stats(new_p, center)
 
-            if algo == "adag":
-                delta = rules.tree_sub(new_p, center)
-                summed = tmap(lambda d: jax.lax.psum(d, WORKER_AXIS), delta)
-                center = rules.adag_commit(center, summed, n)
-            elif algo == "downpour":
-                delta = rules.tree_sub(new_p, center)
-                summed = tmap(lambda d: jax.lax.psum(d, WORKER_AXIS), delta)
-                center = rules.delta_commit(center, summed)
-            elif algo == "dynsgd":
-                # Serialized-commit emulation: within a round, worker w's
-                # commit lands after ``order`` earlier commits, where the
-                # order rotates every round — its delta is scaled by
-                # 1/(staleness+1) exactly as DynSGDParameterServer does.
-                w = jax.lax.axis_index(WORKER_AXIS)
-                order = jnp.mod(w + round_idx, n).astype(jnp.float32)
-                delta = rules.tree_sub(new_p, center)
-                scaled = rules.dynsgd_commit(
-                    tmap(jnp.zeros_like, center), delta, order)
-                summed = tmap(lambda d: jax.lax.psum(d, WORKER_AXIS), scaled)
-                center = rules.tree_add(center, summed)
-            elif algo == "local":
-                # Independent per-worker training (AveragingTrainer /
-                # EnsembleTrainer): no exchange; center untouched.
-                pass
-            elif algo in ("aeasgd", "eamsgd"):
-                e = rules.elastic_difference(new_p, center, alpha)
-                new_p = rules.easgd_worker_update(new_p, e)
-                summed = tmap(lambda d: jax.lax.psum(d, WORKER_AXIS), e)
-                center = rules.easgd_center_update(center, summed)
-            else:
-                raise ValueError(f"unknown algorithm {algo!r}")
+            # the window's exchange: the collective and the center update
+            psum = lambda t: tmap(
+                lambda d: jax.lax.psum(d, WORKER_AXIS), t)
+            with jax.named_scope("commit"):
+                if algo == "adag":
+                    delta = rules.tree_sub(new_p, center)
+                    summed = psum(delta)
+                    center = rules.adag_commit(center, summed, n)
+                elif algo == "downpour":
+                    delta = rules.tree_sub(new_p, center)
+                    summed = psum(delta)
+                    center = rules.delta_commit(center, summed)
+                elif algo == "dynsgd":
+                    # Serialized-commit emulation: within a round, worker w's
+                    # commit lands after ``order`` earlier commits, where the
+                    # order rotates every round — its delta is scaled by
+                    # 1/(staleness+1) exactly as DynSGDParameterServer does.
+                    w = jax.lax.axis_index(WORKER_AXIS)
+                    order = jnp.mod(w + round_idx, n).astype(jnp.float32)
+                    delta = rules.tree_sub(new_p, center)
+                    scaled = rules.dynsgd_commit(
+                        tmap(jnp.zeros_like, center), delta, order)
+                    summed = psum(scaled)
+                    center = rules.tree_add(center, summed)
+                elif algo == "local":
+                    # Independent per-worker training (AveragingTrainer /
+                    # EnsembleTrainer): no exchange; center untouched.
+                    pass
+                elif algo in ("aeasgd", "eamsgd"):
+                    e = rules.elastic_difference(new_p, center, alpha)
+                    new_p = rules.easgd_worker_update(new_p, e)
+                    summed = psum(e)
+                    center = rules.easgd_center_update(center, summed)
+                else:
+                    raise ValueError(f"unknown algorithm {algo!r}")
 
             # exact mean over real (unpadded) examples across all workers
             mean_loss = (jax.lax.psum(loss_sum, WORKER_AXIS)

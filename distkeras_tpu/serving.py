@@ -91,6 +91,7 @@ opcode namespace — the PS protocol's ``'q'`` (quit) lives elsewhere.
 from __future__ import annotations
 
 import collections
+import itertools
 import logging
 import select
 import selectors
@@ -111,6 +112,7 @@ from .core.decode import (_check_supported, _context_limit, _forward,
                           _validate_stopping, _vocab_size, decode_step,
                           init_cache, sample_logits, sample_logits_batched)
 from .core.model import FittedModel, Sequential
+from .metrics import span
 
 logger = logging.getLogger("distkeras_tpu.serving")
 
@@ -512,7 +514,8 @@ class _PrefillJob:
     chunks write straight into the request's allocated blocks (``bt`` /
     ``dbt`` hold the row's uploaded block tables)."""
 
-    __slots__ = ("handle", "staging", "d_staging", "written", "bt", "dbt")
+    __slots__ = ("handle", "staging", "d_staging", "written", "bt", "dbt",
+                 "hit")
 
     def __init__(self, handle: RequestHandle, staging=None, d_staging=None,
                  bt=None, dbt=None):
@@ -522,6 +525,7 @@ class _PrefillJob:
         self.bt = bt                # paged: (1, T) device block-table row
         self.dbt = dbt
         self.written = 0
+        self.hit = 0    # prefix-hit tokens, for the first unit's span
 
 
 class _SuspendedReq:
@@ -1098,7 +1102,7 @@ class ServingEngine:
         self._qlock = threading.Lock()
         self._not_full = threading.Condition(self._qlock)
         self._have_work = threading.Condition(self._qlock)
-        self._next_id = 0
+        self._ids = itertools.count(1)   # request ids
 
         # -- preemption state (QoS swap-out): suspended requests live here
         #    holding NO slot and NO arena blocks — just a host-memory copy
@@ -1133,6 +1137,7 @@ class ServingEngine:
         self._chunk_width = min(self.prefill_chunk, self.max_len)
         self._buckets = _pow2_buckets(self._chunk_width)
         self._pending: "collections.deque" = collections.deque()
+        self._iterations = 0  # step() calls so far (serve.iteration's `it`)
         self._prefilling: Dict[int, _PrefillJob] = {}
         self._lookahead = 1 if self.prefill_mode == "bucketed" else 0
         if self.prefill_mode == "bucketed":
@@ -2228,72 +2233,78 @@ class ServingEngine:
                 deadline_s = self.default_deadline_s
         elif deadline_s <= 0:
             raise ValueError(f"deadline_s must be > 0, got {deadline_s}")
-        key = rng if rng is not None else jax.random.PRNGKey(int(seed))
-        _validate_sampling(temperature, key, top_k, top_p)
-        _validate_stopping(eos_id, pad_id, self._vocab)
-        total = len(prompt) + int(num_steps)
-        if len(prompt) < 1:
-            raise ValueError("prompt must hold at least one token")
-        if total > self.max_len:
-            raise ValueError(f"prompt ({len(prompt)}) + num_steps "
-                             f"({num_steps}) = {total} exceeds the engine's "
-                             f"max_len {self.max_len}")
-        with self._qlock:
-            if self._dead is not None:
-                raise EngineDead(str(self._dead)) from self._dead
-            if self._draining:
-                raise Draining("serving engine is draining; admission "
-                               "stopped")
-            pol = self._tenants.get(tenant)
-            if pol is not None and not pol._take(time.monotonic()):
-                # policy refusal BEFORE requests_submitted so drain()'s
-                # terminal accounting never waits on a refused request;
-                # per-tenant so one tenant's refusals don't dilute the
-                # global shed_rate (requests_rejected untouched)
-                self._tenant_stats(tenant)["quota_refused"] += 1
-                self.stats["quota_refused"] += 1
-                raise QuotaExceeded(
-                    f"tenant {tenant!r} over its token-bucket quota "
-                    f"({pol.rate}/s, burst {pol.burst})")
-            self._next_id += 1
-            handle = RequestHandle(self._next_id, prompt, num_steps,
-                                   temperature, top_k, top_p, eos_id,
-                                   pad_id, key, deadline_s=deadline_s,
-                                   tenant=tenant, priority=priority)
-            self.stats["requests_submitted"] += 1
-            tstats = self._tenant_stats(tenant)
-            tstats["submitted"] += 1
-            if num_steps == 0:  # nothing to generate: complete in place
-                handle._finish("empty")
-                self.stats["requests_completed"] += 1
-                tstats["completed"] += 1
-                return handle
-            while self._qdepth >= self.queue_capacity:
-                if not block or not self._not_full.wait(timeout=timeout):
-                    self.stats["requests_rejected"] += 1
-                    tstats["shed"] += 1
-                    raise QueueFull(
-                        f"admission queue at capacity "
-                        f"({self.queue_capacity}); request {handle.id} shed")
-                # _declare_dead / drain notify _not_full while we wait —
-                # re-check on every wake or the request lands in a queue no
-                # scheduler will ever pop (result() would hang forever).
-                # Both raises count as admission sheds (requests_rejected)
-                # so the terminal accounting drain() sums stays balanced.
+        # the id is taken before the request is built, so that the span
+        # carries it from its first instant; a request refused below
+        # (validation, quota, a full queue) leaves a gap in the sequence
+        rid = next(self._ids)
+        with span("serve.submit", rid=rid, prompt=len(prompt),
+                  steps=int(num_steps)):
+            key = rng if rng is not None else jax.random.PRNGKey(int(seed))
+            _validate_sampling(temperature, key, top_k, top_p)
+            _validate_stopping(eos_id, pad_id, self._vocab)
+            total = len(prompt) + int(num_steps)
+            if len(prompt) < 1:
+                raise ValueError("prompt must hold at least one token")
+            if total > self.max_len:
+                raise ValueError(
+                    f"prompt ({len(prompt)}) + num_steps ({num_steps}) = "
+                    f"{total} exceeds the engine's max_len {self.max_len}")
+            with self._qlock:
                 if self._dead is not None:
-                    self.stats["requests_rejected"] += 1
                     raise EngineDead(str(self._dead)) from self._dead
                 if self._draining:
-                    self.stats["requests_rejected"] += 1
                     raise Draining("serving engine is draining; admission "
                                    "stopped")
-            self._q_push(handle)
-            self.stats["queue_peak"] = max(self.stats["queue_peak"],
-                                           self._qdepth)
-            self._have_work.notify()
-            qd = self._qdepth
-        self._publish_load(qd=qd)
-        return handle
+                pol = self._tenants.get(tenant)
+                if pol is not None and not pol._take(time.monotonic()):
+                    # policy refusal BEFORE requests_submitted so drain()'s
+                    # terminal accounting never waits on a refused request;
+                    # per-tenant so one tenant's refusals don't dilute the
+                    # global shed_rate (requests_rejected untouched)
+                    self._tenant_stats(tenant)["quota_refused"] += 1
+                    self.stats["quota_refused"] += 1
+                    raise QuotaExceeded(
+                        f"tenant {tenant!r} over its token-bucket quota "
+                        f"({pol.rate}/s, burst {pol.burst})")
+                handle = RequestHandle(rid, prompt, num_steps,
+                                       temperature, top_k, top_p, eos_id,
+                                       pad_id, key, deadline_s=deadline_s,
+                                       tenant=tenant, priority=priority)
+                self.stats["requests_submitted"] += 1
+                tstats = self._tenant_stats(tenant)
+                tstats["submitted"] += 1
+                if num_steps == 0:  # nothing to generate: complete in place
+                    handle._finish("empty")
+                    self.stats["requests_completed"] += 1
+                    tstats["completed"] += 1
+                    return handle
+                while self._qdepth >= self.queue_capacity:
+                    if not block or not self._not_full.wait(timeout=timeout):
+                        self.stats["requests_rejected"] += 1
+                        tstats["shed"] += 1
+                        raise QueueFull(
+                            f"admission queue at capacity "
+                            f"({self.queue_capacity}); request {handle.id} "
+                            f"shed")
+                    # _declare_dead / drain notify _not_full while we wait —
+                    # re-check on every wake or the request lands in a queue no
+                    # scheduler will ever pop (result() would hang forever).
+                    # Both raises count as admission sheds (requests_rejected)
+                    # so the terminal accounting drain() sums stays balanced.
+                    if self._dead is not None:
+                        self.stats["requests_rejected"] += 1
+                        raise EngineDead(str(self._dead)) from self._dead
+                    if self._draining:
+                        self.stats["requests_rejected"] += 1
+                        raise Draining("serving engine is draining; admission "
+                                       "stopped")
+                self._q_push(handle)
+                self.stats["queue_peak"] = max(self.stats["queue_peak"],
+                                               self._qdepth)
+                self._have_work.notify()
+                qd = self._qdepth
+            self._publish_load(qd=qd)
+            return handle
 
     def submit_prefilled(self, blocks, prompt, first_token: int,
                          num_steps: int, temperature: float = 0.0,
@@ -2377,64 +2388,68 @@ class ServingEngine:
                     f"{c['k'].dtype}, this arena holds "
                     f"{mine['k'].shape[1:]} {mine['k'].dtype}")
         _validate_stopping(eos_id, pad_id, self._vocab)
-        key = np.asarray(kvb.key, np.uint32)
-        with self._qlock:
-            if self._dead is not None:
-                raise EngineDead(str(self._dead)) from self._dead
-            if self._draining:
-                raise Draining("serving engine is draining; admission "
-                               "stopped")
-            pol = self._tenants.get(tenant)
-            if pol is not None and not pol._take(time.monotonic()):
-                self._tenant_stats(tenant)["quota_refused"] += 1
-                self.stats["quota_refused"] += 1
-                raise QuotaExceeded(
-                    f"tenant {tenant!r} over its token-bucket quota "
-                    f"({pol.rate}/s, burst {pol.burst})")
-            self._next_id += 1
-            handle = RequestHandle(self._next_id, prompt, num_steps,
-                                   temperature, top_k, top_p, eos_id,
-                                   pad_id, key, deadline_s=deadline_s,
-                                   tenant=tenant, priority=priority)
-            handle.kvblocks = kvb
-            self.stats["requests_submitted"] += 1
-            tstats = self._tenant_stats(tenant)
-            tstats["submitted"] += 1
-            # the shipped first token IS this request's first generated
-            # token: push it now (TTFT on this engine is the hand-off
-            # instant) and complete in place when it already terminates
-            handle._push(int(first_token))
-            if (eos_id is not None and int(first_token) == int(eos_id)) \
-                    or num_steps == 1:
-                reason = ("eos" if eos_id is not None
-                          and int(first_token) == int(eos_id) else "length")
-                handle._finish(reason)
-                self.stats["requests_completed"] += 1
-                tstats["completed"] += 1
-                self.stats["tokens_generated"] += 1
-                return handle
-            while self._qdepth >= self.queue_capacity:
-                if not block or not self._not_full.wait(timeout=timeout):
-                    self.stats["requests_rejected"] += 1
-                    tstats["shed"] += 1
-                    raise QueueFull(
-                        f"admission queue at capacity "
-                        f"({self.queue_capacity}); request {handle.id} shed")
+        rid = next(self._ids)
+        with span("serve.submit", rid=rid, prompt=len(prompt),
+                  steps=int(num_steps)):
+            key = np.asarray(kvb.key, np.uint32)
+            with self._qlock:
                 if self._dead is not None:
-                    self.stats["requests_rejected"] += 1
                     raise EngineDead(str(self._dead)) from self._dead
                 if self._draining:
-                    self.stats["requests_rejected"] += 1
                     raise Draining("serving engine is draining; admission "
                                    "stopped")
-            self.stats["tokens_generated"] += 1
-            self._q_push(handle)
-            self.stats["queue_peak"] = max(self.stats["queue_peak"],
-                                           self._qdepth)
-            self._have_work.notify()
-            qd = self._qdepth
-        self._publish_load(qd=qd)
-        return handle
+                pol = self._tenants.get(tenant)
+                if pol is not None and not pol._take(time.monotonic()):
+                    self._tenant_stats(tenant)["quota_refused"] += 1
+                    self.stats["quota_refused"] += 1
+                    raise QuotaExceeded(
+                        f"tenant {tenant!r} over its token-bucket quota "
+                        f"({pol.rate}/s, burst {pol.burst})")
+                handle = RequestHandle(rid, prompt, num_steps,
+                                       temperature, top_k, top_p, eos_id,
+                                       pad_id, key, deadline_s=deadline_s,
+                                       tenant=tenant, priority=priority)
+                handle.kvblocks = kvb
+                self.stats["requests_submitted"] += 1
+                tstats = self._tenant_stats(tenant)
+                tstats["submitted"] += 1
+                # the shipped first token IS this request's first generated
+                # token: push it now (TTFT on this engine is the hand-off
+                # instant) and complete in place when it already terminates
+                handle._push(int(first_token))
+                if (eos_id is not None and int(first_token) == int(eos_id)) \
+                        or num_steps == 1:
+                    reason = ("eos" if eos_id is not None
+                              and int(first_token) == int(eos_id)
+                              else "length")
+                    handle._finish(reason)
+                    self.stats["requests_completed"] += 1
+                    tstats["completed"] += 1
+                    self.stats["tokens_generated"] += 1
+                    return handle
+                while self._qdepth >= self.queue_capacity:
+                    if not block or not self._not_full.wait(timeout=timeout):
+                        self.stats["requests_rejected"] += 1
+                        tstats["shed"] += 1
+                        raise QueueFull(
+                            f"admission queue at capacity "
+                            f"({self.queue_capacity}); request {handle.id} "
+                            f"shed")
+                    if self._dead is not None:
+                        self.stats["requests_rejected"] += 1
+                        raise EngineDead(str(self._dead)) from self._dead
+                    if self._draining:
+                        self.stats["requests_rejected"] += 1
+                        raise Draining("serving engine is draining; admission "
+                                       "stopped")
+                self.stats["tokens_generated"] += 1
+                self._q_push(handle)
+                self.stats["queue_peak"] = max(self.stats["queue_peak"],
+                                               self._qdepth)
+                self._have_work.notify()
+                qd = self._qdepth
+            self._publish_load(qd=qd)
+            return handle
 
     @property
     def queue_depth(self) -> int:
@@ -2457,28 +2472,30 @@ class ServingEngine:
         Everything else read here is scheduler-confined (``_free``,
         ``_active``, the trie counter) or an already-synchronised stats
         counter — stale-by-one is fine for routing."""
-        prev = self._load_snapshot
-        stats = self.stats
-        with self._qlock:
-            qi = self._q_int
-        self._load_snapshot = {
-            "queue_depth": prev["queue_depth"] if qd is None else int(qd),
-            "slots_free": len(self._free),
-            "slots_total": self.num_slots,
-            "active": int(self._active.sum()),
-            "trie_blocks": (self._pool.trie_nodes if self.paged else 0),
-            "queue_capacity": self.queue_capacity,
-            "max_len": self.max_len,
-            "draining": (prev["draining"] if draining is None
-                         else bool(draining)),
-            "dead": prev["dead"] if dead is None else bool(dead),
-            "prefix_hit_tokens": stats["prefix_hit_tokens"],
-            "prefill_tokens": stats["prefill_tokens"],
-            "tokens_generated": stats["tokens_generated"],
-            "requests_completed": stats["requests_completed"],
-            "requests_failed": stats["requests_failed"],
-            "queued_interactive": qi,
-        }
+        with span("serve.publish"):
+            prev = self._load_snapshot
+            stats = self.stats
+            with self._qlock:
+                qi = self._q_int
+            self._load_snapshot = {
+                "queue_depth": (prev["queue_depth"] if qd is None
+                                else int(qd)),
+                "slots_free": len(self._free),
+                "slots_total": self.num_slots,
+                "active": int(self._active.sum()),
+                "trie_blocks": (self._pool.trie_nodes if self.paged else 0),
+                "queue_capacity": self.queue_capacity,
+                "max_len": self.max_len,
+                "draining": (prev["draining"] if draining is None
+                             else bool(draining)),
+                "dead": prev["dead"] if dead is None else bool(dead),
+                "prefix_hit_tokens": stats["prefix_hit_tokens"],
+                "prefill_tokens": stats["prefill_tokens"],
+                "tokens_generated": stats["tokens_generated"],
+                "requests_completed": stats["requests_completed"],
+                "requests_failed": stats["requests_failed"],
+                "queued_interactive": qi,
+            }
 
     def load(self) -> Dict[str, Any]:
         """Cheap read-only load snapshot for routing decisions: queue
@@ -2714,7 +2731,9 @@ class ServingEngine:
                 h = self._pop_queued()
                 if h is None:
                     break
-                if not self._ingest(h):
+                with span("serve.admit", rid=h.id):
+                    admitted = self._ingest(h)
+                if not admitted:
                     with self._qlock:
                         self._q_push(h, front=True)
                     break
@@ -2735,7 +2754,8 @@ class ServingEngine:
                 break
             plan = None
             if self.paged:
-                plan = self._admit_blocks(h)
+                with span("serve.admit", rid=h.id):
+                    plan = self._admit_blocks(h)
                 if plan is None:
                     # no blocks even after eviction: requeue at the FRONT
                     # and stop admitting — retirements will free blocks.
@@ -2752,7 +2772,10 @@ class ServingEngine:
             budget -= 1
             did = True
             if self.prefill_mode == "eager":
-                self._prefill(self._free.pop(), h)
+                with span("serve.prefill_unit", rid=h.id,
+                          tokens=len(h.prompt), kind="eager",
+                          width=len(h.prompt), hit=0):
+                    self._prefill(self._free.pop(), h)
             elif (len(h.prompt) - (plan.matched if plan else 0)
                     > self.prefill_chunk):
                 self._start_chunked(self._free.pop(), h, plan)
@@ -2876,79 +2899,85 @@ class ServingEngine:
         suffix length and pass each row's match frontier + block-table
         row; ``prefill_tokens`` counts only what is actually prefilled
         (the hit tokens live in ``prefix_hit_tokens``)."""
+        def matched(h):
+            return plans[h.id].matched if (plans and h.id in plans) else 0
+
         groups: Dict[int, List[RequestHandle]] = {}
         for h in batch:
-            matched = plans[h.id].matched if (plans and h.id in plans) \
-                else 0
-            groups.setdefault(self._bucket_of(len(h.prompt) - matched),
+            groups.setdefault(self._bucket_of(len(h.prompt) - matched(h)),
                               []).append(h)
         for width, group in groups.items():
-            nb = self.prefills_per_step
-            prompts = np.zeros((nb, width), np.int32)
-            match = np.zeros((nb,), np.int32)
-            p_lens = np.ones((nb,), np.int32)
-            slots = np.full((nb,), self.num_slots, np.int32)
-            r_temp = np.zeros((nb,), np.float32)
-            r_topk = np.zeros((nb,), np.int32)
-            r_topp = np.zeros((nb,), np.float32)
-            r_keys = np.zeros((nb, 2), np.uint32)
-            if self.paged:
-                row_bt = np.full((nb, self._t_tbl), self.kv_blocks,
-                                 np.int32)
-                row_dbt = (np.full((nb, self._d_tbl), self.kv_blocks,
-                                   np.int32)
-                           if self._draft_model is not None else None)
-            entries: List[Tuple[int, RequestHandle]] = []
-            for i, h in enumerate(group):
-                slot = self._free.pop()
-                p = len(h.prompt)
-                m = 0
+            hit = sum(matched(h) for h in group)
+            with span("serve.prefill_unit", rid=group[0].id,
+                      tokens=sum(len(h.prompt) for h in group) - hit,
+                      kind="bucket", width=width, hit=hit):
+                nb = self.prefills_per_step
+                prompts = np.zeros((nb, width), np.int32)
+                match = np.zeros((nb,), np.int32)
+                p_lens = np.ones((nb,), np.int32)
+                slots = np.full((nb,), self.num_slots, np.int32)
+                r_temp = np.zeros((nb,), np.float32)
+                r_topk = np.zeros((nb,), np.int32)
+                r_topp = np.zeros((nb,), np.float32)
+                r_keys = np.zeros((nb, 2), np.uint32)
                 if self.paged:
-                    plan = plans[h.id]
-                    m = plan.matched
-                    self._plans[slot] = plan
-                    rb, rd = self._row_tables(plan)
-                    row_bt[i] = rb
-                    if rd is not None:
-                        row_dbt[i] = rd
-                prompts[i, :p - m] = h.prompt[m:]
-                match[i] = m
-                p_lens[i] = p
-                slots[i] = slot
-                r_temp[i] = h.temperature
-                r_topk[i] = 0 if h.top_k is None else int(h.top_k)
-                r_topp[i] = 0.0 if h.top_p is None else float(h.top_p)
-                r_keys[i] = np.asarray(h.key, np.uint32)
-                h.slot = slot
-                h.started_at = time.perf_counter()
-                self._handles[slot] = h
-                self._mirror_admit(slot, h)
-                self.stats["prefills"] += 1
-                self.stats["slot_requests"][slot] += 1
-                self.stats["prefill_tokens"] += p - m
-                entries.append((slot, h))
-            if self.paged:
-                extra = [self._put(prompts), self._put(match),
-                         self._put(p_lens), self._put(slots),
-                         self._put(row_bt)]
-                if row_dbt is not None:
-                    extra.append(self._put(row_dbt))
-                first = self._apply_state(self._bucket_fn(width)(
-                    *self._prog_args(), *extra, self._put(r_temp),
-                    self._put(r_topk), self._put(r_topp),
-                    self._put(r_keys)))
-            else:
-                first = self._apply_state(self._bucket_fn(width)(
-                    *self._prog_args(), self._put(prompts),
-                    self._put(p_lens), self._put(slots), self._put(r_temp),
-                    self._put(r_topk), self._put(r_topp),
-                    self._put(r_keys)))
-            self.stats["prefill_batches"] += 1
-            self.stats["prefill_batched_requests"] += len(group)
-            self.stats["prefill_batch_size_mean"] = round(
-                self.stats["prefill_batched_requests"]
-                / self.stats["prefill_batches"], 3)
-            self._pending.append(("prefill", first, entries))
+                    row_bt = np.full((nb, self._t_tbl), self.kv_blocks,
+                                     np.int32)
+                    row_dbt = (np.full((nb, self._d_tbl), self.kv_blocks,
+                                       np.int32)
+                               if self._draft_model is not None else None)
+                entries: List[Tuple[int, RequestHandle]] = []
+                for i, h in enumerate(group):
+                    slot = self._free.pop()
+                    p = len(h.prompt)
+                    m = 0
+                    if self.paged:
+                        plan = plans[h.id]
+                        m = plan.matched
+                        self._plans[slot] = plan
+                        rb, rd = self._row_tables(plan)
+                        row_bt[i] = rb
+                        if rd is not None:
+                            row_dbt[i] = rd
+                    prompts[i, :p - m] = h.prompt[m:]
+                    match[i] = m
+                    p_lens[i] = p
+                    slots[i] = slot
+                    r_temp[i] = h.temperature
+                    r_topk[i] = 0 if h.top_k is None else int(h.top_k)
+                    r_topp[i] = 0.0 if h.top_p is None else float(h.top_p)
+                    r_keys[i] = np.asarray(h.key, np.uint32)
+                    h.slot = slot
+                    h.started_at = time.perf_counter()
+                    self._handles[slot] = h
+                    self._mirror_admit(slot, h)
+                    self.stats["prefills"] += 1
+                    self.stats["slot_requests"][slot] += 1
+                    self.stats["prefill_tokens"] += p - m
+                    entries.append((slot, h))
+                if self.paged:
+                    extra = [self._put(prompts), self._put(match),
+                             self._put(p_lens), self._put(slots),
+                             self._put(row_bt)]
+                    if row_dbt is not None:
+                        extra.append(self._put(row_dbt))
+                    first = self._apply_state(self._bucket_fn(width)(
+                        *self._prog_args(), *extra, self._put(r_temp),
+                        self._put(r_topk), self._put(r_topp),
+                        self._put(r_keys)))
+                else:
+                    first = self._apply_state(self._bucket_fn(width)(
+                        *self._prog_args(), self._put(prompts),
+                        self._put(p_lens), self._put(slots), self._put(r_temp),
+                        self._put(r_topk), self._put(r_topp),
+                        self._put(r_keys)))
+                self.stats["prefill_batches"] += 1
+                self.stats["prefill_batched_requests"] += len(group)
+                self.stats["prefill_batch_size_mean"] = round(
+                    self.stats["prefill_batched_requests"]
+                    / self.stats["prefill_batches"], 3)
+                self._pending.append(("prefill", first, entries,
+                                      self.stats["decode_steps"]))
 
     def _start_chunked(self, slot: int, h: RequestHandle,
                        plan: Optional[_BlockPlan] = None) -> None:
@@ -2983,6 +3012,7 @@ class ServingEngine:
         self._prefilling[slot] = job
         self.stats["prefills"] += 1
         self.stats["slot_requests"][slot] += 1
+        job.hit = job.written
         self._advance_chunk(slot)
 
     def _advance_chunk(self, slot: int) -> None:
@@ -2999,76 +3029,80 @@ class ServingEngine:
             width, real, final = self._chunk_width, self._chunk_width, False
         else:
             width, real, final = self._bucket_of(remaining), remaining, True
-        toks = np.zeros((1, width), np.int32)
-        toks[0, :real] = h.prompt[offset:offset + real]
-        toks_d = self._put(toks)
-        self.stats["prefill_chunks"] += 1
-        self.stats["prefill_tokens"] += real
-        paged_direct = self.paged and not self.rolling
-        if paged_direct:
-            off_vec = self._put(np.asarray([offset], np.int32))
-            plen_vec = self._put(np.asarray([p_len], np.int32))
-        if not final:
+        hit, job.hit = job.hit, 0
+        with span("serve.prefill_unit", rid=h.id, tokens=real,
+                  kind="final" if final else "chunk", width=width, hit=hit):
+            toks = np.zeros((1, width), np.int32)
+            toks[0, :real] = h.prompt[offset:offset + real]
+            toks_d = self._put(toks)
+            self.stats["prefill_chunks"] += 1
+            self.stats["prefill_tokens"] += real
+            paged_direct = self.paged and not self.rolling
             if paged_direct:
-                if self._draft_model is not None:
-                    self.caches, self.d_caches = self._stage_fn(width)(
-                        self.params, self._draft_params, self.caches,
-                        self.d_caches, toks_d, off_vec, plen_vec,
-                        job.bt, job.dbt)
+                off_vec = self._put(np.asarray([offset], np.int32))
+                plen_vec = self._put(np.asarray([p_len], np.int32))
+            if not final:
+                if paged_direct:
+                    if self._draft_model is not None:
+                        self.caches, self.d_caches = self._stage_fn(width)(
+                            self.params, self._draft_params, self.caches,
+                            self.d_caches, toks_d, off_vec, plen_vec,
+                            job.bt, job.dbt)
+                    else:
+                        self.caches = self._stage_fn(width)(
+                            self.params, self.caches, toks_d, off_vec,
+                            plen_vec, job.bt)
+                elif self._draft_model is not None:
+                    job.staging, job.d_staging = self._stage_fn(width)(
+                        self.params, self._draft_params, job.staging,
+                        job.d_staging, toks_d, offset)
                 else:
-                    self.caches = self._stage_fn(width)(
-                        self.params, self.caches, toks_d, off_vec,
-                        plen_vec, job.bt)
-            elif self._draft_model is not None:
-                job.staging, job.d_staging = self._stage_fn(width)(
-                    self.params, self._draft_params, job.staging,
-                    job.d_staging, toks_d, offset)
+                    job.staging = self._stage_fn(width)(
+                        self.params, job.staging, toks_d, offset)
             else:
-                job.staging = self._stage_fn(width)(
-                    self.params, job.staging, toks_d, offset)
-        else:
-            if paged_direct:
-                if self._draft_model is not None:
-                    first = self._apply_state(self._final_fn(width)(
-                        *self._prog_args(), toks_d, slot, off_vec,
-                        plen_vec, real - 1, job.bt, job.dbt,
-                        *self._sampling_row(h)))
-                else:
-                    first = self._apply_state(self._final_fn(width)(
-                        *self._prog_args(), toks_d, slot, off_vec,
-                        plen_vec, real - 1, job.bt,
-                        *self._sampling_row(h)))
-            elif self.paged:  # rolling: staged chunks, block-table commit
-                if self._draft_model is not None:
+                if paged_direct:
+                    if self._draft_model is not None:
+                        first = self._apply_state(self._final_fn(width)(
+                            *self._prog_args(), toks_d, slot, off_vec,
+                            plen_vec, real - 1, job.bt, job.dbt,
+                            *self._sampling_row(h)))
+                    else:
+                        first = self._apply_state(self._final_fn(width)(
+                            *self._prog_args(), toks_d, slot, off_vec,
+                            plen_vec, real - 1, job.bt,
+                            *self._sampling_row(h)))
+                elif self.paged:  # rolling: staged chunks, block-table commit
+                    if self._draft_model is not None:
+                        first = self._apply_state(self._final_fn(width)(
+                            *self._prog_args(), job.staging, job.d_staging,
+                            toks_d, slot, offset, real - 1, p_len,
+                            job.bt, job.dbt, *self._sampling_row(h)))
+                    else:
+                        first = self._apply_state(self._final_fn(width)(
+                            *self._prog_args(), job.staging, toks_d, slot,
+                            offset, real - 1, p_len, job.bt,
+                            *self._sampling_row(h)))
+                elif self._draft_model is not None:
                     first = self._apply_state(self._final_fn(width)(
                         *self._prog_args(), job.staging, job.d_staging,
                         toks_d, slot, offset, real - 1, p_len,
-                        job.bt, job.dbt, *self._sampling_row(h)))
+                        *self._sampling_row(h)))
                 else:
                     first = self._apply_state(self._final_fn(width)(
-                        *self._prog_args(), job.staging, toks_d, slot,
-                        offset, real - 1, p_len, job.bt,
-                        *self._sampling_row(h)))
-            elif self._draft_model is not None:
-                first = self._apply_state(self._final_fn(width)(
-                    *self._prog_args(), job.staging, job.d_staging,
-                    toks_d, slot, offset, real - 1, p_len,
-                    *self._sampling_row(h)))
-            else:
-                first = self._apply_state(self._final_fn(width)(
-                    *self._prog_args(), job.staging, toks_d,
-                    slot, offset, real - 1, p_len, *self._sampling_row(h)))
-            job.staging = None
-            job.d_staging = None
-            if self.paged:
-                # the chain's contents are now fully dispatched: publish
-                # the prompt's full blocks into the prefix trie
-                self._pool.publish(self._plans[slot], h.prompt)
-        job.written += real
-        if final:
-            del self._prefilling[slot]
-            self._mirror_admit(slot, h)
-            self._pending.append(("prefill", first, [(slot, h)]))
+                        *self._prog_args(), job.staging, toks_d,
+                        slot, offset, real - 1, p_len, *self._sampling_row(h)))
+                job.staging = None
+                job.d_staging = None
+                if self.paged:
+                    # the chain's contents are now fully dispatched: publish
+                    # the prompt's full blocks into the prefix trie
+                    self._pool.publish(self._plans[slot], h.prompt)
+            job.written += real
+            if final:
+                del self._prefilling[slot]
+                self._mirror_admit(slot, h)
+                self._pending.append(("prefill", first, [(slot, h)],
+                                      self.stats["decode_steps"]))
 
     def _mirror_admit(self, slot: int, h: RequestHandle) -> None:
         """Host mirrors of the per-slot state the prefill program just set
@@ -3129,40 +3163,41 @@ class ServingEngine:
 
     def _retire(self, slot: int, reason: str) -> None:
         h = self._handles[slot]
-        self._handles[slot] = None
-        self._active[slot] = False
-        self._temp[slot] = 0.0
-        self._topk[slot] = 0
-        self._topp[slot] = 0.0
-        self._positions[slot] = 0
-        self._cur_tok[slot] = 0
-        self._free.append(slot)
-        if self.prefill_mode == "bucketed":
-            # deactivate the device row too: an in-flight lookahead step
-            # may compute one junk token for it (drained entries skip
-            # finished handles), but from the next dispatch on the slot is
-            # inert until a prefill program rewrites it.  Paged: the
-            # block-table row is re-nulled IN THE SAME program, so that
-            # junk (and every later idle pass) drops into the null block
-            # while the released blocks go back to the allocator — the
-            # one in-flight lookahead write ordered before any program
-            # that could reuse them
-            if self.paged:
-                if self._draft_model is None:
-                    self._dev_act, self._dev_bt = self._deact_fn(
-                        self._dev_act, self._dev_bt, slot)
+        with span("serve.retire", rid=h.id, reason=reason):
+            self._handles[slot] = None
+            self._active[slot] = False
+            self._temp[slot] = 0.0
+            self._topk[slot] = 0
+            self._topp[slot] = 0.0
+            self._positions[slot] = 0
+            self._cur_tok[slot] = 0
+            self._free.append(slot)
+            if self.prefill_mode == "bucketed":
+                # deactivate the device row too: an in-flight lookahead step
+                # may compute one junk token for it (drained entries skip
+                # finished handles), but from the next dispatch on the slot is
+                # inert until a prefill program rewrites it.  Paged: the
+                # block-table row is re-nulled IN THE SAME program, so that
+                # junk (and every later idle pass) drops into the null block
+                # while the released blocks go back to the allocator — the
+                # one in-flight lookahead write ordered before any program
+                # that could reuse them
+                if self.paged:
+                    if self._draft_model is None:
+                        self._dev_act, self._dev_bt = self._deact_fn(
+                            self._dev_act, self._dev_bt, slot)
+                    else:
+                        (self._dev_act, self._dev_bt,
+                         self._dev_dbt) = self._deact_fn(
+                            self._dev_act, self._dev_bt, self._dev_dbt, slot)
+                    self._release_blocks(slot)
                 else:
-                    (self._dev_act, self._dev_bt,
-                     self._dev_dbt) = self._deact_fn(
-                        self._dev_act, self._dev_bt, self._dev_dbt, slot)
-                self._release_blocks(slot)
-            else:
-                self._dev_act = self._deact_fn(self._dev_act, slot)
-        if h._finish(reason):  # no-op when _declare_dead already failed it
-            with self._qlock:  # drain()'s busy() sums this cross-thread
-                self.stats["requests_completed"] += 1
-                self._tenant_stats(h.tenant)["completed"] += 1
-            self._account_terminal(h, reason, time.perf_counter())
+                    self._dev_act = self._deact_fn(self._dev_act, slot)
+            if h._finish(reason):  # no-op when _declare_dead already failed it
+                with self._qlock:  # drain()'s busy() sums this cross-thread
+                    self.stats["requests_completed"] += 1
+                    self._tenant_stats(h.tenant)["completed"] += 1
+                self._account_terminal(h, reason, time.perf_counter())
 
     # ----------------------------------------------- preemption (QoS swap)
     def preempt(self, handle: RequestHandle) -> bool:
@@ -3413,77 +3448,91 @@ class ServingEngine:
         ADVANCED onto a multiple of the reload cadence — a reap- or
         prefill-only iteration leaves the counter parked and must not
         re-pull on every pass."""
-        self.last_beat = time.monotonic()
-        steps_before = self.stats["decode_steps"]
-        did = self._reap()
-        if self._can_preempt:
-            with self._qlock:
-                qos_work = bool(self._preempt_ids or self._suspended
-                                or self._q_int)
-            if qos_work or self._int_blocked:
-                did = self._balance_qos() or did
-        did = self._schedule_prefills() or did
-        if self.role == "prefill":
-            # no token loop at all: drain every dispatched prefill NOW
-            # (the drained first token triggers extraction + hand-off —
-            # with decode gated off, nothing else would ever push a
-            # lookahead entry out of the pipeline)
+        self._iterations += 1
+        with span("serve.iteration", it=self._iterations,
+                  active=int(np.count_nonzero(self._active))):
+            self.last_beat = time.monotonic()
+            steps_before = self.stats["decode_steps"]
+            with span("serve.reap"):
+                did = self._reap()
+            if self._can_preempt:
+                with self._qlock:
+                    qos_work = bool(self._preempt_ids or self._suspended
+                                    or self._q_int)
+                if qos_work or self._int_blocked:
+                    with span("serve.qos"):
+                        did = self._balance_qos() or did
+            with span("serve.schedule"):
+                did = self._schedule_prefills() or did
+            if self.role == "prefill":
+                # no token loop at all: drain every dispatched prefill NOW
+                # (the drained first token triggers extraction + hand-off —
+                # with decode gated off, nothing else would ever push a
+                # lookahead entry out of the pipeline)
+                if self._pending:
+                    did = self._drain_pending(flush=True) or did
+                self._publish_load()
+                return did
+            if self._active.any():
+                self._decode_once()
+                did = True
             if self._pending:
-                did = self._drain_pending(flush=True) or did
+                did = self._drain_pending(flush=not self._active.any()) or did
+            if (self._reload_every
+                    and self.stats["decode_steps"] > steps_before
+                    and self.stats["decode_steps"] % self._reload_every == 0):
+                with span("serve.reload"):
+                    self._pull_weights()
             self._publish_load()
             return did
-        if self._active.any():
-            self._decode_once()
-            did = True
-        if self._pending:
-            did = self._drain_pending(flush=not self._active.any()) or did
-        if (self._reload_every
-                and self.stats["decode_steps"] > steps_before
-                and self.stats["decode_steps"] % self._reload_every == 0):
-            self._pull_weights()
-        self._publish_load()
-        return did
 
     def _decode_once(self) -> None:
         if self.prefill_mode == "eager":
-            nxt, self.caches = self._step_fn(
-                self.params, self.caches, jnp.asarray(self._cur_tok),
-                jnp.asarray(self._positions), jnp.asarray(self._active),
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(self._keys))
-            nxt = np.asarray(nxt)
             self.stats["decode_steps"] += 1
-            self.stats["active_slot_steps"] += int(self._active.sum())
-            for slot in np.flatnonzero(self._active):
-                self._positions[slot] += 1
-                self._cur_tok[slot] = nxt[slot]
-                self._emit(int(slot), int(nxt[slot]))
+            step = self.stats["decode_steps"]
+            live = np.flatnonzero(self._active)
+            with span("serve.decode_dispatch", active=len(live), step=step):
+                nxt, self.caches = self._step_fn(
+                    self.params, self.caches, jnp.asarray(self._cur_tok),
+                    jnp.asarray(self._positions), jnp.asarray(self._active),
+                    jnp.asarray(self._temp), jnp.asarray(self._topk),
+                    jnp.asarray(self._topp), jnp.asarray(self._keys))
+            with span("serve.fetch", step=step):
+                nxt = np.asarray(nxt)
+            self.stats["active_slot_steps"] += len(live)
+            with span("serve.emit", kind="decode", rows=len(live),
+                      step=step):
+                for slot in live:
+                    self._positions[slot] += 1
+                    self._cur_tok[slot] = nxt[slot]
+                    self._emit(int(slot), int(nxt[slot]))
             return
         # bucketed: dispatch only — every argument is already a device
         # array (zero uploads), and the sampled row is fetched one
         # iteration later by _drain_pending (one-step lookahead)
         entries = [(int(s), self._handles[s])
                    for s in np.flatnonzero(self._active)]
-        if self._draft_model is not None:
-            # speculative round: k draft steps + one batched verify in ONE
-            # program; rows commit 1..spec_len+1 tokens each, packed with
-            # their per-row counts into the one drained array
-            (out, self.caches, self.d_caches, self._dev_tok,
-             self._dev_pos) = self._spec_fn(
-                self.params, self._draft_params, *self._state_args())
-            self.stats["decode_steps"] += 1
-            self.stats["verify_calls"] += 1
-            self.stats["target_calls"] += 1
-            self.stats["drafted"] += self.spec_len * len(entries)
-            self.stats["active_slot_steps"] += len(entries)
-            self._pending.append(("spec", out, entries))
-            return
-        out, self.caches, self._dev_pos = self._decode_fn(
-            self.params, *self._state_args())
-        self._dev_tok = out
         self.stats["decode_steps"] += 1
-        self.stats["active_slot_steps"] += len(entries)
-        self._pending.append(("decode", out, entries))
+        step = self.stats["decode_steps"]
+        with span("serve.decode_dispatch", active=len(entries), step=step):
+            if self._draft_model is not None:
+                # speculative round: k draft steps + one batched verify in
+                # ONE program; rows commit 1..spec_len+1 tokens each, packed
+                # with their per-row counts into the one drained array
+                (out, self.caches, self.d_caches, self._dev_tok,
+                 self._dev_pos) = self._spec_fn(
+                    self.params, self._draft_params, *self._state_args())
+                self.stats["verify_calls"] += 1
+                self.stats["target_calls"] += 1
+                self.stats["drafted"] += self.spec_len * len(entries)
+                self.stats["active_slot_steps"] += len(entries)
+                self._pending.append(("spec", out, entries, step))
+                return
+            out, self.caches, self._dev_pos = self._decode_fn(
+                self.params, *self._state_args())
+            self._dev_tok = out
+            self.stats["active_slot_steps"] += len(entries)
+            self._pending.append(("decode", out, entries, step))
 
     def _drain_pending(self, flush: bool = False) -> bool:
         """Emit the tokens of in-flight steps older than the lookahead
@@ -3495,36 +3544,39 @@ class ServingEngine:
         did = False
         keep = 0 if flush else self._lookahead
         while len(self._pending) > keep:
-            kind, arr, entries = self._pending.popleft()
-            vals = self._fetch(arr)
-            for i, (slot, h) in enumerate(entries):
-                if h.finish is not None or self._handles[slot] is not h:
-                    continue
-                if kind == "spec":
-                    # row ``slot`` committed n tokens this round (its
-                    # per-row accept length + 1); emit in order, stopping
-                    # the moment eos/length retires the request — the
-                    # round's trailing tokens die here, like any
-                    # lookahead junk
-                    n = int(vals[slot, -1])
-                    self.stats["accepted"] += max(n - 1, 0)
-                    self._positions[slot] += n
-                    for j in range(n):
-                        token = int(vals[slot, j])
-                        self._cur_tok[slot] = token
+            kind, arr, entries, step = self._pending.popleft()
+            with span("serve.fetch", step=step):
+                vals = self._fetch(arr)
+            with span("serve.emit", kind=kind, rows=len(entries),
+                      step=step):
+                for i, (slot, h) in enumerate(entries):
+                    if h.finish is not None or self._handles[slot] is not h:
+                        continue
+                    if kind == "spec":
+                        # row ``slot`` committed n tokens this round (its
+                        # per-row accept length + 1); emit in order,
+                        # stopping the moment eos/length retires the
+                        # request — the round's trailing tokens die here,
+                        # like any lookahead junk
+                        n = int(vals[slot, -1])
+                        self.stats["accepted"] += max(n - 1, 0)
+                        self._positions[slot] += n
+                        for j in range(n):
+                            token = int(vals[slot, j])
+                            self._cur_tok[slot] = token
+                            self._emit(slot, token)
+                            if (h.finish is not None
+                                    or self._handles[slot] is not h):
+                                break
+                        continue
+                    token = int(vals[slot] if kind == "decode" else vals[i])
+                    if kind == "decode":
+                        self._positions[slot] += 1
+                    self._cur_tok[slot] = token
+                    if self.role == "prefill":
+                        self._finish_prefilled(slot, token)
+                    else:
                         self._emit(slot, token)
-                        if (h.finish is not None
-                                or self._handles[slot] is not h):
-                            break
-                    continue
-                token = int(vals[slot] if kind == "decode" else vals[i])
-                if kind == "decode":
-                    self._positions[slot] += 1
-                self._cur_tok[slot] = token
-                if self.role == "prefill":
-                    self._finish_prefilled(slot, token)
-                else:
-                    self._emit(slot, token)
             did = True
         return did
 
@@ -3823,22 +3875,30 @@ class ServingEngine:
         if self._active.any() or self._prefilling:
             raise RuntimeError("warmup() on an engine with active slots "
                                "would consume a real decode step")
+
+        def program(name):  # one span per program compiled
+            return span("serve.warmup", program=name)
+
         if self.prefill_mode == "eager":
-            nxt, self.caches = self._step_fn(
-                self.params, self.caches, jnp.asarray(self._cur_tok),
-                jnp.asarray(self._positions), jnp.asarray(self._active),
-                jnp.asarray(self._temp), jnp.asarray(self._topk),
-                jnp.asarray(self._topp), jnp.asarray(self._keys))
-            jax.block_until_ready(nxt)
+            with program("step"):
+                nxt, self.caches = self._step_fn(
+                    self.params, self.caches, jnp.asarray(self._cur_tok),
+                    jnp.asarray(self._positions),
+                    jnp.asarray(self._active), jnp.asarray(self._temp),
+                    jnp.asarray(self._topk), jnp.asarray(self._topp),
+                    jnp.asarray(self._keys))
+                jax.block_until_ready(nxt)
             # slot-write program: rewrite row 0 with a copy of itself (a
             # copy — the pool is donated, and XLA rejects donating a
             # buffer aliased by another argument; inactive slots hold junk
             # a prefill fully overwrites, so this is a no-op in the same
             # sense as the free-slot decode rows)
-            row = tmap(lambda B: jnp.copy(B[0:1]), self.caches)
-            self.caches = self._write_slot_fn(self.caches, row,
-                                              jnp.int32(0))
-            jax.block_until_ready(jax.tree_util.tree_leaves(self.caches)[0])
+            with program("write_slot"):
+                row = tmap(lambda B: jnp.copy(B[0:1]), self.caches)
+                self.caches = self._write_slot_fn(self.caches, row,
+                                                  jnp.int32(0))
+                jax.block_until_ready(
+                    jax.tree_util.tree_leaves(self.caches)[0])
             return self
         # bucketed: one all-slots-inactive decode step (the speculative
         # round — draft steps + verify + back-fill — when a draft is
@@ -3850,18 +3910,21 @@ class ServingEngine:
             # instead (all-null rows read the null block)
             rows = jnp.full((self._blocks_per_slot * self.block_size,),
                             self.kv_blocks * self.block_size, jnp.int32)
-            jax.block_until_ready(jax.tree_util.tree_leaves(
-                self._gather_fn(self.caches, rows))[0])
+            with program("gather"):
+                jax.block_until_ready(jax.tree_util.tree_leaves(
+                    self._gather_fn(self.caches, rows))[0])
         elif self._draft_model is not None:
-            (_, self.caches, self.d_caches, self._dev_tok,
-             self._dev_pos) = self._spec_fn(
-                self.params, self._draft_params, *self._state_args())
-            jax.block_until_ready(self._dev_tok)
+            with program("spec"):
+                (_, self.caches, self.d_caches, self._dev_tok,
+                 self._dev_pos) = self._spec_fn(
+                    self.params, self._draft_params, *self._state_args())
+                jax.block_until_ready(self._dev_tok)
         else:
-            out, self.caches, self._dev_pos = self._decode_fn(
-                self.params, *self._state_args())
-            self._dev_tok = out
-            jax.block_until_ready(out)
+            with program("decode"):
+                out, self.caches, self._dev_pos = self._decode_fn(
+                    self.params, *self._state_args())
+                self._dev_tok = out
+                jax.block_until_ready(out)
         if self.role == "decode":
             # ingest program only: the bucket/chunk prefill programs are
             # never dispatched on a decode-role engine (admission is
@@ -3875,18 +3938,20 @@ class ServingEngine:
                        {k: jnp.zeros((n,) + v.shape[1:], v.dtype)
                         for k, v in c.items()}
                        for c in self.caches]
-            (self.caches, self._dev_bt, self._dev_tok, self._dev_pos,
-             self._dev_act, self._dev_temp, self._dev_topk,
-             self._dev_topp, self._dev_keys) = self._ingest_fn(
-                self.caches, self._dev_bt, self._dev_tok, self._dev_pos,
-                self._dev_act, self._dev_temp, self._dev_topk,
-                self._dev_topp, self._dev_keys, rows, payload,
-                jnp.int32(self.num_slots),
-                jnp.full((self._t_tbl,), self.kv_blocks, jnp.int32),
-                jnp.int32(0), jnp.int32(0), jnp.float32(0.0),
-                jnp.int32(0), jnp.float32(0.0),
-                jnp.zeros((2,), jnp.uint32))
-            jax.block_until_ready(jax.tree_util.tree_leaves(self.caches)[0])
+            with program("ingest"):
+                (self.caches, self._dev_bt, self._dev_tok, self._dev_pos,
+                 self._dev_act, self._dev_temp, self._dev_topk,
+                 self._dev_topp, self._dev_keys) = self._ingest_fn(
+                    self.caches, self._dev_bt, self._dev_tok,
+                    self._dev_pos, self._dev_act, self._dev_temp,
+                    self._dev_topk, self._dev_topp, self._dev_keys, rows,
+                    payload, jnp.int32(self.num_slots),
+                    jnp.full((self._t_tbl,), self.kv_blocks, jnp.int32),
+                    jnp.int32(0), jnp.int32(0), jnp.float32(0.0),
+                    jnp.int32(0), jnp.float32(0.0),
+                    jnp.zeros((2,), jnp.uint32))
+                jax.block_until_ready(
+                    jax.tree_util.tree_leaves(self.caches)[0])
             return self
         # ...every bucket's batched prefill program (all rows dropped;
         # quantized pools and draft-pool prefill compile here too — the
@@ -3902,35 +3967,37 @@ class ServingEngine:
                                  jnp.int32)
                         if self._draft_model is not None else None)
             # the copy-on-write block-copy program (null → null)
-            if self._draft_model is None:
-                self.caches = self._copy_fn(self.caches, self.kv_blocks,
-                                            self.kv_blocks)
-            else:
-                self.caches, self.d_caches = self._copy_fn(
-                    self.caches, self.d_caches, self.kv_blocks,
-                    self.kv_blocks)
+            with program("copy"):
+                if self._draft_model is None:
+                    self.caches = self._copy_fn(self.caches, self.kv_blocks,
+                                                self.kv_blocks)
+                else:
+                    self.caches, self.d_caches = self._copy_fn(
+                        self.caches, self.d_caches, self.kv_blocks,
+                        self.kv_blocks)
         for width in self._buckets:
-            if self.paged:
-                extra = [jnp.zeros((nb, width), jnp.int32),
-                         jnp.zeros((nb,), jnp.int32),
-                         jnp.ones((nb,), jnp.int32), drop, null_bt]
-                if null_dbt is not None:
-                    extra.append(null_dbt)
-                self._apply_state(self._bucket_fn(width)(
-                    *self._prog_args(), *extra,
-                    jnp.zeros((nb,), jnp.float32),
-                    jnp.zeros((nb,), jnp.int32),
-                    jnp.zeros((nb,), jnp.float32),
-                    jnp.zeros((nb, 2), jnp.uint32)))
-            else:
-                self._apply_state(self._bucket_fn(width)(
-                    *self._prog_args(),
-                    jnp.zeros((nb, width), jnp.int32),
-                    jnp.ones((nb,), jnp.int32), drop,
-                    jnp.zeros((nb,), jnp.float32),
-                    jnp.zeros((nb,), jnp.int32),
-                    jnp.zeros((nb,), jnp.float32),
-                    jnp.zeros((nb, 2), jnp.uint32)))
+            with program(f"bucket_{width}"):
+                if self.paged:
+                    extra = [jnp.zeros((nb, width), jnp.int32),
+                             jnp.zeros((nb,), jnp.int32),
+                             jnp.ones((nb,), jnp.int32), drop, null_bt]
+                    if null_dbt is not None:
+                        extra.append(null_dbt)
+                    self._apply_state(self._bucket_fn(width)(
+                        *self._prog_args(), *extra,
+                        jnp.zeros((nb,), jnp.float32),
+                        jnp.zeros((nb,), jnp.int32),
+                        jnp.zeros((nb,), jnp.float32),
+                        jnp.zeros((nb, 2), jnp.uint32)))
+                else:
+                    self._apply_state(self._bucket_fn(width)(
+                        *self._prog_args(),
+                        jnp.zeros((nb, width), jnp.int32),
+                        jnp.ones((nb,), jnp.int32), drop,
+                        jnp.zeros((nb,), jnp.float32),
+                        jnp.zeros((nb,), jnp.int32),
+                        jnp.zeros((nb,), jnp.float32),
+                        jnp.zeros((nb, 2), jnp.uint32)))
         # ...and the chunk-step programs, when a prompt can be long enough
         # to take the chunked path at all
         if self.max_len > self.prefill_chunk:
@@ -3938,54 +4005,55 @@ class ServingEngine:
                    jnp.zeros((1,), jnp.float32),
                    jnp.zeros((1, 2), jnp.uint32))
             for width in sorted({self._chunk_width, *self._buckets}):
-                toks = jnp.zeros((1, width), jnp.int32)
-                if self.paged and not self.rolling:
-                    off = jnp.zeros((1,), jnp.int32)
-                    plen = jnp.ones((1,), jnp.int32)
-                    bt1 = null_bt[:1]
+                with program(f"chunk_{width}"):
+                    toks = jnp.zeros((1, width), jnp.int32)
+                    if self.paged and not self.rolling:
+                        off = jnp.zeros((1,), jnp.int32)
+                        plen = jnp.ones((1,), jnp.int32)
+                        bt1 = null_bt[:1]
+                        if self._draft_model is not None:
+                            self.caches, self.d_caches = self._stage_fn(width)(
+                                self.params, self._draft_params, self.caches,
+                                self.d_caches, toks, off, plen, bt1,
+                                null_dbt[:1])
+                            self._apply_state(self._final_fn(width)(
+                                *self._prog_args(), toks, self.num_slots,
+                                off, plen, 0, bt1, null_dbt[:1], *one))
+                        else:
+                            self.caches = self._stage_fn(width)(
+                                self.params, self.caches, toks, off, plen,
+                                bt1)
+                            self._apply_state(self._final_fn(width)(
+                                *self._prog_args(), toks, self.num_slots,
+                                off, plen, 0, bt1, *one))
+                        continue
+                    staging = init_cache(self.model, 1, self.max_len)
                     if self._draft_model is not None:
-                        self.caches, self.d_caches = self._stage_fn(width)(
-                            self.params, self._draft_params, self.caches,
-                            self.d_caches, toks, off, plen, bt1,
-                            null_dbt[:1])
-                        self._apply_state(self._final_fn(width)(
-                            *self._prog_args(), toks, self.num_slots,
-                            off, plen, 0, bt1, null_dbt[:1], *one))
+                        d_staging = init_cache(self._draft_model, 1,
+                                               self.max_len)
+                        staging, d_staging = self._stage_fn(width)(
+                            self.params, self._draft_params, staging,
+                            d_staging, toks, 0)
+                        if self.paged:  # rolling paged: block-table commit
+                            self._apply_state(self._final_fn(width)(
+                                *self._prog_args(), staging, d_staging, toks,
+                                self.num_slots, 0, 0, 1, null_bt[:1],
+                                null_dbt[:1], *one))
+                        else:
+                            self._apply_state(self._final_fn(width)(
+                                *self._prog_args(), staging, d_staging, toks,
+                                self.num_slots, 0, 0, 1, *one))
                     else:
-                        self.caches = self._stage_fn(width)(
-                            self.params, self.caches, toks, off, plen,
-                            bt1)
-                        self._apply_state(self._final_fn(width)(
-                            *self._prog_args(), toks, self.num_slots,
-                            off, plen, 0, bt1, *one))
-                    continue
-                staging = init_cache(self.model, 1, self.max_len)
-                if self._draft_model is not None:
-                    d_staging = init_cache(self._draft_model, 1,
-                                           self.max_len)
-                    staging, d_staging = self._stage_fn(width)(
-                        self.params, self._draft_params, staging,
-                        d_staging, toks, 0)
-                    if self.paged:  # rolling paged: block-table commit
-                        self._apply_state(self._final_fn(width)(
-                            *self._prog_args(), staging, d_staging, toks,
-                            self.num_slots, 0, 0, 1, null_bt[:1],
-                            null_dbt[:1], *one))
-                    else:
-                        self._apply_state(self._final_fn(width)(
-                            *self._prog_args(), staging, d_staging, toks,
-                            self.num_slots, 0, 0, 1, *one))
-                else:
-                    staging = self._stage_fn(width)(self.params, staging,
-                                                    toks, 0)
-                    if self.paged:
-                        self._apply_state(self._final_fn(width)(
-                            *self._prog_args(), staging, toks,
-                            self.num_slots, 0, 0, 1, null_bt[:1], *one))
-                    else:
-                        self._apply_state(self._final_fn(width)(
-                            *self._prog_args(), staging, toks,
-                            self.num_slots, 0, 0, 1, *one))
+                        staging = self._stage_fn(width)(self.params, staging,
+                                                        toks, 0)
+                        if self.paged:
+                            self._apply_state(self._final_fn(width)(
+                                *self._prog_args(), staging, toks,
+                                self.num_slots, 0, 0, 1, null_bt[:1], *one))
+                        else:
+                            self._apply_state(self._final_fn(width)(
+                                *self._prog_args(), staging, toks,
+                                self.num_slots, 0, 0, 1, *one))
         # QoS engines also pre-pay the preemption swap programs: gather
         # (all-null rows read the null block) and ingest (slot num_slots
         # drops the install, the scatter lands in the null block) — a
@@ -3998,25 +4066,27 @@ class ServingEngine:
             n = self._blocks_per_slot * self.block_size
             null_rows = jnp.full((n,), self.kv_blocks * self.block_size,
                                  jnp.int32)
-            jax.block_until_ready(jax.tree_util.tree_leaves(
-                self._swap_gather_fn(self.caches, null_rows, self._dev_tok,
-                                     self._dev_pos, self._dev_keys,
-                                     jnp.int32(0))[0])[0])
+            with program("swap_gather"):
+                jax.block_until_ready(jax.tree_util.tree_leaves(
+                    self._swap_gather_fn(self.caches, null_rows, self._dev_tok,
+                                         self._dev_pos, self._dev_keys,
+                                         jnp.int32(0))[0])[0])
             payload = [None if c is None else
                        {k: jnp.zeros((n,) + v.shape[1:], v.dtype)
                         for k, v in c.items()}
                        for c in self.caches]
-            (self.caches, self._dev_bt, self._dev_tok, self._dev_pos,
-             self._dev_act, self._dev_temp, self._dev_topk,
-             self._dev_topp, self._dev_keys) = self._swap_ingest_fn(
-                self.caches, self._dev_bt, self._dev_tok, self._dev_pos,
-                self._dev_act, self._dev_temp, self._dev_topk,
-                self._dev_topp, self._dev_keys, null_rows, payload,
-                jnp.int32(self.num_slots),
-                jnp.full((self._t_tbl,), self.kv_blocks, jnp.int32),
-                jnp.int32(0), jnp.int32(0), jnp.float32(0.0),
-                jnp.int32(0), jnp.float32(0.0),
-                jnp.zeros((2,), jnp.uint32))
+            with program("swap_ingest"):
+                (self.caches, self._dev_bt, self._dev_tok, self._dev_pos,
+                 self._dev_act, self._dev_temp, self._dev_topk,
+                 self._dev_topp, self._dev_keys) = self._swap_ingest_fn(
+                    self.caches, self._dev_bt, self._dev_tok, self._dev_pos,
+                    self._dev_act, self._dev_temp, self._dev_topk,
+                    self._dev_topp, self._dev_keys, null_rows, payload,
+                    jnp.int32(self.num_slots),
+                    jnp.full((self._t_tbl,), self.kv_blocks, jnp.int32),
+                    jnp.int32(0), jnp.int32(0), jnp.float32(0.0),
+                    jnp.int32(0), jnp.float32(0.0),
+                    jnp.zeros((2,), jnp.uint32))
         jax.block_until_ready(jax.tree_util.tree_leaves(self.caches)[0])
         return self
 
@@ -4024,7 +4094,7 @@ class ServingEngine:
         try:
             while self._running:
                 if not self.step():
-                    with self._qlock:
+                    with span("serve.idle_wait"), self._qlock:
                         self._have_work.wait_for(
                             lambda: self._qdepth > 0 or bool(self._preempt_ids)
                             or not self._running,

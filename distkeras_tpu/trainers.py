@@ -36,6 +36,7 @@ from .data.dataset import Dataset
 from .parallel import mesh as mesh_lib
 from .parallel.spmd import SPMDEngine, DistState, shape_epoch_data
 from .parallel import rules
+from .metrics import span
 
 tmap = jax.tree_util.tree_map
 
@@ -524,69 +525,89 @@ class DistributedTrainer(Trainer):
         rngs = engine.worker_rngs(self.seed + 17)
         try:
             for epoch in range(start_epoch, self.num_epoch):
-                t0 = time.time()
-                if shuffle:
-                    # deterministic per-epoch reshuffle (reference shuffles
-                    # once up front via utils.shuffle; per-epoch is strictly
-                    # better for convergence and still seed-reproducible)
-                    perm = np.random.default_rng(
-                        self.seed + epoch).permutation(len(x))
-                    xe, ye = x[perm], y[perm]
-                    se = seg[perm] if seg is not None else None
-                else:
-                    xe, ye, se = x, y, seg
-                shaped = shape_epoch_data(
-                    xe, ye, self.num_workers, self.communication_window,
-                    self.batch_size, columns_seg=se)
-                if se is not None:
-                    xb, yb, sb, mb, rounds = shaped
-                else:
-                    (xb, yb, mb, rounds), sb = shaped, None
-                first = skip_rounds if epoch == start_epoch else 0
-                if self.checkpoint_unit == "round" and ckpt is not None:
-                    # per-round stepping: same round program as the epoch
-                    # scan (bit-identical), checkpointable mid-epoch on the
-                    # global round clock.  Losses stay on device until the
-                    # epoch ends so rounds without a checkpoint dispatch
-                    # without a host sync.
-                    losses = []
-                    done = int(self._state.round_idx)
-                    for r in range(first, rounds):
-                        self._state, loss = engine.run_round(
-                            self._state, xb[r], yb[r], mb[r], rngs,
-                            s=sb[r] if sb is not None else None)
-                        losses.append(loss)
-                        done += 1
-                        if done % self.checkpoint_every == 0:
-                            # live (possibly sharded) state: npz device_gets
-                            # internally; orbax snapshots to host in save()
-                            # and writes async — per-host shards on a pod
-                            ckpt.save(done, self._state,
+                with span("train.epoch", epoch=epoch):
+                    t0 = time.time()
+                    with span("train.shuffle"):
+                        if shuffle:
+                            # deterministic per-epoch reshuffle (reference
+                            # shuffles once up front via utils.shuffle;
+                            # per-epoch is strictly better for convergence
+                            # and still seed-reproducible)
+                            perm = np.random.default_rng(
+                                self.seed + epoch).permutation(len(x))
+                            xe, ye = x[perm], y[perm]
+                            se = seg[perm] if seg is not None else None
+                        else:
+                            xe, ye, se = x, y, seg
+                    with span("train.shape"):
+                        shaped = shape_epoch_data(
+                            xe, ye, self.num_workers,
+                            self.communication_window, self.batch_size,
+                            columns_seg=se)
+                    if se is not None:
+                        xb, yb, sb, mb, rounds = shaped
+                    else:
+                        (xb, yb, mb, rounds), sb = shaped, None
+                    first = skip_rounds if epoch == start_epoch else 0
+                    if self.checkpoint_unit == "round" and ckpt is not None:
+                        # per-round stepping: same round program as the
+                        # epoch scan (bit-identical), checkpointable
+                        # mid-epoch on the global round clock.  Losses stay
+                        # on device until the epoch ends so rounds without
+                        # a checkpoint dispatch without a host sync.
+                        losses = []
+                        done = int(self._state.round_idx)
+                        for r in range(first, rounds):
+                            with span("train.dispatch", rounds=1):
+                                self._state, loss = engine.run_round(
+                                    self._state, xb[r], yb[r], mb[r], rngs,
+                                    s=sb[r] if sb is not None else None)
+                            losses.append(loss)
+                            done += 1
+                            if done % self.checkpoint_every == 0:
+                                # live (possibly sharded) state: npz
+                                # device_gets internally; orbax snapshots
+                                # to host in save() and writes async —
+                                # per-host shards on a pod
+                                with span("train.checkpoint"):
+                                    ckpt.save(done, self._state,
+                                              meta={"engine": "spmd",
+                                                    "unit": "round",
+                                                    "rounds_per_epoch": rpe})
+                        with span("train.fetch"):
+                            losses = (np.asarray(
+                                jax.device_get(jnp.stack(losses)),
+                                np.float32)
+                                if losses else np.zeros((0,), np.float32))
+                    else:
+                        with span("train.dispatch", rounds=rounds):
+                            self._state, losses = engine.run_epoch(
+                                self._state, xb, yb, mb, rngs, sb=sb)
+                        with span("train.fetch"):
+                            losses = np.asarray(losses)
+                    with span("train.log"):
+                        self.history.extend(losses.tolist())
+                        # every real row trains exactly once (tail is
+                        # padded+masked, not dropped); a resumed partial
+                        # epoch counts exactly the real rows of its
+                        # remaining rounds (mask sum)
+                        examples = (len(xe) if first == 0
+                                    else int(mb[first:].sum()))
+                        metrics.epoch(
+                            epoch, examples, time.time() - t0,
+                            float(losses.mean()) if len(losses) else 0.0)
+                    if (ckpt is not None and self.checkpoint_unit == "epoch"
+                            and (epoch + 1) % self.checkpoint_every == 0):
+                        with span("train.checkpoint"):
+                            ckpt.save(epoch + 1, self._state,
                                       meta={"engine": "spmd",
-                                            "unit": "round",
-                                            "rounds_per_epoch": rpe})
-                    losses = (np.asarray(jax.device_get(jnp.stack(losses)),
-                                         np.float32)
-                              if losses else np.zeros((0,), np.float32))
-                else:
-                    self._state, losses = engine.run_epoch(
-                        self._state, xb, yb, mb, rngs, sb=sb)
-                    losses = np.asarray(losses)
-                self.history.extend(losses.tolist())
-                # every real row trains exactly once (tail is padded+masked,
-                # not dropped); a resumed partial epoch counts exactly the
-                # real rows of its remaining rounds (mask sum)
-                examples = (len(xe) if first == 0
-                            else int(mb[first:].sum()))
-                metrics.epoch(epoch, examples, time.time() - t0,
-                              float(losses.mean()) if len(losses) else 0.0)
-                if (ckpt is not None and self.checkpoint_unit == "epoch"
-                        and (epoch + 1) % self.checkpoint_every == 0):
-                    ckpt.save(epoch + 1, self._state,
-                              meta={"engine": "spmd", "unit": "epoch"})
-                if val_fn is not None and self._validate_epoch(
-                        val_fn, self._state.center, epoch, metrics):
-                    break
+                                            "unit": "epoch"})
+                    if val_fn is not None:
+                        with span("train.validate"):
+                            stop = self._validate_epoch(
+                                val_fn, self._state.center, epoch, metrics)
+                        if stop:
+                            break
         finally:
             metrics.logger.close()
             if ckpt is not None:

@@ -1,0 +1,329 @@
+"""The program's own spans and scopes (``distkeras_tpu/metrics.py`` lists
+them): under a ``jax.profiler`` session a tiny engine run and a two-epoch
+ADAG job yield every span with its fields, nested on one thread and in a
+request's order; outputs are bit-equal with a session open and closed; with
+no session nothing is written; and the compiled programs carry every scope
+and kernel name without a change to their arithmetic.
+
+Two profiler sessions in all (a start/stop costs seconds), both in
+module-scoped fixtures of this one file.
+"""
+
+import collections
+import contextlib
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distkeras_tpu import ADAG, Dataset, metrics
+from distkeras_tpu.core.model import FittedModel
+from distkeras_tpu.models import mnist_mlp, transformer_lm
+from distkeras_tpu.serving import ServingEngine, TenantPolicy
+
+Span = collections.namedtuple("Span", "start end name thread fields")
+
+PROMPTS = [(np.arange(1, 6) % 64, 5),        # one bucket program
+           (np.arange(3, 43) % 64, 4),       # chunks, then a final unit
+           (np.arange(7, 27) % 64, 6)]
+
+
+def read_spans(log_dir):
+    """Every ``serve.*`` / ``train.*`` event of the session's trace."""
+    path, = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans, thread = [], 0
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("serve.", "train.")):
+                    spans.append(Span(e.start_ns, e.start_ns + e.duration_ns,
+                                      e.name, thread, dict(e.stats)))
+            thread += 1
+    return sorted(spans, key=lambda s: (s.start, -s.end))
+
+
+def lm():
+    model = transformer_lm(vocab_size=64, seq_len=64, d_model=32,
+                           num_heads=2, num_layers=2, mlp_dim=64,
+                           compute_dtype="float32")
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+def engine():
+    eng = ServingEngine(FittedModel(*lm()), num_slots=2, max_len=64,
+                        paged=True, block_size=8, prefill_chunk=16)
+    eng.register_tenant(TenantPolicy("vip", tier="interactive"))
+    return eng
+
+
+def serve(eng):
+    """The three prompts through the engine's own thread; tokens served."""
+    eng.start()
+    try:
+        handles = [eng.submit(p, n, tenant="vip" if i == 2 else None)
+                   for i, (p, n) in enumerate(PROMPTS)]
+        assert all(h.wait(120) for h in handles)
+    finally:
+        eng.stop()
+    return [list(h.tokens) for h in handles]
+
+
+@pytest.fixture(scope="module")
+def serve_trace(tmp_path_factory):
+    log_dir = str(tmp_path_factory.mktemp("serve_trace"))
+    eng = engine()
+    # the reload hook without a parameter server: the span is what is tested
+    eng._reload_every, eng._pull_weights = 1, lambda: None
+    with metrics.trace(log_dir):
+        eng.warmup()
+        tokens = serve(eng)
+    return read_spans(log_dir), tokens
+
+
+def dataset():
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(64, 784)).astype(np.float32)
+    y = np.eye(10, dtype=np.float32)[rng.integers(0, 10, 64)]
+    return Dataset({"features": x, "label": y})
+
+
+def train(validation_data=None, **kw):
+    trainer = ADAG(mnist_mlp("float32"), num_workers=2, batch_size=8,
+                   num_epoch=2, communication_window=2,
+                   worker_optimizer="adam", learning_rate=1e-3, **kw)
+    trainer.train(dataset(), validation_data=validation_data)
+    return list(trainer.history)
+
+
+@pytest.fixture(scope="module")
+def train_trace(tmp_path_factory):
+    log_dir = str(tmp_path_factory.mktemp("train_trace"))
+    with metrics.trace(log_dir):
+        losses = (train(checkpoint_dir=os.path.join(log_dir, "ckpt"))
+                  + train(validation_data=dataset()))
+    return read_spans(log_dir), losses
+
+
+SERVE_SPANS = {
+    "serve.submit": ("rid", "prompt", "steps"),
+    "serve.iteration": ("it", "active"),
+    "serve.reap": (),
+    "serve.qos": (),
+    "serve.schedule": (),
+    "serve.admit": ("rid",),
+    "serve.prefill_unit": ("rid", "tokens", "kind", "width", "hit"),
+    "serve.decode_dispatch": ("active", "step"),
+    "serve.fetch": ("step",),
+    "serve.emit": ("kind", "rows", "step"),
+    "serve.retire": ("rid", "reason"),
+    "serve.publish": (),
+    "serve.reload": (),
+    "serve.idle_wait": (),
+    "serve.warmup": ("program",),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SERVE_SPANS))
+def test_engine_run_yields_the_span_with_its_fields(serve_trace, name):
+    found = [s for s in serve_trace[0] if s.name == name]
+    assert found, f"no {name} span in the trace"
+    for s in found:
+        assert tuple(s.fields) == SERVE_SPANS[name]
+        assert all(isinstance(v, (int, str)) for v in s.fields.values())
+
+
+PARENTS = {
+    "serve.reap": "serve.iteration", "serve.qos": "serve.iteration",
+    "serve.schedule": "serve.iteration", "serve.admit": "serve.schedule",
+    "serve.prefill_unit": "serve.schedule",
+    "serve.decode_dispatch": "serve.iteration",
+    "serve.fetch": "serve.iteration", "serve.emit": "serve.iteration",
+    "serve.retire": "serve.iteration", "serve.reload": "serve.iteration",
+}
+
+
+@pytest.mark.parametrize("child", sorted(PARENTS))
+def test_children_lie_inside_their_parent_on_one_thread(serve_trace, child):
+    parents = [s for s in serve_trace[0] if s.name == PARENTS[child]]
+    for c in (s for s in serve_trace[0] if s.name == child):
+        assert any(p.thread == c.thread and p.start <= c.start
+                   and c.end <= p.end for p in parents), c
+
+
+def test_spans_of_one_thread_nest_and_never_cross(serve_trace):
+    by_thread = collections.defaultdict(list)
+    for s in serve_trace[0]:
+        by_thread[s.thread].append(s)
+    for spans in by_thread.values():
+        open_ends = []
+        for s in spans:
+            while open_ends and open_ends[-1] <= s.start:
+                open_ends.pop()
+            assert not open_ends or s.end <= open_ends[-1], s
+            open_ends.append(s.end)
+
+
+@pytest.mark.parametrize("rid", [1, 2, 3])
+def test_a_request_is_submitted_admitted_prefilled_retired(serve_trace, rid):
+    mine = [s for s in serve_trace[0] if s.fields.get("rid") == rid]
+    names = [s.name for s in mine]
+    assert names[0] == "serve.submit" and names[1] == "serve.admit"
+    assert names[-1] == "serve.retire"
+    assert set(names[2:-1]) == {"serve.prefill_unit"}
+    assert mine[-1].fields["reason"] == "length"
+    prompt, steps = PROMPTS[rid - 1]
+    assert mine[0].fields["prompt"] == len(prompt)
+    assert mine[0].fields["steps"] == steps
+    assert sum(s.fields["tokens"] for s in mine[2:-1]) == len(prompt)
+    kinds = [s.fields["kind"] for s in mine[2:-1]]
+    assert kinds == (["bucket"] if len(prompt) <= 16
+                     else ["chunk"] * (len(kinds) - 1) + ["final"])
+    # the submit ends on the caller's thread before the engine admits
+    assert mine[0].end <= mine[1].start and mine[0].thread != mine[1].thread
+
+
+def test_step_joins_a_dispatch_to_the_fetch_that_drains_it(serve_trace):
+    spans = serve_trace[0]
+    sent = {s.fields["step"]: s for s in spans
+            if s.name == "serve.decode_dispatch"}
+    assert sorted(sent) == list(range(1, len(sent) + 1))
+    drained = [s for s in spans if s.name == "serve.fetch"]
+    for step, d in sent.items():
+        # the last step is the lookahead's junk: stop() leaves it in flight
+        assert step == len(sent) or any(
+            f.fields["step"] == step and f.start >= d.end for f in drained)
+    assert all(s.fields["active"] > 0 for s in sent.values())
+    # a step's tokens are emitted once, as a decode entry of its live rows
+    emitted = {s.fields["step"]: s for s in spans
+               if s.name == "serve.emit" and s.fields["kind"] == "decode"}
+    assert all(emitted[step].fields["rows"] == sent[step].fields["active"]
+               for step in emitted)
+
+
+TRAIN_CHILDREN = ["train.shuffle", "train.shape", "train.dispatch",
+                  "train.fetch", "train.log", "train.checkpoint",
+                  "train.validate"]
+
+
+@pytest.mark.parametrize("last", [False, True])
+def test_an_epoch_holds_its_phases_in_order(train_trace, last):
+    spans = train_trace[0]
+    epochs = [s for s in spans if s.name == "train.epoch"]
+    # two trainers: one with a checkpoint directory, one with validation
+    assert [e.fields for e in epochs] == [{"epoch": 0}, {"epoch": 1}] * 2
+    e = epochs[-1 if last else 1]
+    inside = [s.name for s in spans if s.thread == e.thread
+              and s is not e and e.start <= s.start and s.end <= e.end]
+    want = [n for n in TRAIN_CHILDREN
+            if n != ("train.checkpoint" if last else "train.validate")]
+    assert inside == want
+    dispatch, = (s for s in spans if s.name == "train.dispatch"
+                 and e.start <= s.start and s.end <= e.end)
+    assert dispatch.fields == {"rounds": 2}
+
+
+def test_served_tokens_are_the_same_with_and_without_a_session(serve_trace):
+    assert serve(engine()) == serve_trace[1]
+
+
+def test_losses_are_the_same_with_and_without_a_session(train_trace):
+    # the traced fixture trained twice over the same data; each run's
+    # history is that of a run with no session open
+    assert train() * 2 == train_trace[1]
+
+
+def test_no_session_no_trace_file(tmp_path, monkeypatch):
+    monkeypatch.chdir(tmp_path)
+    with metrics.trace(str(tmp_path / "off"), enabled=False):
+        serve(engine())
+        with metrics.span("serve.iteration", it=0):
+            pass
+    assert not os.listdir(tmp_path)
+
+
+# -- scopes and kernel names in the compiled programs ---------------------------
+
+def train_step_lowered():
+    import optax
+    from distkeras_tpu.core.train import make_masked_step
+    model, params = lm()
+    tx = optax.adam(1e-3)
+    step = make_masked_step(
+        model, "sparse_categorical_crossentropy_from_logits", tx)
+    x = jnp.zeros((2, 64), jnp.int32)
+    return jax.jit(step).lower(params, tx.init(params), x, x,
+                               jnp.ones((2,)), jax.random.PRNGKey(0))
+
+
+def adag_round_lowered():
+    trainer = ADAG(lm()[0], num_workers=2, batch_size=2, num_epoch=1,
+                   communication_window=2, worker_optimizer="adam",
+                   loss="sparse_categorical_crossentropy_from_logits")
+    trainer._input_shape = (64,)
+    eng = trainer.service((64,))
+    state = eng.init_state(jax.random.PRNGKey(0), (64,))
+    x = np.zeros((2, 2, 2, 64), np.int32)
+    return eng._build_round_step().lower(
+        state, x, x, np.ones((2, 2, 2), np.float32), eng.worker_rngs(0))
+
+
+def decode_step_lowered():
+    eng = engine()
+    return eng._decode_fn.lower(eng.params, *eng._state_args())
+
+
+MODEL = ["embed", "block_0", "block_1", "attn", "attn_core", "mlp",
+         "final_norm", "lm_head"]
+LOWERED = {
+    "train_step": (train_step_lowered, MODEL + ["loss", "optimizer"]),
+    "adag_round": (adag_round_lowered,
+                   MODEL + ["loss", "optimizer", "commit"]),
+    "decode_step": (decode_step_lowered,
+                    MODEL + ["kv_write", "kv_gather", "sample"]),
+}
+
+
+@pytest.mark.parametrize("program", sorted(LOWERED))
+def test_the_lowered_program_names_every_scope(program):
+    lower, scopes = LOWERED[program]
+    text = lower().as_text(debug_info=True)
+    # a transformed scope is wrapped: transpose(jvp(lm_head))
+    named = {part for line in text.splitlines() if "loc(" in line
+             for part in re.findall(r"[\w.\-]+", line)}
+    assert not [s for s in scopes if s not in named]
+    assert "attn/attn_core" in text
+
+
+@pytest.mark.parametrize("program", sorted(LOWERED))
+def test_scopes_leave_the_arithmetic_as_it_was(program, monkeypatch):
+    lower, _ = LOWERED[program]
+    scoped = lower().as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert lower().as_text() == scoped
+
+
+def test_the_kernels_carry_their_names():
+    from distkeras_tpu.ops.flash_attention import flash_attention
+    from distkeras_tpu.ops.fused_ce import fused_softmax_cross_entropy
+
+    def both(q, logits, labels):
+        attn = flash_attention(q, q, q, causal=True, interpret=False)
+        ce = fused_softmax_cross_entropy(logits, labels, interpret=False)
+        return attn.astype(jnp.float32).sum() + ce.sum()
+
+    q = jax.ShapeDtypeStruct((2, 128, 2, 64), jnp.bfloat16)
+    logits = jax.ShapeDtypeStruct((256, 512), jnp.float32)
+    labels = jax.ShapeDtypeStruct((256,), jnp.int32)
+    text = jax.jit(jax.grad(both, argnums=(0, 1))).trace(
+        q, logits, labels).lower(lowering_platforms=("tpu",)).as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd",
+                 "fused_ce_bwd"):
+        assert f'kernel_name = "{name}"' in text
