@@ -151,7 +151,8 @@ def check(cond, what: str) -> None:
 
 
 def kernel_names(lowered_text: str) -> dict:
-    """Pallas kernels in a lowered program, by kernel function name."""
+    """Pallas kernels in a lowered program, by the ``name=`` of their
+    ``pallas_call`` (``distkeras_tpu/metrics.py`` lists them)."""
     return dict(collections.Counter(
         re.findall(r'kernel_name = "(\w+)"', lowered_text)))
 
@@ -299,8 +300,8 @@ def lm_train(cfg, seed, on_tpu):
     check(hist[-1] < hist[0] - 0.5,
           f"LM loss did not fall on the x+1 corpus: {hist}")
     names = kernel_names(lowered[0])
-    flash = require_kernels(names, ("_flash_kernel", "_dq_kernel",
-                                    "_dkv_kernel"), on_tpu,
+    flash = require_kernels(names, ("flash_fwd", "flash_dq", "flash_dkv"),
+                            on_tpu,
                             "SingleTrainer step")
 
     # -- the parallel LM: fused CE (and flash) inside shard_map --------------
@@ -316,7 +317,7 @@ def lm_train(cfg, seed, on_tpu):
     pnames = kernel_names(step.lower(params, opt_state, bt, bl).as_text())
     # (its default sp_impl="ring" attends through parallel/ring.py, plain
     # XLA by design — only the fused-CE kernels belong in this program)
-    fused = require_kernels(pnames, ("_fwd_kernel", "_bwd_kernel"), on_tpu,
+    fused = require_kernels(pnames, ("fused_ce_fwd", "fused_ce_bwd"), on_tpu,
                             "ParallelTransformerLM step")
     plosses, psecs = [], []
     for _ in range(cfg["plm_steps"]):
@@ -546,8 +547,8 @@ def host_ps(cfg, seed, native):
 def kernels(cfg, seed, on_tpu):
     """What the old subprocess smoke tests carried and the phases above do
     not: kernel numerics against the XLA reference (windowed and GQA flash,
-    ragged fused CE), flash inside shard_map, the long-context stack, and
-    the 1F1B pipeline schedule."""
+    the paged decode kernel, ragged fused CE), flash inside shard_map, the
+    long-context stack, and the 1F1B pipeline schedule."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -601,6 +602,41 @@ def kernels(cfg, seed, on_tpu):
         worst = max(worst, fwd)
     out["flash_vs_xla_max_abs_err"] = worst
 
+    # paged decode kernel vs the gather path it stands in for, on one arena:
+    # ragged rows (one position, a page, a page + 1, the whole view), a dead
+    # row, grouped queries, head_dim 64, pages of 16 and 32
+    from distkeras_tpu.ops.attention import paged_attention
+    from distkeras_tpu.ops.paged_attention import paged_decode_attention
+    view = 1024 if on_tpu else 128
+    worst = 0.0
+    for h, hkv, page in ((16, 16, 16), (8, 2, 32)):
+        lens = np.array([1, page, page + 1, view, 0, view // 2 + 1, 3 * page,
+                         view - 1], np.int32)
+        b, f, cols = len(lens), hkv * 64, view // page + 1
+        blocks = b * (cols - 1)
+        tables = np.full((b, cols), blocks, np.int32)   # null = `blocks`
+        ids = iter(rng.permutation(blocks))
+        for r, n in enumerate(lens):
+            tables[r, :-(-n // page)] = [next(ids)
+                                         for _ in range(-(-n // page))]
+        ka, va = (jnp.asarray(rng.standard_normal(((blocks + 1) * page, f)),
+                              jnp.bfloat16) for _ in range(2))
+        q = jnp.asarray(rng.standard_normal((b, h, 64)), jnp.bfloat16)
+        got = jax.jit(lambda *a: paged_decode_attention(*a, page))(
+            q, ka, va, tables, lens)
+
+        want = jax.jit(lambda q, ka, va, tables, lens: paged_attention(
+            q[:, None], ka, va, tables, page, view, kv_length=lens,
+            q_positions=jnp.maximum(lens - 1, 0)[:, None])[:, 0])(
+                q, ka, va, tables, lens)
+        live = (lens > 0)[:, None, None]
+        err = float(np.max(np.abs(np.where(live, f32(got) - f32(want), 0))))
+        check(err < 0.03 and not f32(got)[lens == 0].any(),
+              f"paged decode vs gather at H={h} Hkv={hkv} page={page}: "
+              f"{err}")
+        worst = max(worst, err)
+    out["paged_decode_vs_gather_max_abs_err"] = worst
+
     # fused CE vs the log_softmax oracle, ragged vocab and rows included
     def oracle(lg, lb):
         logp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
@@ -645,7 +681,7 @@ def kernels(cfg, seed, on_tpu):
     fwd = jax.jit(model.apply)
     out["long_context_flash"] = require_kernels(
         kernel_names(fwd.lower(params, toks).as_text()),
-        ("_flash_kernel",), on_tpu, f"long-context forward at {s}")
+        ("flash_fwd",), on_tpu, f"long-context forward at {s}")
     check(np.isfinite(f32(fwd(params, toks))).all(),
           f"non-finite logits at seq_len {s}")
     prompt = toks[:, :16]
@@ -717,7 +753,7 @@ def parallel_lm_4(cfg, seed, on_tpu):
           f"non-finite losses: {l4} {l1}")
     check(shard1 == full and shard4 == (full[0], full[1] // 2),
           f"w1 {full}: shard on 2x1x2 is {shard4}, on 1x1x1 {shard1}")
-    kern = require_kernels(names4, ("_fwd_kernel", "_bwd_kernel"), on_tpu,
+    kern = require_kernels(names4, ("fused_ce_fwd", "fused_ce_bwd"), on_tpu,
                            "2x1x2 step")
     # bf16 compute, and tp changes the matmul reduction order; Adam's
     # first updates (sign-like steps) amplify that rounding a little

@@ -173,10 +173,20 @@ def init_paged_arena(model, num_blocks: int, block_size: int,
                      kv_dtype: Optional[str] = None) -> List[Any]:
     """The paged slot pool's backing store: per TransformerBlock a FLAT
     arena of ``num_blocks + 1`` fixed-size blocks laid out contiguously —
-    ``{"k", "v"}`` of shape ((num_blocks + 1) * block_size, num_kv_heads,
-    key_dim) (plus ``{"ks", "vs"}`` per-entry scales for
-    ``kv_dtype="int8"``, quantized codes paged identically to the
-    full-precision entries).  Physical block b owns arena slots
+    ``{"k", "v"}`` of shape ((num_blocks + 1) * block_size, num_kv_heads *
+    key_dim): a position's kv heads side by side in ONE row (plus
+    ``{"ks", "vs"}`` per-(slot, head) scales for ``kv_dtype="int8"``,
+    quantized codes paged identically to the full-precision entries).
+    Rows, not a (slots, heads, key_dim) cube, because of where the device
+    keeps them: a TPU lays a bf16[slots, 16, 64] array out with the SLOT
+    axis minor-most (``{0,2,1:T(8,128)(2,1)}``: key_dim 64 would fill half
+    a 128-lane tile, so the large axis takes the lanes), which no
+    per-position write and no per-block read can use — every paged program
+    then copies the whole pool into row-major order and back (PERF.md
+    section 6, PR 25).  A row of ``heads * key_dim`` features is
+    lane-dense, stays row-major at rest, is written in place, and makes a
+    block one contiguous slab that ``ops.paged_attention`` reads by DMA.
+    Physical block b owns arena slots
     [b * block_size, (b + 1) * block_size); logical position p of a
     request whose block table maps logical block ``p // block_size`` to b
     lives at slot ``b * block_size + p % block_size``.  The EXTRA
@@ -200,12 +210,13 @@ def init_paged_arena(model, num_blocks: int, block_size: int,
     for layer in model.layers:
         if isinstance(layer, TransformerBlock):
             mha = layer._mha()
-            shape = (arena_len, mha._kv_heads(), mha.key_dim)
+            shape = (arena_len, mha._kv_heads() * mha.key_dim)
             if kv_dtype == "int8":
+                scales = (arena_len, mha._kv_heads())
                 caches.append({"k": jnp.zeros(shape, jnp.int8),
                                "v": jnp.zeros(shape, jnp.int8),
-                               "ks": jnp.zeros(shape[:2], jnp.float32),
-                               "vs": jnp.zeros(shape[:2], jnp.float32)})
+                               "ks": jnp.zeros(scales, jnp.float32),
+                               "vs": jnp.zeros(scales, jnp.float32)})
             else:
                 caches.append({"k": jnp.zeros(shape, dtype),
                                "v": jnp.zeros(shape, dtype)})
@@ -222,19 +233,23 @@ def _kv_quantized(cache) -> bool:
 def _kv_write(cache, idx, k_t, v_t):
     """Scatter a (B, L, Hkv, Dh) k/v write into ``cache`` at ``idx`` (a
     tuple of broadcastable row/slot index arrays); int8 caches quantize on
-    write, storing codes and per-entry scales side by side.  Out-of-bounds
-    indices drop (jit scatter semantics) — the serving engine's
-    speculative verify leans on that at the end-of-request boundary."""
+    write, storing codes and per-entry scales side by side.  Entries take
+    the cache's own trailing shape: (Hkv, Dh) in a dense slab, one row of
+    Hkv * Dh features in a paged arena.  Out-of-bounds indices drop (jit
+    scatter semantics) — the serving engine's speculative verify leans on
+    that at the end-of-request boundary."""
+    def put(name, x):
+        # idx addresses the leading axes; what is left is one entry
+        entry = cache[name].shape[len(idx):]
+        return cache[name].at[idx].set(x.reshape(x.shape[:2] + entry))
+
     if _kv_quantized(cache):
         from .quant import quantize_kv
         kq, ks = quantize_kv(k_t)
         vq, vs = quantize_kv(v_t)
-        return {"k": cache["k"].at[idx].set(kq),
-                "v": cache["v"].at[idx].set(vq),
-                "ks": cache["ks"].at[idx].set(ks),
-                "vs": cache["vs"].at[idx].set(vs)}
-    return {"k": cache["k"].at[idx].set(k_t),
-            "v": cache["v"].at[idx].set(v_t)}
+        return {"k": put("k", kq), "v": put("v", vq),
+                "ks": put("ks", ks), "vs": put("vs", vs)}
+    return {"k": put("k", k_t), "v": put("v", v_t)}
 
 
 def _kv_read(cache, dtype):
@@ -251,7 +266,7 @@ def gather_blocks(caches, rows):
     """Pull the arena slots named by ``rows`` (a flat (n,) int32 vector of
     PHYSICAL slot indices — block table rows expanded by ``block_size``)
     out of a flat paged arena (``init_paged_arena``): per TransformerBlock
-    a dict of ``(n, Hkv, Dh)`` payloads (int8 arenas also gather their
+    a dict of ``(n, Hkv * Dh)`` payloads (int8 arenas also gather their
     ``(n, Hkv)`` scales).  The prefill half of a disaggregated transfer —
     read-only, so gathering a radix-shared prefix block is safe.  Shape is
     static in ``rows.shape``: callers pad ``rows`` with null-block slots
@@ -293,6 +308,62 @@ def _per_row(pos) -> bool:
     return getattr(pos, "ndim", 0) == 1
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def paged_kernel_applies(mha: MultiHeadAttention, cache, paged: "PagedView",
+                         q) -> bool:
+    """Does this paged attention read go to the Pallas decode kernel
+    (``ops.paged_attention``) instead of gather + dense attention?  Decided
+    from what the call itself shows, never by an option: ONE query token a
+    row (``q``: (B, 1, H, Dh), an array or its shape-and-dtype), a
+    full-view table (no ring: a ring's slots are not in position order),
+    no write or query bounds (those are prefill's), full-precision entries
+    (an int8 arena dequantizes on the gather path), no sliding window on
+    the layer, a TPU underneath, and shapes the kernel tiles.  The serving
+    engine asks the same question when it builds its decode program
+    (``paged_step_on_kernel``), so its counter says what the program
+    does."""
+    from ..ops.paged_attention import kernel_tiles
+    b, length, h, dh = q.shape
+    return (length == 1 and not paged.ring
+            and paged.floor is None and paged.ceil is None
+            and paged.qcap is None and not _kv_quantized(cache)
+            and mha.attention_window is None and _on_tpu()
+            and kernel_tiles((b, h, dh), q.dtype, cache["k"].shape,
+                             cache["k"].dtype, paged.page, paged.view))
+
+
+def paged_step_on_kernel(model, caches, batch: int, page: int, view: int,
+                         ring: bool = False) -> bool:
+    """True when EVERY attention layer of a single-token paged step over
+    ``caches`` reads through the decode kernel — what the serving engine's
+    decode program is built on (``paged_kernel_applies`` layer by layer,
+    with the shapes that step will trace)."""
+    probe = PagedView(None, page, view, ring=ring)
+    blocks = [(layer._mha(), c) for layer, c in zip(model.layers, caches)
+              if isinstance(layer, TransformerBlock)]
+    return bool(blocks) and all(
+        paged_kernel_applies(m, c, probe, jax.ShapeDtypeStruct(
+            (batch, 1, m.num_heads, m.key_dim), model._cdtype))
+        for m, c in blocks)
+
+
+def _live_lengths(paged: "PagedView", pos, arena_slots: int):
+    """Positions each row of a single-token paged step attends, its new
+    token included: ``pos + 1`` for a row that holds a request, 0 for one
+    that does not.  Which is which is read off the table, not off ``pos``:
+    a retired slot's row is all null block while its position stays stale
+    (``ServingEngine._build_deact_fn``), and so is a slot still being
+    prefilled, so a row is live exactly when the block its new token was
+    written into is a real one."""
+    null_block = arena_slots // paged.page - 1
+    blk = jnp.minimum(pos // paged.page, paged.tables.shape[1] - 1)
+    here = jnp.take_along_axis(paged.tables, blk[:, None], axis=1)[:, 0]
+    return jnp.where(here != null_block, jnp.minimum(pos + 1, paged.view), 0)
+
+
 def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
                  rolling: bool = False, paged: Optional[PagedView] = None):
     """Cached attention over (B, L, D) queries starting at position
@@ -321,10 +392,17 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
     (``init_paged_arena``) addressed through per-row block tables instead
     of a (B, S, ...) slab.  Writes scatter at gather-computed physical
     slots (``floor``/``ceil`` route shared-prefix and right-pad positions
-    into the null block); reads gather each row's logical view back out
-    (``ops.attention.paged_gather``) and attend with the SAME per-row
-    masks as the dense path — the paged step is a storage relayout, not
-    a numerics change.  Requires per-row ``pos``."""
+    into the null block).  Reads take one of two ways, chosen by what the
+    call shows (``paged_kernel_applies``): the single-token decode step on
+    a TPU reads K and V in place through the block tables, each row as far
+    as its own length, in one Pallas kernel
+    (``ops.paged_attention.paged_decode_attention``: f32 softmax and
+    accumulation over the stored entries, the flash recurrence); everything
+    else (prefill units, the speculative verify's L > 1, ring views, int8
+    arenas, windowed layers, the CPU) gathers each row's logical view back
+    out (``ops.attention.paged_gather``) and attends with the SAME per-row
+    masks as the dense path.  Either way the paged step is a storage
+    relayout, not a numerics change.  Requires per-row ``pos``."""
     from ..ops.attention import dot_product_attention, paged_gather
     b, length = h.shape[0], h.shape[1]
     dh = mha.key_dim
@@ -392,32 +470,41 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
                              phys, null_phys)
         with jax.named_scope("kv_write"):
             new_cache = _kv_write(cache, (phys,), k_t, v_t)
-        with jax.named_scope("kv_gather"):
-            if _kv_quantized(new_cache):
-                from .quant import dequantize_kv
-                k = dequantize_kv(
-                    paged_gather(new_cache["k"], paged.tables, bs, view),
-                    paged_gather(new_cache["ks"], paged.tables, bs, view),
-                    cdtype)
-                v = dequantize_kv(
-                    paged_gather(new_cache["v"], paged.tables, bs, view),
-                    paged_gather(new_cache["vs"], paged.tables, bs, view),
-                    cdtype)
-            else:
-                k = paged_gather(new_cache["k"], paged.tables, bs, view)
-                v = paged_gather(new_cache["v"], paged.tables, bs, view)
-        kv_positions = kv_length = None
-        if paged.ring:
-            # same frontier layout as the dense ring: view slot j holds
-            # the newest position <= each row's write frontier congruent
-            # to j mod view (negative = never written)
-            front = pos[:, None] + (length - 1)
-            j = jnp.arange(view)
-            kv_positions = front - jnp.mod(front - j[None, :], view)
+        if paged_kernel_applies(mha, new_cache, paged, q):
+            # the single-token step: K and V are read where they lie, each
+            # row as far as its own length, by one kernel over the tables
+            from ..ops.paged_attention import paged_decode_attention
+            with jax.named_scope("kv_gather"):
+                lengths = _live_lengths(paged, pos, new_cache["k"].shape[0])
+            with jax.named_scope("attn_core"):
+                out = paged_decode_attention(
+                    q[:, 0], new_cache["k"], new_cache["v"], paged.tables,
+                    lengths, bs)[:, None]
         else:
-            kv_length = pos + length
-        out = attend(k, v, q_positions=q_clamped, kv_length=kv_length,
-                     kv_positions=kv_positions)
+            def view_of(name):  # each row's (view, ...) entries
+                return paged_gather(new_cache[name], paged.tables, bs, view)
+
+            def heads(rows):    # a row of Hkv * Dh features, unfolded
+                return rows.reshape(b, view, mha._kv_heads(), dh)
+
+            with jax.named_scope("kv_gather"):
+                k, v = heads(view_of("k")), heads(view_of("v"))
+                if _kv_quantized(new_cache):
+                    from .quant import dequantize_kv
+                    k = dequantize_kv(k, view_of("ks"), cdtype)
+                    v = dequantize_kv(v, view_of("vs"), cdtype)
+            kv_positions = kv_length = None
+            if paged.ring:
+                # same frontier layout as the dense ring: view slot j holds
+                # the newest position <= each row's write frontier
+                # congruent to j mod view (negative = never written)
+                front = pos[:, None] + (length - 1)
+                j = jnp.arange(view)
+                kv_positions = front - jnp.mod(front - j[None, :], view)
+            else:
+                kv_length = pos + length
+            out = attend(k, v, q_positions=q_clamped, kv_length=kv_length,
+                         kv_positions=kv_positions)
     elif per_row:
         # L >= 1: every row writes its L entries at its own offsets (the
         # serving engine's decode step at L == 1, its speculative verify

@@ -37,7 +37,12 @@ Serving (``serving.py``; the engine's thread unless said):
                       (prefix-hit tokens the admission found; on the first
                       unit of a request, else 0)
 ``serve.decode_dispatch`` ``_decode_once``: ``active``, ``step``
-                      (``stats["decode_steps"]`` after this dispatch)
+                      (``stats["decode_steps"]`` after this dispatch),
+                      ``attn`` (``kernel``: the dispatched program reads K
+                      and V in place through ``paged_decode``; ``gather``:
+                      it gathers each slot's view — settled when the
+                      program is built; ``stats["paged_kernel_steps"]``
+                      counts the ``kernel`` dispatches)
 ``serve.fetch``       the device→host read of one in-flight step — the time
                       the host WAITS for the device: ``step`` of the entry
                       drained (joins it to its ``serve.decode_dispatch``;
@@ -69,9 +74,11 @@ time) name the compiled programs' phases in every profile and HLO dump:
 ``embed``, ``block_<i>`` ⊃ ``attn`` ⊃ ``attn_core``, ``mlp``,
 ``final_norm``, ``lm_head`` (model forward, training and decoding alike);
 ``loss``, ``optimizer``, ``commit`` (train step and the SPMD round);
-``kv_write``, ``kv_gather``, ``sample`` (decode step).  Pallas kernels
-carry ``flash_fwd``/``flash_dq``/``flash_dkv`` and
-``fused_ce_fwd``/``fused_ce_bwd``.
+``kv_write``, ``kv_gather``, ``sample`` (decode step; on the kernel path
+``kv_gather`` holds only the row lengths' preparation).  Pallas kernels
+carry ``flash_fwd``/``flash_dq``/``flash_dkv``,
+``fused_ce_fwd``/``fused_ce_bwd`` and ``paged_decode`` (under
+``attn_core`` of the paged single-token step).
 """
 
 from __future__ import annotations

@@ -260,9 +260,10 @@ class KVBlocks:
 
     ``layers`` mirrors the model's layer list: ``None`` for layers without
     a KV cache, else a dict of flat arena slices in LOGICAL block order —
-    ``{"k", "v"}`` of shape ``(num_blocks * block_size, Hkv, Dh)`` (plus
-    ``{"ks", "vs"}`` per-entry scales of shape ``(num_blocks * block_size,
-    Hkv)`` when the arena is int8-quantized, PR 11).  Logical order
+    ``{"k", "v"}`` of shape ``(num_blocks * block_size, Hkv * Dh)``, the
+    arena's own rows (plus ``{"ks", "vs"}`` per-entry scales of shape
+    ``(num_blocks * block_size, Hkv)`` when the arena is int8-quantized,
+    PR 11).  Logical order
     replaces the sender's block table on the wire: the receiver allocates
     its OWN physical blocks (``_PagedKVPool.admit``) and scatters row i of
     the payload into its i-th block — physical ids never cross engines.
@@ -327,7 +328,7 @@ class KVBlocks:
                     f"kv-block transfer layer {i} carries unknown "
                     f"payloads {sorted(extra)}")
             k, v = c["k"], c["v"]
-            if k.ndim != 3 or k.shape != v.shape or k.dtype != v.dtype:
+            if k.ndim != 2 or k.shape != v.shape or k.dtype != v.dtype:
                 raise ProtocolError(
                     f"kv-block transfer layer {i} k/v disagree: "
                     f"{k.shape}/{k.dtype} vs {v.shape}/{v.dtype}")
@@ -344,11 +345,14 @@ class KVBlocks:
                     raise ProtocolError(
                         f"kv-block transfer layer {i} ships scales for "
                         f"non-int8 codes ({k.dtype})")
-                for s in ("ks", "vs"):
-                    if c[s].shape != k.shape[:2]:
-                        raise ProtocolError(
-                            f"kv-block transfer layer {i} {s} shape "
-                            f"{c[s].shape} != {k.shape[:2]}")
+                # one scale a (row, kv head): k's and v's heads agree and
+                # divide the row
+                ks = c["ks"].shape
+                if (ks != c["vs"].shape or len(ks) != 2 or ks[0] != rows
+                        or not ks[1] or k.shape[1] % ks[1]):
+                    raise ProtocolError(
+                        f"kv-block transfer layer {i} scales {ks} / "
+                        f"{c['vs'].shape} do not scale rows of {k.shape}")
         return self
 
     def decoded(self) -> "KVBlocks":

@@ -9,7 +9,8 @@ MXU-shaped and makes the sequence axis shardable for ring attention
 
 ``impl``: ``"xla"`` — plain jnp, XLA fuses the softmax chain; ``"pallas"`` —
 the fused flash kernel in ``ops/flash_attention.py`` (TPU); ``None`` — pick
-pallas on TPU when shapes qualify, else xla.
+pallas on TPU when shapes qualify, else xla.  The paged single-token decode
+step has a kernel of its own, ``ops/paged_attention.py``.
 """
 
 from __future__ import annotations
@@ -190,10 +191,15 @@ def paged_gather(arena, block_tables, page_size: int, view_len: int):
     block id drop reads into junk (masked by the caller's frontier).
     Returns the (B, view_len, ...) logical view: entry (r, p) is the
     arena slot holding row r's logical position p.  This is the
-    gather-by-block-table read the paged decode/prefill programs run —
-    the values are bit-identical to a dense (B, view_len, ...) cache
-    holding the same writes, so attention over the view reproduces the
-    dense path's numerics exactly.
+    gather-by-block-table read of the paged PREFILL programs, the
+    speculative verify, ring and int8 pools and every CPU engine; the
+    single-token decode step on a TPU no longer builds this view and reads
+    the arena in place (``ops.paged_attention``; ``core.decode.
+    paged_kernel_applies`` says which).  The values are bit-identical to a
+    dense (B, view_len, ...) cache holding the same writes, so attention
+    over the view reproduces the dense path's numerics exactly; the
+    serving arena's rows are ``Hkv * Dh`` features wide and the caller
+    unfolds the heads.
     """
     idx = jnp.arange(int(view_len))
     blk = jnp.minimum(idx // int(page_size), block_tables.shape[1] - 1)
@@ -211,11 +217,16 @@ def paged_attention(q, k_arena, v_arena, block_tables, page_size: int,
     its block table, then attended with the usual per-row causal masks
     (``q_positions``/``q_offset`` anchor the queries, ``kv_length`` masks
     the unwritten logical tail, ``kv_positions`` carries ring layouts).
-    Quantized arenas dequantize BEFORE this entry point (the caller
-    gathers codes + scales and fuses the dequant — see
+    Arena rows hold a position's kv heads side by side (``Hkv * Dh``
+    features, ``core.decode.init_paged_arena``) and are unfolded by ``q``'s
+    head_dim.  Quantized arenas dequantize BEFORE this entry point (the
+    caller gathers codes + scales and fuses the dequant — see
     ``core/decode.py``)."""
-    k = paged_gather(k_arena, block_tables, page_size, view_len)
-    v = paged_gather(v_arena, block_tables, page_size, view_len)
+    def view(arena):
+        rows = paged_gather(arena, block_tables, page_size, view_len)
+        return rows.reshape(rows.shape[:2] + (-1, q.shape[-1]))
+
+    k, v = view(k_arena), view(v_arena)
     return dot_product_attention(q, k, v, causal=True, scale=scale,
                                  q_positions=q_positions, q_offset=q_offset,
                                  kv_length=kv_length, window=window,

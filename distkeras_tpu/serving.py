@@ -1140,6 +1140,10 @@ class ServingEngine:
         self._iterations = 0  # step() calls so far (serve.iteration's `it`)
         self._prefilling: Dict[int, _PrefillJob] = {}
         self._lookahead = 1 if self.prefill_mode == "bucketed" else 0
+        #: what the decode program's attention reads K and V through:
+        #: "kernel" (ops.paged_attention, in place) or "gather" — settled
+        #: where the program is built, reported on serve.decode_dispatch
+        self._decode_attn = "gather"
         if self.prefill_mode == "bucketed":
             # params live on device once: the decode loop must not re-ship
             # the weights (or anything else) host→device per iteration
@@ -1217,6 +1221,9 @@ class ServingEngine:
             "requests_submitted": 0, "requests_completed": 0,
             "requests_rejected": 0, "tokens_generated": 0,
             "prefills": 0, "decode_steps": 0, "active_slot_steps": 0,
+            # decode steps whose program reads K and V in place through
+            # the paged decode kernel (every step or none of an engine's)
+            "paged_kernel_steps": 0,
             "queue_peak": 0, "slot_requests": [0] * self.num_slots,
             "weight_reloads": 0,
             # hot-reload hardening observables (docs/serving.md): reloads
@@ -1390,12 +1397,18 @@ class ServingEngine:
         caches, new positions), so a steady-state iteration uploads nothing
         and reads back only the sampled token row.  Paged engines take the
         device block tables as an extra (read-only) argument and write/
-        gather through them — per-row block-indexed cache writes inside
-        the same jitted step."""
+        read through them — per-row block-indexed cache writes inside the
+        same jitted step, and the read either in place by the paged decode
+        kernel or by gather, as ``_dec.paged_kernel_applies`` finds (the
+        speculative round is its own program, built on the gather path)."""
         model, rolling = self.model, self.rolling
 
         if self.paged:
             page, view = self.block_size, self._t_view
+            if self._draft_model is None and _dec.paged_step_on_kernel(
+                    model, self.caches, self.num_slots, page, view,
+                    ring=rolling):
+                self._decode_attn = "kernel"
 
             def pstep(params, caches, bt, tok, positions, active, temp,
                       topk, topp, keys):
@@ -2381,12 +2394,13 @@ class ServingEngine:
                     f"layer {i} quantization disagrees: shipped "
                     f"{'int8' if 'ks' in c else 'dense'} KV, this arena is "
                     f"{'int8' if 'ks' in mine else 'dense'}")
-            if c["k"].shape[1:] != mine["k"].shape[1:] \
-                    or c["k"].dtype != mine["k"].dtype:
-                raise ValueError(
-                    f"layer {i} shipped rows are {c['k'].shape[1:]} "
-                    f"{c['k'].dtype}, this arena holds "
-                    f"{mine['k'].shape[1:]} {mine['k'].dtype}")
+            for name in c:  # rows of Hkv * Dh features, scales of Hkv
+                if c[name].shape[1:] != mine[name].shape[1:] \
+                        or c[name].dtype != mine[name].dtype:
+                    raise ValueError(
+                        f"layer {i} shipped {name!r} rows are "
+                        f"{c[name].shape[1:]} {c[name].dtype}, this arena "
+                        f"holds {mine[name].shape[1:]} {mine[name].dtype}")
         _validate_stopping(eos_id, pad_id, self._vocab)
         rid = next(self._ids)
         with span("serve.submit", rid=rid, prompt=len(prompt),
@@ -3491,7 +3505,8 @@ class ServingEngine:
             self.stats["decode_steps"] += 1
             step = self.stats["decode_steps"]
             live = np.flatnonzero(self._active)
-            with span("serve.decode_dispatch", active=len(live), step=step):
+            with span("serve.decode_dispatch", active=len(live), step=step,
+                      attn=self._decode_attn):
                 nxt, self.caches = self._step_fn(
                     self.params, self.caches, jnp.asarray(self._cur_tok),
                     jnp.asarray(self._positions), jnp.asarray(self._active),
@@ -3514,7 +3529,9 @@ class ServingEngine:
                    for s in np.flatnonzero(self._active)]
         self.stats["decode_steps"] += 1
         step = self.stats["decode_steps"]
-        with span("serve.decode_dispatch", active=len(entries), step=step):
+        self.stats["paged_kernel_steps"] += self._decode_attn == "kernel"
+        with span("serve.decode_dispatch", active=len(entries), step=step,
+                  attn=self._decode_attn):
             if self._draft_model is not None:
                 # speculative round: k draft steps + one batched verify in
                 # ONE program; rows commit 1..spec_len+1 tokens each, packed

@@ -5,7 +5,7 @@ DESCRIBED ``v5e:2x2`` topology (``jax.experimental.topologies``): nothing
 runs, but Mosaic refuses here exactly what it would refuse on the device —
 misaligned slices, too much VMEM, a kernel that cannot be partitioned —
 which interpret mode (every other kernel test in this suite) cannot see.
-Shapes are the real widths ``chip_smoke.py`` runs.
+Shapes are the real widths ``chip_smoke.py`` and the benchmark's cells run.
 
 Everything that touches the topology lives in the module-scoped fixtures
 below (never at import time: under pytest-xdist every worker imports this
@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from distkeras_tpu.ops.flash_attention import flash_attention
 from distkeras_tpu.ops.fused_ce import fused_softmax_cross_entropy
+from distkeras_tpu.ops.paged_attention import paged_decode_attention
 
 KERNEL = "tpu_custom_call"
 
@@ -119,6 +120,47 @@ def test_fused_ce_compiles_for_v5e(one_chip, shape, dtype, mode):
     text = _compiled_text(_ce if mode == "fwd" else _ce_grad, logits,
                           labels)
     assert KERNEL in text
+
+
+# -- paged decode attention --------------------------------------------------
+
+PAGED_SHAPES = [  # slots B, heads H, kv heads, Dh, blocks, page; dtype
+    ((64, 16, 16, 64, 4096, 16), jnp.bfloat16),  # serve-chat-gpt2m's pool
+    ((8, 12, 12, 64, 512, 16), jnp.bfloat16),    # gpt2-small: 12 heads pad
+    ((64, 32, 8, 128, 1024, 32), jnp.bfloat16),  # grouped queries, Dh 128
+    ((16, 16, 16, 64, 1024, 8), jnp.float32),
+]
+
+
+@pytest.mark.parametrize("shape,dtype", PAGED_SHAPES,
+                         ids=lambda x: "x".join(map(str, x))
+                         if isinstance(x, tuple) else np.dtype(x).name)
+def test_paged_decode_compiles_for_v5e(one_chip, shape, dtype):
+    """The decode step's attention part as ``_mha_forward`` runs it: the
+    new token's K and V scattered into the donated arenas, the kernel
+    reading them through the tables.  One kernel, and no copy of an arena:
+    rows of Hkv * Dh features stay row-major at rest, the page view is a
+    bitcast and the scatter writes in place."""
+    b, h, hkv, dh, blocks, page = shape
+    slots, f, cols = (blocks + 1) * page, hkv * dh, 1024 // page + 1
+
+    def step(ka, va, q, kt, vt, tables, pos):
+        at = jnp.take_along_axis(tables, (pos // page)[:, None],
+                                 axis=1)[:, 0] * page + pos % page
+        ka, va = ka.at[at].set(kt), va.at[at].set(vt)
+        out = paged_decode_attention(q, ka, va, tables, pos + 1, page,
+                                     interpret=False)
+        return out, ka, va
+
+    S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(
+        S((slots, f), dtype), S((slots, f), dtype), S((b, h, dh), dtype),
+        S((b, f), dtype), S((b, f), dtype), S((b, cols), jnp.int32),
+        S((b,), jnp.int32)).compile().as_text()
+    assert text.count(KERNEL) == 1
+    arena = f"[{slots},{f}]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and arena in line.split(" copy(")[0]]
 
 
 # -- kernels inside shard_map on the 2x2 mesh --------------------------------

@@ -119,7 +119,7 @@ SERVE_SPANS = {
     "serve.schedule": (),
     "serve.admit": ("rid",),
     "serve.prefill_unit": ("rid", "tokens", "kind", "width", "hit"),
-    "serve.decode_dispatch": ("active", "step"),
+    "serve.decode_dispatch": ("active", "step", "attn"),
     "serve.fetch": ("step",),
     "serve.emit": ("kind", "rows", "step"),
     "serve.retire": ("rid", "reason"),
@@ -200,6 +200,8 @@ def test_step_joins_a_dispatch_to_the_fetch_that_drains_it(serve_trace):
         assert step == len(sent) or any(
             f.fields["step"] == step and f.start >= d.end for f in drained)
     assert all(s.fields["active"] > 0 for s in sent.values())
+    # the CPU's decode program gathers; a TPU's reads through the kernel
+    assert {s.fields["attn"] for s in sent.values()} == {"gather"}
     # a step's tokens are emitted once, as a decode entry of its live rows
     emitted = {s.fields["step"]: s for s in spans
                if s.name == "serve.emit" and s.fields["kind"] == "decode"}
@@ -327,3 +329,30 @@ def test_the_kernels_carry_their_names():
     for name in ("flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd",
                  "fused_ce_bwd"):
         assert f'kernel_name = "{name}"' in text
+
+
+def test_the_paged_decode_step_names_its_kernel_under_attn_core(monkeypatch):
+    """What a TPU engine's decode program lowers to: the kernel by its
+    name, under ``attn_core``; ``kv_gather`` still a scope of the program
+    (the row lengths), so the scope readers find it and read it near 0."""
+    from distkeras_tpu.core import decode
+    from distkeras_tpu.ops import paged_attention
+    monkeypatch.setattr(decode, "_on_tpu", lambda: True)
+    compiled = paged_attention.paged_decode_attention
+    monkeypatch.setattr(
+        paged_attention, "paged_decode_attention",
+        lambda *a, **kw: compiled(*a, **kw, interpret=False))
+    model = transformer_lm(vocab_size=64, seq_len=64, d_model=128,
+                           num_heads=2, num_layers=2, mlp_dim=64,
+                           compute_dtype="float32")
+    eng = ServingEngine(FittedModel(model, model.init(jax.random.PRNGKey(0))),
+                        num_slots=2, max_len=64, paged=True, block_size=8)
+    assert eng._decode_attn == "kernel"
+    text = eng._decode_fn.trace(eng.params, *eng._state_args()).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    # the layers share ONE lowering of the kernel (the wrapper is jitted);
+    # each calls it from under its own attn_core
+    assert text.count('kernel_name = "paged_decode"') == 1
+    for block in ("block_0", "block_1"):
+        assert f"{block}/attn/attn_core/jit(paged_decode_attention)" in text
+    assert "attn/kv_gather" in text and "attn/kv_write" in text

@@ -534,16 +534,16 @@ def _kvb(int8=False, bs=4, nb=2, hkv=2, dh=3, seed=0):
     layers = [None]
     for _ in range(2):
         if int8:
-            c = {"k": rng.integers(-127, 128, (rows, hkv, dh)).astype(
+            c = {"k": rng.integers(-127, 128, (rows, hkv * dh)).astype(
                      np.int8),
-                 "v": rng.integers(-127, 128, (rows, hkv, dh)).astype(
+                 "v": rng.integers(-127, 128, (rows, hkv * dh)).astype(
                      np.int8),
                  "ks": rng.random((rows, hkv)).astype(np.float32),
                  "vs": rng.random((rows, hkv)).astype(np.float32)}
         else:
-            c = {"k": rng.standard_normal((rows, hkv, dh)).astype(
+            c = {"k": rng.standard_normal((rows, hkv * dh)).astype(
                      np.float32),
-                 "v": rng.standard_normal((rows, hkv, dh)).astype(
+                 "v": rng.standard_normal((rows, hkv * dh)).astype(
                      np.float32)}
         layers.append(c)
     return networking.KVBlocks(layers, bs, nb, positions=rows - 1,
