@@ -6,6 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distkeras_tpu.ops import attention as attention_ops
+from distkeras_tpu.ops import flash_attention as flash_ops
 from distkeras_tpu.ops.attention import dot_product_attention
 from distkeras_tpu.ops.flash_attention import flash_attention
 
@@ -47,8 +49,9 @@ def test_flash_gradients():
 
 @pytest.mark.parametrize("window", [1, 5, 16, 40])
 def test_flash_sliding_window_matches_reference(window):
-    """Windowed flash (multi-block: out-of-window k blocks skipped via
-    _live_kq) == windowed XLA reference, forward and all three grads."""
+    """Windowed flash (multi-block: out-of-window k tiles are neither
+    fetched nor visited) == windowed XLA reference, forward and all three
+    grads."""
     q, k, v = rand_qkv(7, b=1, s=64, h=2, d=8)
     out = flash_attention(q, k, v, True, None, 16, 16, True, window)
     want = dot_product_attention(q, k, v, causal=True, window=window)
@@ -168,3 +171,80 @@ def test_flash_bf16_gradients_close():
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
                                    np.asarray(b), atol=0.06)
+
+
+# -- tiles chosen from the shape (block_q = block_k = None) -------------------
+
+MODES = {"causal": (True, None), "full": (False, None),
+         "window512": (True, 512)}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("mode", list(MODES))
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [128, 384, 640, 1024, 2048])
+def test_chosen_tiles_match_reference(s, d, mode, dtype):
+    """With the tiles ``_tiles`` picks, the kernels equal the XLA oracle ON
+    THE SAME INPUTS, outputs and all three gradients: bf16 within the
+    tolerance of ``test_flash_bf16_gradients_close`` (both sides multiply
+    bf16 and accumulate f32), f32 at the f32 parity tests' tolerances
+    (f32 inputs keep f32 matmuls).  Covers one tile (128), tiles that are
+    no power of two (384, 640), the training cell's length, several tiles
+    with clamped fetches (2048), and a window shorter and longer than S."""
+    causal, window = MODES[mode]
+    q, k, v = (t.astype(dtype) for t in rand_qkv(s + d, b=1, s=s, h=2, d=d))
+    ct = jax.random.normal(jax.random.PRNGKey(s), q.shape).astype(dtype)
+
+    def run(attn):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(ct)
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, causal, None, None,
+                                              None, True, window))
+    want = run(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=causal, window=window))
+    tols = (0.06,) * 4 if dtype == "bfloat16" else (1e-5, 1e-4, 1e-4, 1e-4)
+    for name, a, b, tol in zip(("out", "dq", "dk", "dv"), got, want, tols):
+        assert a.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
+                                   np.asarray(b, dtype=np.float32),
+                                   atol=tol, err_msg=name)
+
+
+LENGTHS = sorted({2 ** e for e in range(7, 18)}
+                 | {128 * m for m in (3, 5, 6, 7, 9, 10, 12, 24, 96, 1000)})
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("d", [64, 128, 256])
+def test_tile_function(d, itemsize):
+    """``_tiles`` alone: from S = 128 to 131,072 its tiles divide S, are
+    whole lane blocks, stay inside the VMEM budget (with the chunk the
+    kernels will walk them in), and keep masked calls' rows at or under
+    half the reach of a query."""
+    for s in LENGTHS:
+        for causal, window in ((False, None), (True, None), (True, 512),
+                               (True, 100), (True, 4096)):
+            rows, span = flash_ops._tiles(s, d, itemsize, causal, window)
+            chunk = flash_ops._chunk(span)
+            assert s % rows == 0 and s % span == 0 and span % chunk == 0
+            assert rows % 128 == 0 and chunk % 128 == 0
+            assert flash_ops._vmem_bytes(rows, span, chunk, d, itemsize) \
+                <= flash_ops.VMEM_BUDGET
+            if causal:
+                assert rows <= max(128, min(s, window or s) // 2)
+
+
+def test_tile_function_refuses_what_the_dispatcher_refuses(monkeypatch):
+    """No tile for a length ``_pallas_eligible`` turns away (neither whole
+    lane blocks nor one short block of whole bf16 sublane tiles); one for
+    every length it takes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for s in list(range(8, 400, 8)) + [500, 1000, 1024, 1100, 4096 + 64]:
+        x = jax.ShapeDtypeStruct((1, s, 2, 64), jnp.bfloat16)
+        if attention_ops._pallas_eligible(x, x):
+            rows, span = flash_ops._tiles(s, 64, 2, True, None)
+            assert s % rows == 0 and s % span == 0
+        else:
+            with pytest.raises(ValueError, match="not tileable"):
+                flash_ops._tiles(s, 64, 2, True, None)
