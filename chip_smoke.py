@@ -69,6 +69,18 @@ TINY = dict(
     long_seq=256, long_window=32,
 )
 
+#: the Pallas kernels each check looks for in its program's HLO.  Every
+#: name is one of ``distkeras_tpu.metrics.KERNEL_NAMES``: ``require_kernels``
+#: holds them to it here, ``tests/test_tracing.py`` in tier 1 (the package
+#: itself is imported only after ``build_native``, so not up here).
+REQUIRED_KERNELS = {
+    "SingleTrainer step": ("flash_fwd", "flash_dq", "flash_dkv"),
+    "ParallelTransformerLM step": ("fused_ce_fwd", "fused_ce_bwd"),
+    "long-context forward": ("flash_fwd",),
+    "2x1x2 step": ("fused_ce_fwd", "fused_ce_bwd"),
+}
+
+
 def emit(**line) -> None:
     print(json.dumps(line), flush=True)
 
@@ -157,9 +169,15 @@ def kernel_names(lowered_text: str) -> dict:
         re.findall(r'kernel_name = "(\w+)"', lowered_text)))
 
 
-def require_kernels(names: dict, wanted, on_tpu: bool, what: str) -> str:
-    """On the chip the compiled kernels must be in the program; on the CPU
-    rehearsal the dispatch takes its interpret/XLA branch by design."""
+def require_kernels(names: dict, what: str, on_tpu: bool) -> str:
+    """On the chip the kernels ``REQUIRED_KERNELS[what]`` must be in the
+    program; on the CPU rehearsal the dispatch takes its interpret/XLA
+    branch by design."""
+    from distkeras_tpu.metrics import KERNEL_NAMES
+    wanted = REQUIRED_KERNELS[what]
+    unknown = [k for k in wanted if k not in KERNEL_NAMES]
+    check(not unknown, f"{what}: {unknown} are not kernels the package "
+          f"names ({KERNEL_NAMES})")
     if not on_tpu:
         return "not checked (cpu rehearsal)"
     missing = [k for k in wanted if not names.get(k)]
@@ -300,9 +318,7 @@ def lm_train(cfg, seed, on_tpu):
     check(hist[-1] < hist[0] - 0.5,
           f"LM loss did not fall on the x+1 corpus: {hist}")
     names = kernel_names(lowered[0])
-    flash = require_kernels(names, ("flash_fwd", "flash_dq", "flash_dkv"),
-                            on_tpu,
-                            "SingleTrainer step")
+    flash = require_kernels(names, "SingleTrainer step", on_tpu)
 
     # -- the parallel LM: fused CE (and flash) inside shard_map --------------
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1, 1),
@@ -317,8 +333,7 @@ def lm_train(cfg, seed, on_tpu):
     pnames = kernel_names(step.lower(params, opt_state, bt, bl).as_text())
     # (its default sp_impl="ring" attends through parallel/ring.py, plain
     # XLA by design — only the fused-CE kernels belong in this program)
-    fused = require_kernels(pnames, ("fused_ce_fwd", "fused_ce_bwd"), on_tpu,
-                            "ParallelTransformerLM step")
+    fused = require_kernels(pnames, "ParallelTransformerLM step", on_tpu)
     plosses, psecs = [], []
     for _ in range(cfg["plm_steps"]):
         t0 = time.perf_counter()
@@ -681,7 +696,7 @@ def kernels(cfg, seed, on_tpu):
     fwd = jax.jit(model.apply)
     out["long_context_flash"] = require_kernels(
         kernel_names(fwd.lower(params, toks).as_text()),
-        ("flash_fwd",), on_tpu, f"long-context forward at {s}")
+        "long-context forward", on_tpu)
     check(np.isfinite(f32(fwd(params, toks))).all(),
           f"non-finite logits at seq_len {s}")
     prompt = toks[:, :16]
@@ -753,8 +768,7 @@ def parallel_lm_4(cfg, seed, on_tpu):
           f"non-finite losses: {l4} {l1}")
     check(shard1 == full and shard4 == (full[0], full[1] // 2),
           f"w1 {full}: shard on 2x1x2 is {shard4}, on 1x1x1 {shard1}")
-    kern = require_kernels(names4, ("fused_ce_fwd", "fused_ce_bwd"), on_tpu,
-                           "2x1x2 step")
+    kern = require_kernels(names4, "2x1x2 step", on_tpu)
     # bf16 compute, and tp changes the matmul reduction order; Adam's
     # first updates (sign-like steps) amplify that rounding a little
     tol = 0.01

@@ -40,8 +40,7 @@ freshness sample per stamped chunk:
 
 reported as ``freshness_p50_s`` / ``freshness_p99_s`` (row-weighted
 percentiles) in :meth:`OnlineDeployment.stats`, mirrored into
-``trainer.stream_stats`` and ``engine.stats``, and surfaced as bench
-fields (``bench.py``).
+``trainer.stream_stats`` and ``engine.stats``.
 
 **Blue/green reload** (:meth:`OnlineDeployment.blue_green_swap`): serve
 generation *g* while *g+1* warms — a ``respawn_clone()`` pulls the

@@ -76,9 +76,9 @@ time) name the compiled programs' phases in every profile and HLO dump:
 ``loss``, ``optimizer``, ``commit`` (train step and the SPMD round);
 ``kv_write``, ``kv_gather``, ``sample`` (decode step; on the kernel path
 ``kv_gather`` holds only the row lengths' preparation).  Pallas kernels
-carry ``flash_fwd``/``flash_dq``/``flash_dkv``,
-``fused_ce_fwd``/``fused_ce_bwd`` and ``paged_decode`` (under
-``attn_core`` of the paged single-token step).
+carry the names in ``KERNEL_NAMES``: ``flash_fwd``/``flash_dq``/
+``flash_dkv``, ``fused_ce_fwd``/``fused_ce_bwd`` and ``paged_decode``
+(under ``attn_core`` of the paged single-token step).
 """
 
 from __future__ import annotations
@@ -89,6 +89,12 @@ import time
 from typing import IO, Any, Dict, List, Optional
 
 import jax
+
+#: ``name=`` of every ``pallas_call`` in ``ops/``: what a trace, an HLO dump,
+#: ``chip_smoke.py``'s ``require_kernels`` and ``tests/test_tracing.py`` look
+#: the kernels up by.
+KERNEL_NAMES = ("flash_fwd", "flash_dq", "flash_dkv",
+                "fused_ce_fwd", "fused_ce_bwd", "paged_decode")
 
 
 class MetricsLogger:
@@ -171,93 +177,3 @@ def span(name: str, **fields):
     recorded only while a profiler session is open and shares the device
     trace's timeline.  The module docstring lists the program's spans."""
     return jax.profiler.TraceAnnotation(name, **fields)
-
-
-# -- analytic FLOPs + MFU ----------------------------------------------------
-
-# bf16 peak FLOP/s per chip by ``device_kind`` substring; first match wins,
-# so more specific entries come first.  Source: Google Cloud TPU
-# documentation, the per-generation system-architecture pages ("TPU v5e":
-# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s; likewise "TPU
-# v6e", "TPU v5p", "TPU v4", "TPU v3", "TPU v2"; v4i from Jouppi et al.
-# 2021, "Ten Lessons From Three Generations...").
-_PEAK_FLOPS = (
-    ("TPU v6 lite", 918e12),   # Trillium
-    ("TPU v5 lite", 197e12),   # v5e
-    ("TPU v5p", 459e12),
-    ("TPU v5", 459e12),
-    ("TPU v4 lite", 138e12),   # v4i
-    ("TPU v4", 275e12),
-    ("TPU v3", 123e12),
-    ("TPU v2", 46e12),
-)
-
-
-def peak_flops(device_kind: str) -> float:
-    """bf16 peak FLOP/s for a ``jax.devices()[0].device_kind`` string.  A
-    device that is not in the table is an error, not a default: a
-    utilization against an unknown peak is no number at all."""
-    for key, val in _PEAK_FLOPS:
-        if key.lower() in str(device_kind).lower():
-            return val
-    raise ValueError(
-        f"no peak FLOP/s known for device_kind {device_kind!r}; add it to "
-        "distkeras_tpu.metrics._PEAK_FLOPS with its source")
-
-
-def _attention_flops(layer, in_shape) -> float:
-    """Matmul FLOPs of one attention layer on one example.
-
-    Sizes the k/v projections by ``num_kv_heads`` so GQA/MQA models are not
-    overcounted (q/o stay full-width: ``num_heads * key_dim``), and caps the
-    score/value matmuls at the sliding-window width when one is set.
-    """
-    s, d = in_shape
-    inner = layer.num_heads * layer.key_dim
-    kv_heads = layer.num_kv_heads or layer.num_heads
-    inner_kv = kv_heads * layer.key_dim
-    total = 2.0 * s * d * (inner + 2.0 * inner_kv)  # q + k + v projections
-    total += 2.0 * s * inner * d                  # output projection
-    window = getattr(layer, "attention_window", None)
-    ctx = float(min(s, window + 1)) if window is not None else float(s)
-    total += 2.0 * 2.0 * s * ctx * inner          # qk^T and scores@v
-    return total
-
-
-def flops_per_example(model, backward: bool = True) -> float:
-    """Analytic matmul/conv FLOPs for one example through a ``Sequential``.
-
-    Counts the MXU work only (Dense 2·m·k·n, Conv2D 2·Ho·Wo·kh·kw·cin·cout,
-    attention/MLP projections inside TransformerBlock); elementwise/pooling
-    FLOPs are negligible against these.  ``backward=True`` applies the
-    standard 3x rule (forward + ~2x for the two backward matmuls per
-    forward matmul) — the number MFU is judged against.
-    """
-    import jax
-    import numpy as np
-    from .core import layers as L
-
-    if model.input_shape is None:
-        raise ValueError("model has no input_shape")
-    shape = tuple(model.input_shape)
-    rng = jax.random.PRNGKey(0)
-    total = 0.0
-    for layer in model.layers:
-        _, out_shape = layer.init(rng, shape)
-        if isinstance(layer, L.Dense):
-            rows = float(np.prod(shape[:-1])) if len(shape) > 1 else 1.0
-            total += 2.0 * rows * shape[-1] * layer.units
-        elif isinstance(layer, L.Conv2D):
-            ho, wo, _ = out_shape
-            kh, kw = layer.kernel_size
-            total += 2.0 * ho * wo * kh * kw * shape[-1] * layer.filters
-        elif isinstance(layer, L.Embedding):
-            pass  # gather, not matmul
-        elif isinstance(layer, L.MultiHeadAttention):
-            total += _attention_flops(layer, shape)
-        elif isinstance(layer, L.TransformerBlock):
-            s, d = shape
-            total += _attention_flops(layer, shape)
-            total += 2.0 * s * d * layer.mlp_dim * 2  # mlp in+out
-        shape = out_shape
-    return total * (3.0 if backward else 1.0)

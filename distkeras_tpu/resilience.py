@@ -328,7 +328,7 @@ class ShardSupervisor:
     rejects any in-flight commit still stamped with the old generation
     (``parameter_servers.SocketParameterServer`` — the epoch/generation
     handshake).  ``recoveries`` records one entry per respawn for
-    observability (tests + ``bench.py``'s ``host_ps_recovery_ms``).
+    observability (the tests read it).
     """
 
     def __init__(self, group, algorithm: str, num_workers: int,

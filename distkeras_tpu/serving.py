@@ -19,14 +19,11 @@ iteration-level (Orca-style) scheduling:
    ``prefills_per_step`` queued requests into free slots, then runs one
    decode step for every running request.  New work never stalls the
    running batch for more than a bounded number of prefill work units.
- - **Compiled bucketed prefill** (``prefill_mode="bucketed"``, the
-   default) — admitted prompts are right-padded to a small power-of-two
-   length-bucket ladder and prefilled TOGETHER, one jitted batched forward
-   per bucket (jit cache keyed on the bucket length; per-row
-   ``kv_length`` masking keeps pad tokens out of every softmax), replacing
-   the per-request eager ``_forward`` of the original engine — which is
-   retained, bit-identical, behind ``prefill_mode="eager"`` as the
-   reference path.
+ - **Compiled bucketed prefill** — admitted prompts are right-padded to a
+   small power-of-two length-bucket ladder and prefilled TOGETHER, one
+   jitted batched forward per bucket (jit cache keyed on the bucket
+   length; per-row ``kv_length`` masking keeps pad tokens out of every
+   softmax).
  - **Chunked prefill** — a prompt longer than ``prefill_chunk`` splits
    into chunks advanced one per scheduler iteration, interleaved with
    decode steps (Sarathi-style stall-free prefill): a 1024-token prompt
@@ -77,9 +74,10 @@ iteration-level (Orca-style) scheduling:
 
 Determinism contract: a lone request through the engine emits tokens
 BIT-IDENTICAL to offline ``generate`` under the same seed/params
-(tests/test_serving.py) — prefill runs the same eager ``_forward``,
-decode sampling runs the factored ``sample_logits_batched`` whose per-row
-math reproduces ``generate``'s ``sample_logits`` row for row.
+(tests/test_serving.py) — prefill runs the same ``_forward`` inside its
+jitted programs, decode sampling runs the factored
+``sample_logits_batched`` whose per-row math reproduces ``generate``'s
+``sample_logits`` row for row.
 
 The wire layer (``ServingServer``/``ServingClient``) speaks the same frame
 codec + ``BufferPool`` transport as the PS stack, with two opcodes of its
@@ -107,10 +105,9 @@ import numpy as np
 from . import networking
 from .core import decode as _dec
 from .core import quant as _quant
-from .core.decode import (_check_supported, _context_limit, _forward,
-                          _to_ring, _validate_rolling, _validate_sampling,
-                          _validate_stopping, _vocab_size, decode_step,
-                          init_cache, sample_logits, sample_logits_batched)
+from .core.decode import (_check_supported, _context_limit,
+                          _validate_rolling, _validate_sampling,
+                          _validate_stopping, _vocab_size, init_cache)
 from .core.model import FittedModel, Sequential
 from .metrics import span
 
@@ -822,20 +819,18 @@ class ServingEngine:
     model's positional range).  ``rolling=True`` (sliding-window models
     only) makes each slot an O(W) ring instead of ``max_len`` slots.
 
-    ``prefill_mode``: ``"bucketed"`` (default) runs the compiled fast
-    path — batched bucket prefill, chunked long-prompt prefill, and
-    device-resident decode state with one-step lookahead; ``"eager"`` is
-    the original per-request eager-``_forward`` engine, retained as the
-    bit-identical reference.  ``prefill_chunk`` bounds how many prompt
-    tokens one scheduler iteration may prefill for a single request
-    (bucketed mode): longer prompts split into chunks interleaved with
-    decode steps, so admissions never stall the running batch for more
-    than one chunk per iteration.
+    Every forward runs inside a jitted program: batched bucket prefill,
+    chunked long-prompt prefill, and device-resident decode state with
+    one-step lookahead.  ``prefill_chunk`` bounds how many prompt tokens
+    one scheduler iteration may prefill for a single request: longer
+    prompts split into chunks interleaved with decode steps, so
+    admissions never stall the running batch for more than one chunk per
+    iteration.
 
     Speculation + quantization (all default OFF — defaults are
     bit-identical to the pre-speculation engine):
 
-     - ``spec_draft`` (bucketed mode): a cheaper draft model
+     - ``spec_draft``: a cheaper draft model
        (``FittedModel`` or ``(Sequential, params)``, same vocabulary)
        turns every decode iteration into a speculative ROUND — ``spec_len``
        per-slot draft steps against the draft's own slot-pooled KV cache,
@@ -849,12 +844,12 @@ class ServingEngine:
      - ``quantize``: ``"int8"`` (weight-only post-training quantization
        through ``core.quant.quantize_params``) or ``"bf16"`` — applied at
        construction and re-applied to every ``attach_ps`` hot-reload
-       pull.  Lossy; the eager engine stays the full-precision reference.
-     - ``kv_dtype="int8"`` (bucketed mode): the slot pools (target and
+       pull.  Lossy.
+     - ``kv_dtype="int8"``: the slot pools (target and
        draft) store int8 codes + per-entry scales — roughly half the
        bf16 slot bytes, so ``num_slots`` can ~double at fixed pool HBM
        (``kv_pool_bytes`` is the byte-accounted observable).  Lossy.
-     - ``paged=True`` (bucketed mode): the slot pool becomes a PAGED KV
+     - ``paged=True``: the slot pool becomes a PAGED KV
        pool — a flat arena of ``kv_blocks`` fixed-size blocks
        (``block_size`` tokens each, int8 codes + scales paged identically
        when ``kv_dtype="int8"``) with per-request block tables, so a
@@ -882,7 +877,7 @@ class ServingEngine:
                  queue_capacity: int = 64, prefills_per_step: int = 1,
                  rolling: bool = False,
                  default_deadline_s: Optional[float] = None,
-                 prefill_mode: str = "bucketed", prefill_chunk: int = 128,
+                 prefill_chunk: int = 128,
                  spec_draft: Optional[Union[FittedModel,
                                             Tuple[Sequential, Any]]] = None,
                  spec_len: int = 4,
@@ -927,12 +922,6 @@ class ServingEngine:
         self.role = role
         # -- speculation + quantization knobs (all default OFF: the engine
         #    is bit-identical to its pre-speculation self until asked)
-        if prefill_mode == "eager" and (spec_draft is not None
-                                        or kv_dtype is not None or paged):
-            raise ValueError(
-                "spec_draft / kv_dtype / paged are fast-path features "
-                "(prefill_mode='bucketed'); the eager engine stays the "
-                "unmodified bit-exactness reference")
         if quantize not in (None, "int8", "bf16"):
             raise ValueError(f"quantize must be None, 'int8' or 'bf16', "
                              f"got {quantize!r}")
@@ -987,10 +976,6 @@ class ServingEngine:
         self.rolling = bool(rolling)
         self.queue_capacity = int(queue_capacity)
         self.prefills_per_step = max(int(prefills_per_step), 1)
-        if prefill_mode not in ("bucketed", "eager"):
-            raise ValueError(f"prefill_mode must be 'bucketed' or 'eager', "
-                             f"got {prefill_mode!r}")
-        self.prefill_mode = prefill_mode
         if int(prefill_chunk) < 1:
             raise ValueError(f"prefill_chunk must be >= 1, got "
                              f"{prefill_chunk}")
@@ -1074,12 +1059,7 @@ class ServingEngine:
         self._handles: List[Optional[RequestHandle]] = [None] * self.num_slots
         self._free: List[int] = list(range(self.num_slots - 1, -1, -1))
         self._positions = np.zeros((self.num_slots,), np.int32)
-        self._cur_tok = np.zeros((self.num_slots,), np.int32)
         self._active = np.zeros((self.num_slots,), bool)
-        self._temp = np.zeros((self.num_slots,), np.float32)
-        self._topk = np.zeros((self.num_slots,), np.int32)    # 0 = off
-        self._topp = np.zeros((self.num_slots,), np.float32)  # 0 = off
-        self._keys = np.zeros((self.num_slots, 2), np.uint32)
 
         # -- admission queues (the ONLY cross-thread state besides
         #    handles): one FIFO list per tenant, picked by stride-based
@@ -1118,78 +1098,66 @@ class ServingEngine:
         self._int_blocked = False
         self._can_preempt = (self.paged and not self.rolling
                              and self._draft_model is None
-                             and self.role == "unified"
-                             and self.prefill_mode == "bucketed")
+                             and self.role == "unified")
         self._swap_gather_fn = None
         self._swap_ingest_fn = None
 
-        # -- jitted programs (compiled once per engine: shapes are fixed)
-        self._step_fn = self._build_step_fn()
-        self._write_slot_fn = jax.jit(
-            lambda big, row, s: tmap(
-                lambda B, r: jax.lax.dynamic_update_slice(
-                    B, r, (s, 0, 0, 0)), big, row),
-            donate_argnums=(0,))
-
-        # -- compiled prefill fast path + device-resident decode state
-        #    (bucketed mode; the eager reference keeps the host arrays
-        #    above authoritative and uploads them every step)
+        # -- compiled prefill programs + device-resident decode state
+        #    (compiled once per engine and shape key: shapes are fixed)
         self._chunk_width = min(self.prefill_chunk, self.max_len)
         self._buckets = _pow2_buckets(self._chunk_width)
         self._pending: "collections.deque" = collections.deque()
         self._iterations = 0  # step() calls so far (serve.iteration's `it`)
         self._prefilling: Dict[int, _PrefillJob] = {}
-        self._lookahead = 1 if self.prefill_mode == "bucketed" else 0
         #: what the decode program's attention reads K and V through:
         #: "kernel" (ops.paged_attention, in place) or "gather" — settled
         #: where the program is built, reported on serve.decode_dispatch
         self._decode_attn = "gather"
-        if self.prefill_mode == "bucketed":
-            # params live on device once: the decode loop must not re-ship
-            # the weights (or anything else) host→device per iteration
-            self.params = jax.device_put(self.params)
-            self._dev_tok = jnp.zeros((self.num_slots,), jnp.int32)
-            self._dev_pos = jnp.zeros((self.num_slots,), jnp.int32)
-            self._dev_act = jnp.zeros((self.num_slots,), bool)
-            self._dev_temp = jnp.zeros((self.num_slots,), jnp.float32)
-            self._dev_topk = jnp.zeros((self.num_slots,), jnp.int32)
-            self._dev_topp = jnp.zeros((self.num_slots,), jnp.float32)
-            self._dev_keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
-            if self.paged:
-                # device-resident block tables (one row per slot, null-
-                # filled — null = kv_blocks, the arena's junk block) plus
-                # the host-side allocator/prefix-trie.  A retired slot's
-                # table row is re-nulled so its idle decode passes junk
-                # into the null block, never a reallocated block.
-                bs = self.block_size
-                self._t_tbl = -(-self._t_view // bs) + 1
-                self._dev_bt = jnp.full((self.num_slots, self._t_tbl),
-                                        self.kv_blocks, jnp.int32)
-                if self._draft_model is not None:
-                    self._d_tbl = -(-self.max_len // bs) + 1
-                    self._dev_dbt = jnp.full(
-                        (self.num_slots, self._d_tbl), self.kv_blocks,
-                        jnp.int32)
-                else:
-                    self._dev_dbt = None
-                self._copy_fn = self._build_copy_fn()
-                if self.role == "prefill":
-                    # read-only arena gather (the extraction half of a
-                    # disaggregated transfer) — fixed (blocks_per_slot ×
-                    # block_size) row vector, so one trace serves every
-                    # prompt length (junk rows gather the null block and
-                    # are sliced off on host)
-                    self._gather_fn = jax.jit(_dec.gather_blocks)
-                if self.role == "decode":
-                    self._ingest_fn = self._build_ingest_fn()
-            self._decode_fn = self._build_device_step_fn()
-            self._deact_fn = self._build_deact_fn()
-            self._bucket_fns: Dict[int, Any] = {}
-            self._stage_fns: Dict[int, Any] = {}
-            self._final_fns: Dict[int, Any] = {}
+        # params live on device once: the decode loop must not re-ship
+        # the weights (or anything else) host→device per iteration
+        self.params = jax.device_put(self.params)
+        self._dev_tok = jnp.zeros((self.num_slots,), jnp.int32)
+        self._dev_pos = jnp.zeros((self.num_slots,), jnp.int32)
+        self._dev_act = jnp.zeros((self.num_slots,), bool)
+        self._dev_temp = jnp.zeros((self.num_slots,), jnp.float32)
+        self._dev_topk = jnp.zeros((self.num_slots,), jnp.int32)
+        self._dev_topp = jnp.zeros((self.num_slots,), jnp.float32)
+        self._dev_keys = jnp.zeros((self.num_slots, 2), jnp.uint32)
+        if self.paged:
+            # device-resident block tables (one row per slot, null-
+            # filled — null = kv_blocks, the arena's junk block) plus
+            # the host-side allocator/prefix-trie.  A retired slot's
+            # table row is re-nulled so its idle decode passes junk
+            # into the null block, never a reallocated block.
+            bs = self.block_size
+            self._t_tbl = -(-self._t_view // bs) + 1
+            self._dev_bt = jnp.full((self.num_slots, self._t_tbl),
+                                    self.kv_blocks, jnp.int32)
             if self._draft_model is not None:
-                self._draft_params = jax.device_put(self._draft_params)
-                self._spec_fn = self._build_spec_fn()
+                self._d_tbl = -(-self.max_len // bs) + 1
+                self._dev_dbt = jnp.full(
+                    (self.num_slots, self._d_tbl), self.kv_blocks,
+                    jnp.int32)
+            else:
+                self._dev_dbt = None
+            self._copy_fn = self._build_copy_fn()
+            if self.role == "prefill":
+                # read-only arena gather (the extraction half of a
+                # disaggregated transfer) — fixed (blocks_per_slot ×
+                # block_size) row vector, so one trace serves every
+                # prompt length (junk rows gather the null block and
+                # are sliced off on host)
+                self._gather_fn = jax.jit(_dec.gather_blocks)
+            if self.role == "decode":
+                self._ingest_fn = self._build_ingest_fn()
+        self._decode_fn = self._build_device_step_fn()
+        self._deact_fn = self._build_deact_fn()
+        self._bucket_fns: Dict[int, Any] = {}
+        self._stage_fns: Dict[int, Any] = {}
+        self._final_fns: Dict[int, Any] = {}
+        if self._draft_model is not None:
+            self._draft_params = jax.device_put(self._draft_params)
+            self._spec_fn = self._build_spec_fn()
 
         # -- hot weight reload (stretch; off unless attach_ps is called)
         self._ps_addr: Optional[Tuple[str, int]] = None
@@ -1328,25 +1296,9 @@ class ServingEngine:
         }
 
     # ------------------------------------------------------------------ jit
-    def _build_step_fn(self):
-        model, rolling = self.model, self.rolling
-
-        def step(params, caches, tok, positions, active, temp, topk, topp,
-                 keys):
-            logits, caches = decode_step(model, params, caches, tok,
-                                         positions, rolling)
-            nxt = sample_logits_batched(logits, positions, temp, keys,
-                                        topk, topp)
-            # active mask: free slots keep their token (their row computes a
-            # junk forward into their own cache row, which the next
-            # prefill fully overwrites — never into anyone else's)
-            return jnp.where(active, nxt, tok), caches
-
-        return jax.jit(step, donate_argnums=(1,))
-
     # ------------------------------------------- compiled prefill programs
     #
-    # The fast path's whole compute surface is a handful of jitted
+    # The engine's whole compute surface is a handful of jitted
     # programs, cached per shape key so live traffic never re-traces:
     #
     #  - ``_bucket_fn(L)`` — ONE batched forward prefills up to
@@ -1371,8 +1323,7 @@ class ServingEngine:
     #    prompt positions).
     #
     # Every traced call goes through ``_dec`` (the decode MODULE) so a
-    # trace is observable/countable; the module-level ``_forward`` import
-    # is the EAGER path's — the bucketed hot path never calls it.
+    # trace is observable/countable.
 
     def _bucket_fn(self, width: int):
         fn = self._bucket_fns.get(width)
@@ -1393,7 +1344,7 @@ class ServingEngine:
         return fn
 
     def _build_device_step_fn(self):
-        """The bucketed-mode decode step: state advances ON DEVICE (donated
+        """The decode step: state advances ON DEVICE (donated
         caches, new positions), so a steady-state iteration uploads nothing
         and reads back only the sampled token row.  Paged engines take the
         device block tables as an extra (read-only) argument and write/
@@ -2667,46 +2618,6 @@ class ServingEngine:
                     round((now - h.deadline) * 1e3, 3))
 
     # ------------------------------------------------------------- prefill
-    def _prefill(self, slot: int, h: RequestHandle) -> None:
-        """EAGER-mode admission (``prefill_mode="eager"``, the reference
-        path): one per-request prompt forward through the same eager
-        ``_forward`` offline ``generate`` prefills with — identical
-        numerics — first token sampled at ``p_len - 1`` through the shared
-        ``sample_logits``, cache row scattered into the pool."""
-        p_len = len(h.prompt)
-        prompt = jnp.asarray(h.prompt[None], jnp.int32)
-        row = init_cache(self.model, 1,
-                         p_len if self.rolling else self.max_len)
-        logits, row = _forward(self.model, self.params, row, prompt, 0)
-        first = sample_logits(logits[:, -1], p_len - 1, h.temperature,
-                              h.key, h.top_k, h.top_p)
-        if self.rolling:
-            ringed = []
-            for layer, cache in zip(self.model.layers, row):
-                if cache is None:
-                    ringed.append(None)
-                    continue
-                w = layer._mha().attention_window
-                ringed.append({name: _to_ring(cache[name], p_len, w)
-                               for name in ("k", "v")})
-            row = ringed
-        self.caches = self._write_slot_fn(self.caches, row,
-                                          jnp.int32(slot))
-        h.slot = slot
-        h.started_at = time.perf_counter()
-        self._handles[slot] = h
-        self._positions[slot] = p_len
-        self._cur_tok[slot] = int(first[0])
-        self._active[slot] = True
-        self._temp[slot] = h.temperature
-        self._topk[slot] = 0 if h.top_k is None else int(h.top_k)
-        self._topp[slot] = 0.0 if h.top_p is None else float(h.top_p)
-        self._keys[slot] = np.asarray(h.key, np.uint32)
-        self.stats["prefills"] += 1
-        self.stats["slot_requests"][slot] += 1
-        self.stats["prefill_tokens"] += p_len
-        self._emit(slot, int(first[0]))
-
     def _bucket_of(self, n: int) -> int:
         for b in self._buckets:
             if b >= n:
@@ -2717,10 +2628,9 @@ class ServingEngine:
         """Spend up to ``prefills_per_step`` prefill work units this
         iteration: first advance chunked prefills already holding slots
         (one chunk each — finishing started work bounds every occupant's
-        TTFT), then admit queued requests.  Bucketed mode gathers short
-        prompts into per-bucket batches (one jitted forward each) and
-        routes prompts longer than ``prefill_chunk`` to the chunked path;
-        eager mode prefills per request, as it always did.
+        TTFT), then admit queued requests: short prompts gather into
+        per-bucket batches (one jitted forward each), prompts longer
+        than ``prefill_chunk`` go to the chunked path.
 
         Paged engines additionally walk the radix index per admission:
         matched prefix blocks are shared (COW at a partial boundary), the
@@ -2785,12 +2695,7 @@ class ServingEngine:
                     break
             budget -= 1
             did = True
-            if self.prefill_mode == "eager":
-                with span("serve.prefill_unit", rid=h.id,
-                          tokens=len(h.prompt), kind="eager",
-                          width=len(h.prompt), hit=0):
-                    self._prefill(self._free.pop(), h)
-            elif (len(h.prompt) - (plan.matched if plan else 0)
+            if (len(h.prompt) - (plan.matched if plan else 0)
                     > self.prefill_chunk):
                 self._start_chunked(self._free.pop(), h, plan)
             else:
@@ -2895,7 +2800,6 @@ class ServingEngine:
             self._put(np.float32(0.0 if h.top_p is None else h.top_p)),
             self._put(np.asarray(h.key, np.uint32)))
         self._mirror_admit(slot, h)
-        self._cur_tok[slot] = h.tokens[0]
         self.stats["kv_blocks_ingested"] += n_src
         self.stats["kv_block_bytes_ingested"] += kvb.nbytes
         self.stats["transfer_ms"].append(
@@ -3120,14 +3024,9 @@ class ServingEngine:
 
     def _mirror_admit(self, slot: int, h: RequestHandle) -> None:
         """Host mirrors of the per-slot state the prefill program just set
-        on device — the scheduler's bookkeeping view (``_cur_tok`` lands
-        when the first token is drained)."""
+        on device — the scheduler's bookkeeping view."""
         self._active[slot] = True
         self._positions[slot] = len(h.prompt)
-        self._temp[slot] = h.temperature
-        self._topk[slot] = 0 if h.top_k is None else int(h.top_k)
-        self._topp[slot] = 0.0 if h.top_p is None else float(h.top_p)
-        self._keys[slot] = np.asarray(h.key, np.uint32)
 
     def _finish_prefilled(self, slot: int, token: int) -> None:
         """Prefill role's hand-off: the drained first token means this
@@ -3175,38 +3074,35 @@ class ServingEngine:
         elif len(h.tokens) >= h.num_steps:
             self._retire(slot, "length")
 
+    def _vacate(self, slot: int) -> None:
+        """Free a running request's slot, host row and device row.  An
+        in-flight lookahead step may compute one junk token for the row
+        (drained entries skip finished handles), but from the next
+        dispatch on the slot is inert until a prefill program rewrites it.
+        Paged: the block-table row is re-nulled IN THE SAME program, so
+        that junk (and every later idle pass) drops into the null block
+        while the released blocks go back to the allocator — the one
+        in-flight lookahead write ordered before any program that could
+        reuse them."""
+        self._handles[slot] = None
+        self._active[slot] = False
+        self._positions[slot] = 0
+        self._free.append(slot)
+        if not self.paged:
+            self._dev_act = self._deact_fn(self._dev_act, slot)
+            return
+        if self._draft_model is None:
+            self._dev_act, self._dev_bt = self._deact_fn(
+                self._dev_act, self._dev_bt, slot)
+        else:
+            (self._dev_act, self._dev_bt, self._dev_dbt) = self._deact_fn(
+                self._dev_act, self._dev_bt, self._dev_dbt, slot)
+        self._release_blocks(slot)
+
     def _retire(self, slot: int, reason: str) -> None:
         h = self._handles[slot]
         with span("serve.retire", rid=h.id, reason=reason):
-            self._handles[slot] = None
-            self._active[slot] = False
-            self._temp[slot] = 0.0
-            self._topk[slot] = 0
-            self._topp[slot] = 0.0
-            self._positions[slot] = 0
-            self._cur_tok[slot] = 0
-            self._free.append(slot)
-            if self.prefill_mode == "bucketed":
-                # deactivate the device row too: an in-flight lookahead step
-                # may compute one junk token for it (drained entries skip
-                # finished handles), but from the next dispatch on the slot is
-                # inert until a prefill program rewrites it.  Paged: the
-                # block-table row is re-nulled IN THE SAME program, so that
-                # junk (and every later idle pass) drops into the null block
-                # while the released blocks go back to the allocator — the
-                # one in-flight lookahead write ordered before any program
-                # that could reuse them
-                if self.paged:
-                    if self._draft_model is None:
-                        self._dev_act, self._dev_bt = self._deact_fn(
-                            self._dev_act, self._dev_bt, slot)
-                    else:
-                        (self._dev_act, self._dev_bt,
-                         self._dev_dbt) = self._deact_fn(
-                            self._dev_act, self._dev_bt, self._dev_dbt, slot)
-                    self._release_blocks(slot)
-                else:
-                    self._dev_act = self._deact_fn(self._dev_act, slot)
+            self._vacate(slot)
             if h._finish(reason):  # no-op when _declare_dead already failed it
                 with self._qlock:  # drain()'s busy() sums this cross-thread
                     self.stats["requests_completed"] += 1
@@ -3223,8 +3119,8 @@ class ServingEngine:
         The deterministic-control surface tests and operators use; the
         scheduler fires the same path itself when the interactive tier is
         starved.  Returns False when the request already finished or this
-        engine cannot preempt (needs ``paged=True``, bucketed prefill,
-        ``role="unified"``, no rolling window, no speculation)."""
+        engine cannot preempt (needs ``paged=True``, ``role="unified"``,
+        no rolling window, no speculation)."""
         if not self._can_preempt:
             return False
         with handle._cond:
@@ -3284,21 +3180,7 @@ class ServingEngine:
         rec = _SuspendedReq(h, layers, n_src, pos, tok)
         # free the slot + blocks exactly like _retire, minus the terminal
         # transition: the handle stays live, parked in _suspended
-        self._handles[slot] = None
-        self._active[slot] = False
-        self._temp[slot] = 0.0
-        self._topk[slot] = 0
-        self._topp[slot] = 0.0
-        self._positions[slot] = 0
-        self._cur_tok[slot] = 0
-        self._free.append(slot)
-        if self._draft_model is None:
-            self._dev_act, self._dev_bt = self._deact_fn(
-                self._dev_act, self._dev_bt, slot)
-        else:
-            (self._dev_act, self._dev_bt, self._dev_dbt) = self._deact_fn(
-                self._dev_act, self._dev_bt, self._dev_dbt, slot)
-        self._release_blocks(slot)
+        self._vacate(slot)
         h.slot = None
         nbytes = sum(a.nbytes for c in layers if c is not None
                      for a in c.values())
@@ -3369,8 +3251,8 @@ class ServingEngine:
             self._put(np.float32(0.0 if h.top_p is None else h.top_p)),
             self._put(np.asarray(h.key, np.uint32)))
         self._mirror_admit(slot, h)
-        self._positions[slot] = rec.pos   # the suspended frontier, not
-        self._cur_tok[slot] = rec.tok     # the prompt boundary
+        # the suspended frontier, not the prompt boundary
+        self._positions[slot] = rec.pos
         with self._qlock:
             self.stats["resumes"] += 1
             self._tenant_stats(h.tenant)["resumes"] += 1
@@ -3501,28 +3383,7 @@ class ServingEngine:
             return did
 
     def _decode_once(self) -> None:
-        if self.prefill_mode == "eager":
-            self.stats["decode_steps"] += 1
-            step = self.stats["decode_steps"]
-            live = np.flatnonzero(self._active)
-            with span("serve.decode_dispatch", active=len(live), step=step,
-                      attn=self._decode_attn):
-                nxt, self.caches = self._step_fn(
-                    self.params, self.caches, jnp.asarray(self._cur_tok),
-                    jnp.asarray(self._positions), jnp.asarray(self._active),
-                    jnp.asarray(self._temp), jnp.asarray(self._topk),
-                    jnp.asarray(self._topp), jnp.asarray(self._keys))
-            with span("serve.fetch", step=step):
-                nxt = np.asarray(nxt)
-            self.stats["active_slot_steps"] += len(live)
-            with span("serve.emit", kind="decode", rows=len(live),
-                      step=step):
-                for slot in live:
-                    self._positions[slot] += 1
-                    self._cur_tok[slot] = nxt[slot]
-                    self._emit(int(slot), int(nxt[slot]))
-            return
-        # bucketed: dispatch only — every argument is already a device
+        # dispatch only — every argument is already a device
         # array (zero uploads), and the sampled row is fetched one
         # iteration later by _drain_pending (one-step lookahead)
         entries = [(int(s), self._handles[s])
@@ -3559,7 +3420,7 @@ class ServingEngine:
         skipped: the lookahead step computed one junk token for it, which
         dies here."""
         did = False
-        keep = 0 if flush else self._lookahead
+        keep = 0 if flush else 1
         while len(self._pending) > keep:
             kind, arr, entries, step = self._pending.popleft()
             with span("serve.fetch", step=step):
@@ -3579,9 +3440,7 @@ class ServingEngine:
                         self.stats["accepted"] += max(n - 1, 0)
                         self._positions[slot] += n
                         for j in range(n):
-                            token = int(vals[slot, j])
-                            self._cur_tok[slot] = token
-                            self._emit(slot, token)
+                            self._emit(slot, int(vals[slot, j]))
                             if (h.finish is not None
                                     or self._handles[slot] is not h):
                                 break
@@ -3589,7 +3448,6 @@ class ServingEngine:
                     token = int(vals[slot] if kind == "decode" else vals[i])
                     if kind == "decode":
                         self._positions[slot] += 1
-                    self._cur_tok[slot] = token
                     if self.role == "prefill":
                         self._finish_prefilled(slot, token)
                     else:
@@ -3828,7 +3686,6 @@ class ServingEngine:
             max_len=self.max_len, queue_capacity=self.queue_capacity,
             prefills_per_step=self.prefills_per_step, rolling=self.rolling,
             default_deadline_s=self.default_deadline_s,
-            prefill_mode=self.prefill_mode,
             prefill_chunk=self.prefill_chunk,
             spec_draft=(None if self._draft_model is None
                         else (self._draft_model, self._draft_params)),
@@ -3878,9 +3735,8 @@ class ServingEngine:
 
     def warmup(self) -> "ServingEngine":
         """Compile the engine's jitted programs before serving traffic: the
-        decode step plus — in bucketed mode — EVERY bucket's batched
-        prefill program and (when long prompts can chunk) the chunk-step
-        programs.  A fresh engine otherwise pays each program's jit
+        decode step plus EVERY bucket's batched prefill program and (when
+        long prompts can chunk) the chunk-step programs.  A fresh engine otherwise pays each program's jit
         trace/compile inside the first real iteration that needs it —
         under an ``EngineSupervisor`` whose ``liveness_deadline`` is
         shorter than that compile, a cold engine is indistinguishable
@@ -3896,28 +3752,7 @@ class ServingEngine:
         def program(name):  # one span per program compiled
             return span("serve.warmup", program=name)
 
-        if self.prefill_mode == "eager":
-            with program("step"):
-                nxt, self.caches = self._step_fn(
-                    self.params, self.caches, jnp.asarray(self._cur_tok),
-                    jnp.asarray(self._positions),
-                    jnp.asarray(self._active), jnp.asarray(self._temp),
-                    jnp.asarray(self._topk), jnp.asarray(self._topp),
-                    jnp.asarray(self._keys))
-                jax.block_until_ready(nxt)
-            # slot-write program: rewrite row 0 with a copy of itself (a
-            # copy — the pool is donated, and XLA rejects donating a
-            # buffer aliased by another argument; inactive slots hold junk
-            # a prefill fully overwrites, so this is a no-op in the same
-            # sense as the free-slot decode rows)
-            with program("write_slot"):
-                row = tmap(lambda B: jnp.copy(B[0:1]), self.caches)
-                self.caches = self._write_slot_fn(self.caches, row,
-                                                  jnp.int32(0))
-                jax.block_until_ready(
-                    jax.tree_util.tree_leaves(self.caches)[0])
-            return self
-        # bucketed: one all-slots-inactive decode step (the speculative
+        # one all-slots-inactive decode step (the speculative
         # round — draft steps + verify + back-fill — when a draft is
         # attached: a respawn under live traffic must pay zero jit on its
         # first real round)...
@@ -4225,10 +4060,9 @@ class ServingEngine:
             else:
                 self.params = self.model.set_weights(self.params,
                                                      msg["weights"])
-            if self.prefill_mode == "bucketed":
-                # keep the weights device-resident: the decode loop's
-                # zero-upload contract must survive a reload
-                self.params = jax.device_put(self.params)
+            # keep the weights device-resident: the decode loop's
+            # zero-upload contract must survive a reload
+            self.params = jax.device_put(self.params)
             self.stats["weight_reloads"] += 1
             self.stats["reloads"] += 1
             clock = msg.get("clock") if isinstance(msg, dict) else None

@@ -23,8 +23,8 @@ from .data.dataset import Dataset
 def use_compile_cache() -> str:
     """Point jax's persistent compilation cache somewhere that survives the
     process, and return where.  Entry points that compile for the chip
-    (``chip_smoke.py``, ``bench.py``) call this before their first jit;
-    nothing under ``tests/`` does.
+    (``chip_smoke.py``) call this before their first jit; nothing under
+    ``tests/`` does.
 
     ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself, so no cache
     option is touched here.  Unset: ``<checkout>/.jax_cache`` — a FIXED

@@ -312,7 +312,7 @@ class PSWorker(Worker):
         # device (see _train_epoch_overlapped for the staleness contract)
         self.comm_overlap = bool(comm_overlap)
         #: messages initiated toward the PS (each 'p'/'c'/'u' counts 1) —
-        #: the transport-cost observable bench.py and tests read
+        #: the transport-cost observable the tests read
         self.transport_ops = 0
         # fault injection (SURVEY §5: the reference had none): worker id ->
         # (kind, budget) — the worker faults at its budget+1-th commit with

@@ -6,9 +6,8 @@ continuation lengths) in two modes:
 
  - **closed loop** (``run_closed_loop``): N concurrent "users", each
    submitting its next request the moment the previous one completes —
-   the canonical serving-bench harness (offered load == capacity at the
-   given concurrency).  This is what ``bench.py``'s ``serving_*`` fields
-   run.
+   the canonical closed-loop harness (offered load == capacity at the
+   given concurrency).
  - **open loop / offered QPS** (``run_open_loop``): requests arrive on a
    fixed schedule at a target rate regardless of completion, so latency
    degradation under overload (and queue backpressure shedding) is
@@ -18,7 +17,7 @@ continuation lengths) in two modes:
 ``sequential_baseline`` runs the SAME trace through offline per-request
 ``generate`` — one request at a time, no batching — which is the
 comparison continuous batching must beat at ≥ 4 concurrent requests
-(tests/test_serving_bench.py asserts it; ``bench.py`` records it).
+(tests/test_serving_bench.py asserts it).
 
 Run:  JAX_PLATFORMS=cpu python examples/loadgen.py [--requests 24]
       [--slots 4] [--concurrency 8] [--qps-sweep 20,50,100]
@@ -63,10 +62,10 @@ def make_trace(num_requests: int, seed: int = 0, vocab: int = 16,
     past the engine's ``prefill_chunk`` to exercise chunked prefill).
 
     ``pattern="arith"`` draws each prompt as a seeded-start x+1 (mod
-    vocab) run instead of iid tokens — in-distribution for the
-    ``build_spec_engine`` trained pair, the way real serving prompts are
-    in-distribution for a production draft (speculation's accept rate,
-    and therefore its win, is a property of the traffic).
+    vocab) run instead of iid tokens — in-distribution for a pair
+    trained on the x+1 task (tests/test_speculative.py), the way real
+    serving prompts are in-distribution for a production draft
+    (speculation's accept rate is a property of the traffic).
 
     ``pattern="bimodal"`` is the disaggregation interference trace
     (DistServe/Splitwise): a ``long_fraction`` of requests are
@@ -83,8 +82,7 @@ def make_trace(num_requests: int, seed: int = 0, vocab: int = 16,
     each followed by the request's own drawn suffix.  With a paged
     engine every admission after a group's first is a prefix hit that
     prefills only the suffix; a dense engine prefills ``prefix_len +
-    suffix`` every time — the TTFT comparison ``bench.py``'s
-    ``serving_prefix_ttft_p99_ms`` leg measures.
+    suffix`` every time.
 
     ``tenants``/``tier_mix``: the MIXED-TENANT QoS trace (PR 18) — with
     ``tenants >= 2``, a ``tier_mix`` fraction of requests carry
@@ -157,10 +155,8 @@ def qos_policies(tenants: int = 2, interactive_weight: float = 4.0,
 def run_overload(engine, trace: Sequence[Dict[str, Any]], qps: float,
                  timeout_s: float = 300.0) -> Dict[str, Any]:
     """The QoS overload leg: open-loop arrivals at an offered ``qps``
-    past capacity over a mixed-tenant trace.  The acceptance shape
-    (bench fields ``serving_interactive_p99_ms_under_overload`` /
-    ``serving_batch_completion_rate`` / ``serving_preempt_resume_ms``):
-    the interactive tier holds its latency band — weighted-fair
+    past capacity over a mixed-tenant trace.  The acceptance shape: the
+    interactive tier holds its latency band — weighted-fair
     admission pops it first and starvation preempts batch-tier slots —
     while the batch tier absorbs ALL the queueing, shedding, and
     preemption."""
@@ -432,9 +428,7 @@ def run_wire_closed_loop(addr, trace: Sequence[Dict[str, Any]],
     what it measures is the server's transport core, not just the engine.
     At 64 clients the thread-per-connection core holds 64 server-side
     relay threads while the event core holds ONE selector thread —
-    ``server_conn_threads_peak`` samples that difference mid-flight (the
-    O(1)-vs-O(N) observable ``bench.py``'s ``serving_connection_scaling``
-    field records alongside tokens/sec per core × client count)."""
+    ``server_conn_threads_peak`` samples that difference mid-flight."""
     from distkeras_tpu.serving import ServingClient
 
     it = iter(trace)
@@ -536,7 +530,6 @@ def sequential_baseline(fitted, trace: Sequence[Dict[str, Any]],
 
 def build_engine(num_slots: int = 4, max_len: int = 32, vocab: int = 16,
                  queue_capacity: int = 64, seed: int = 0,
-                 prefill_mode: str = "bucketed",
                  prefill_chunk: Optional[int] = None,
                  prefills_per_step: Optional[int] = None,
                  spec_draft: Optional[str] = None,
@@ -549,11 +542,9 @@ def build_engine(num_slots: int = 4, max_len: int = 32, vocab: int = 16,
                  disaggregate: bool = False,
                  prefill_engines: int = 1):
     """A small random-weight LM + engine (throughput benches measure
-    scheduling and batching, not model quality) — one place so bench,
-    tests, and the CLI agree on the workload shape.  ``prefill_mode``/
-    ``prefill_chunk``/``prefills_per_step`` pass through to the engine
-    (the TTFT comparison legs run the same trace through ``"bucketed"``
-    and ``"eager"``).
+    scheduling and batching, not model quality) — one place so tests and
+    the CLI agree on the workload shape.  ``prefill_chunk``/
+    ``prefills_per_step`` pass through to the engine.
 
     ``spec_draft``: ``"self"`` uses the target as its own draft (high
     accept rate — the round-collapsing win is real because the whole
@@ -577,7 +568,7 @@ def build_engine(num_slots: int = 4, max_len: int = 32, vocab: int = 16,
                            compute_dtype="float32")
     params = model.init(jax.random.PRNGKey(seed), (max_len,))
     fitted = FittedModel(model, params)
-    kw: Dict[str, Any] = {"prefill_mode": prefill_mode}
+    kw: Dict[str, Any] = {}
     if prefill_chunk is not None:
         kw["prefill_chunk"] = int(prefill_chunk)
     if prefills_per_step is not None:
@@ -620,7 +611,6 @@ def build_engine(num_slots: int = 4, max_len: int = 32, vocab: int = 16,
 def build_fleet(replicas: int = 2, affinity: str = "prefix",
                 num_slots: int = 4, max_len: int = 32, vocab: int = 16,
                 queue_capacity: int = 64, seed: int = 0,
-                prefill_mode: str = "bucketed",
                 prefill_chunk: Optional[int] = None,
                 paged: bool = False,
                 block_size: Optional[int] = None,
@@ -629,7 +619,7 @@ def build_fleet(replicas: int = 2, affinity: str = "prefix",
                 tenants=None):
     """``replicas`` identical engines serving the SAME weights behind a
     :class:`distkeras_tpu.router.ServingRouter` — the fleet analog of
-    ``build_engine`` (one model build, N engines, so what the bench
+    ``build_engine`` (one model build, N engines, so what a run
     measures is routing + replication, not N different models).  The
     router gets an ``engine_factory`` too, so ``autoscale_tick`` /
     ``scale_up`` work out of the box on the returned fleet."""
@@ -645,7 +635,7 @@ def build_fleet(replicas: int = 2, affinity: str = "prefix",
                            compute_dtype="float32")
     params = model.init(jax.random.PRNGKey(seed), (max_len,))
     fitted = FittedModel(model, params)
-    kw: Dict[str, Any] = {"prefill_mode": prefill_mode}
+    kw: Dict[str, Any] = {}
     if prefill_chunk is not None:
         kw["prefill_chunk"] = int(prefill_chunk)
     if paged:
@@ -695,49 +685,6 @@ def fleet_report(router, closed: Dict[str, Any]) -> Dict[str, Any]:
     }
 
 
-def build_spec_engine(num_slots: int = 4, max_len: int = 32,
-                      vocab: int = 16, queue_capacity: int = 64,
-                      spec_len: int = 4, num_epoch: int = 25,
-                      seed: int = 0, **engine_kw):
-    """A TRAINED (2-layer target, 1-layer draft) pair on the
-    deterministic x+1 token task + a speculative engine over them — the
-    honest speculative configuration: the draft is roughly half the
-    target's compute yet proposes what the target would emit (accept
-    rate ≳ 0.8 — tests/test_speculative.py trains the same pair), so a
-    round commits ~``spec_len`` tokens for less than ``spec_len + 1``
-    target-step-equivalents of compute ON TOP of collapsing the round to
-    one dispatch.  ``bench.py``'s ``serving_spec_*`` leg runs this
-    against the plain fast path (identical architecture, so service
-    times are comparable)."""
-    import jax  # noqa: F401  (platform init before model building)
-
-    from distkeras_tpu.data.dataset import Dataset
-    from distkeras_tpu.models import transformer_lm
-    from distkeras_tpu.serving import ServingEngine
-    from distkeras_tpu.trainers import SingleTrainer
-
-    rng = np.random.default_rng(seed)
-    x = rng.integers(0, vocab, (256, 12)).astype(np.int32)
-    y = (x + 1) % vocab
-
-    def train(layers):
-        model = transformer_lm(vocab_size=vocab, seq_len=max_len,
-                               d_model=32, num_heads=4, num_layers=layers,
-                               mlp_dim=64, compute_dtype="float32")
-        t = SingleTrainer(
-            model, batch_size=32, num_epoch=num_epoch,
-            loss="sparse_categorical_crossentropy_from_logits",
-            worker_optimizer="adam", learning_rate=3e-3)
-        return t.train(Dataset({"features": x, "label": y}))
-
-    target, draft = train(2), train(1)
-    engine = ServingEngine(target, num_slots=num_slots, max_len=max_len,
-                           queue_capacity=queue_capacity,
-                           spec_draft=draft, spec_len=spec_len,
-                           **engine_kw)
-    return target, draft, engine
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=24)
@@ -755,10 +702,6 @@ def main():
     ap.add_argument("--chaos-seed", type=int, default=0)
     ap.add_argument("--deadline", type=float, default=None,
                     help="per-request deadline_s stamped on every request")
-    ap.add_argument("--prefill-mode", choices=("bucketed", "eager"),
-                    default="bucketed",
-                    help="engine prefill path: the compiled bucketed fast "
-                         "path (default) or the eager reference")
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="chunked-prefill threshold/size (tokens); prompts "
                          "longer than this interleave with decode steps")
@@ -863,7 +806,6 @@ def main():
                                      affinity=args.affinity,
                                      num_slots=args.slots,
                                      max_len=args.max_len,
-                                     prefill_mode=args.prefill_mode,
                                      prefill_chunk=args.prefill_chunk,
                                      paged=args.paged,
                                      block_size=args.block_size,
@@ -872,7 +814,6 @@ def main():
     else:
         fitted, engine = build_engine(num_slots=args.slots,
                                       max_len=args.max_len,
-                                      prefill_mode=args.prefill_mode,
                                       prefill_chunk=args.prefill_chunk,
                                       spec_draft=args.spec_draft,
                                       spec_len=args.spec_len,
@@ -954,7 +895,7 @@ def main():
                 "kv_pool_bytes": closed["kv_pool_bytes"]}))
         if args.ttft:
             print(json.dumps({
-                "mode": "ttft", "prefill_mode": args.prefill_mode,
+                "mode": "ttft",
                 "p50_ms": closed["ttft_p50_ms"],
                 "p99_ms": closed["ttft_p99_ms"],
                 "prefill_tokens_per_sec":
@@ -974,7 +915,6 @@ def main():
                                         affinity=args.affinity,
                                         num_slots=args.slots,
                                         max_len=args.max_len,
-                                        prefill_mode=args.prefill_mode,
                                         prefill_chunk=args.prefill_chunk,
                                         paged=args.paged,
                                         block_size=args.block_size,
@@ -985,7 +925,6 @@ def main():
                 continue
             _, engine = build_engine(num_slots=args.slots,
                                      max_len=args.max_len,
-                                     prefill_mode=args.prefill_mode,
                                      prefill_chunk=args.prefill_chunk,
                                      spec_draft=args.spec_draft,
                                      spec_len=args.spec_len,

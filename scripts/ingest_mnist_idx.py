@@ -1,7 +1,7 @@
 """Convert raw MNIST/Fashion-MNIST IDX files to the npz layout the data
 loaders consume — so a populated ``DISTKERAS_TPU_DATA`` upgrades every
 real-data hook (``data/datasets.py :: load_mnist``, the accuracy-parity
-gate, ``bench.py``'s ``data: "real"`` field) with ZERO code changes.
+gate) with ZERO code changes.
 
 This sandbox has no egress, so the script only documents + performs the
 local half: download the four files elsewhere (classic Yann LeCun MNIST

@@ -73,7 +73,6 @@ def _engine(fitted, **kw):
 
 
 def _paged_engine(fitted, **kw):
-    kw.setdefault("prefill_mode", "bucketed")
     kw.setdefault("paged", True)
     kw.setdefault("block_size", 4)
     kw.setdefault("kv_blocks", 64)

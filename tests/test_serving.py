@@ -156,7 +156,7 @@ def test_retired_slot_state_is_cleared(fitted):
     eng.run_until_idle()
     assert h.done and eng._handles[0] is None
     assert not eng._active.any()
-    assert eng._temp[0] == 0.0 and eng._topk[0] == 0 and eng._topp[0] == 0.0
+    assert not bool(eng._dev_act[0])
     assert eng._free == [0]
     # a greedy follow-up through the same slot is unpolluted by the
     # previous occupant's sampling params

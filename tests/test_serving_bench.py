@@ -1,4 +1,4 @@
-"""Load-generator + serving-bench surface (examples/loadgen.py, bench.py).
+"""Load-generator surface (examples/loadgen.py).
 
 The fast variants here are tier-1: a small fixed trace through the closed
 loop must complete losslessly with sane metrics, and the trace itself must
@@ -82,39 +82,6 @@ def test_closed_loop_outputs_match_offline_generate():
             rng=jax.random.PRNGKey(req["seed"]) if temp else None,
             max_len=engine.max_len))[0]
         np.testing.assert_array_equal(h.result(), want)
-
-
-def test_bench_serving_fields_shape():
-    """bench.serving_bench returns exactly the serving_* field set (None
-    allowed — the artifact contract) without touching the north star."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    out = bench.serving_bench(budget_s=0.0)  # force the overrun path
-    assert set(out) == {"serving_tokens_per_sec", "serving_p50_ms",
-                        "serving_p99_ms", "serving_slot_occupancy",
-                        "serving_sequential_tokens_per_sec",
-                        "serving_shed_rate", "serving_slot_reclaim_ms",
-                        "serving_deadline_miss_rate",
-                        "serving_ttft_p50_ms", "serving_ttft_p99_ms",
-                        "serving_prefill_tokens_per_sec",
-                        "serving_longprompt_ttft_p99_ms",
-                        "serving_longprompt_ttft_eager_p99_ms",
-                        "serving_spec_tokens_per_sec",
-                        "serving_spec_accept_rate",
-                        "serving_quant_capacity_slots",
-                        "serving_prefix_ttft_p99_ms",
-                        "serving_prefix_ttft_dense_p99_ms",
-                        "serving_prefix_hit_rate",
-                        "serving_prefix_prefill_tokens_per_sec",
-                        "serving_prefix_prefill_dense_tokens_per_sec",
-                        "serving_paged_capacity_slots",
-                        "serving_unified_decode_p99_ms",
-                        "serving_disagg_decode_p99_ms",
-                        "serving_kv_transfer_bytes",
-                        "serving_interactive_p99_ms_under_overload",
-                        "serving_batch_completion_rate",
-                        "serving_preempt_resume_ms"}
 
 
 def test_closed_loop_chaos_kill_schedule_no_leaks():
@@ -235,40 +202,9 @@ def test_paged_loadgen_shared_prefix_fast_leg():
 
 
 # ---------------------------------------------------------------------------
-# fleet routing (PR 17): the fast legs are tier-1 (seeded trace, bounded
-# waits); the scaling timing comparison is slow
-# ---------------------------------------------------------------------------
-
-@pytest.mark.router
-def test_fleet_bench_fields_shape():
-    """bench.serving_fleet_bench returns exactly the serving_fleet_*
-    field set (None allowed — the artifact contract)."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    out = bench.serving_fleet_bench(budget_s=0.0)  # force the overrun path
-    assert set(out) == {"serving_fleet_tokens_per_sec",
-                        "serving_fleet_prefix_hit_rate",
-                        "serving_fleet_failover_lost_requests"}
-    assert all(v is None for v in out.values())
-
-
-# ---------------------------------------------------------------------------
 # wire transport scaling (PR 19): the fast legs are tier-1 (small trace over
 # loopback, bounded waits); the 64-client scaling comparison is slow
 # ---------------------------------------------------------------------------
-
-def test_wire_bench_fields_shape():
-    """bench.serving_wire_bench returns exactly the transport-scaling
-    field set (None allowed — the artifact contract)."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    out = bench.serving_wire_bench(budget_s=0.0)  # force the overrun path
-    assert set(out) == {"serving_event_tokens_per_sec",
-                        "serving_connection_scaling"}
-    assert all(v is None for v in out.values())
-
 
 def test_wire_closed_loop_lossless_both_cores():
     """Tier-1 deterministic wire leg: a small trace through a
@@ -330,6 +266,10 @@ def test_wire_event_core_holds_throughput_at_64_clients():
     assert tps["event"] >= tps["threaded"] * 0.9, tps
 
 
+# ---------------------------------------------------------------------------
+# fleet routing (PR 17): seeded trace, bounded waits
+# ---------------------------------------------------------------------------
+
 @pytest.mark.router
 def test_closed_loop_router_fleet_lossless():
     """Tier-1 deterministic fleet leg: the closed loop drives a 2-replica
@@ -352,24 +292,6 @@ def test_closed_loop_router_fleet_lossless():
     assert sum(p["routed"] for p in report["per_replica"]) == 6
     assert report["requests_failed"] == 0
     assert report["routed_skew"] is not None and report["routed_skew"] >= 1
-
-
-@pytest.mark.router
-@pytest.mark.slow
-def test_fleet_bench_scaling_and_failover():
-    """The full bench leg: the scaling curve records every fleet size,
-    affinity routing beats the random control arm on the tenanted trace,
-    and the failover count is ZERO — the acceptance bar."""
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    out = bench.serving_fleet_bench(budget_s=300.0)
-    scaling = out["serving_fleet_tokens_per_sec"]
-    assert scaling and scaling["1"] > 0
-    hit = out["serving_fleet_prefix_hit_rate"]
-    assert hit["prefix"] is not None and hit["random"] is not None
-    assert hit["prefix"] > hit["random"], hit
-    assert out["serving_fleet_failover_lost_requests"] == 0
 
 
 @pytest.mark.paged

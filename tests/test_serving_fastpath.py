@@ -1,17 +1,14 @@
-"""Serving fast path (distkeras_tpu/serving.py, ``prefill_mode="bucketed"``).
+"""The serving engine's compute path (distkeras_tpu/serving.py).
 
-PR 9 rebuilt the engine's compute path in three layers: compiled bucketed
-batch prefill, chunked long-prompt prefill interleaved with decode, and
-device-resident decode state with one-step lookahead.  The contract
-pinned here:
+Three layers: compiled bucketed batch prefill, chunked long-prompt prefill
+interleaved with decode, and device-resident decode state with one-step
+lookahead.  The contract pinned here:
 
- - bucketed AND chunked prefill emit tokens BIT-IDENTICAL to the eager
-   reference (``prefill_mode="eager"``) and to offline ``generate``,
-   across greedy + sampled × rolling + full-cache × mixed prompt lengths
-   sharing one bucketed batch — the fast path is an execution strategy,
-   never a numerics change;
- - the bucketed hot path never calls the eager ``_forward`` (compiled by
-   construction, the acceptance criterion);
+ - bucketed AND chunked prefill emit tokens BIT-IDENTICAL to offline
+   ``generate`` (per request), across greedy + sampled × rolling +
+   full-cache × mixed prompt lengths sharing one bucketed batch — the
+   engine is an execution strategy, never a numerics change;
+ - the engine runs no forward outside a jitted program;
  - a decode-only iteration performs ZERO host→device uploads and exactly
    ONE device→host readback (the sampled token row) — asserted with a
    transfer-counting double wrapped around the jitted step;
@@ -24,15 +21,12 @@ pinned here:
    (a reap-only iteration parked on a reload multiple must not re-pull).
 """
 
-import time
-
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-import distkeras_tpu.serving as serving
 from distkeras_tpu.core import decode
 from distkeras_tpu.core.model import FittedModel
 from distkeras_tpu.models import transformer_lm
@@ -89,7 +83,7 @@ def _want(fitted, h, **kw):
 
 
 # ---------------------------------------------------------------------------
-# bit-identity: bucketed / chunked / rolling vs eager reference + generate
+# bit-identity: bucketed / chunked / rolling vs offline generate
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("kw", [
@@ -97,17 +91,11 @@ def _want(fitted, h, **kw):
     {"temperature": 0.7, "seed": 11},                         # plain sample
     {"temperature": 0.7, "top_k": 5, "top_p": 0.9, "seed": 11},
 ])
-def test_bucketed_lone_request_matches_eager_and_generate(fitted, kw):
-    rows = {}
-    for mode in ("bucketed", "eager"):
-        eng = ServingEngine(fitted, num_slots=3, max_len=24,
-                            prefill_mode=mode)
-        h = eng.submit(PROMPT, 8, **kw)
-        eng.run_until_idle()
-        rows[mode] = h.result()
-    want = _want(fitted, h, max_len=24)
-    np.testing.assert_array_equal(rows["bucketed"], want)
-    np.testing.assert_array_equal(rows["eager"], want)
+def test_bucketed_lone_request_matches_generate(fitted, kw):
+    eng = ServingEngine(fitted, num_slots=3, max_len=24)
+    h = eng.submit(PROMPT, 8, **kw)
+    eng.run_until_idle()
+    np.testing.assert_array_equal(h.result(), _want(fitted, h, max_len=24))
 
 
 def test_mixed_prompt_lengths_share_one_bucketed_batch(fitted):
@@ -195,28 +183,31 @@ def test_eos_retirement_on_fast_path(fitted):
 
 
 # ---------------------------------------------------------------------------
-# hot-path discipline: no eager forward, one transfer each way
+# hot-path discipline: no forward outside jit, one transfer each way
 # ---------------------------------------------------------------------------
 
 def test_no_eager_forward_in_bucketed_hot_path(fitted, monkeypatch):
-    """Acceptance criterion: with prefill_mode="bucketed" (the default)
-    the engine never calls the module-level eager ``_forward`` — only the
-    eager reference mode does."""
-    def bomb(*a, **k):
-        raise AssertionError("eager _forward reached the bucketed hot "
-                             "path")
+    """The engine runs no forward outside a jitted program: every call of
+    ``core.decode._forward`` it makes, on the bucket, the chunked and the
+    decode path, sees TRACED tokens."""
+    real, calls = decode._forward, []
 
-    monkeypatch.setattr(serving, "_forward", bomb)
+    def traced_only(model, params, caches, tokens, *a, **k):
+        assert isinstance(tokens, jax.core.Tracer), \
+            "_forward ran op by op on the engine's hot path"
+        calls.append(1)
+        return real(model, params, caches, tokens, *a, **k)
+
+    monkeypatch.setattr(decode, "_forward", traced_only)
     eng = ServingEngine(fitted, num_slots=2, max_len=24, prefill_chunk=4)
     h = eng.submit(PROMPT, 4)
     hl = eng.submit((np.arange(1, 12, dtype=np.int32) * 7) % VOCAB, 4)
-    eng.run_until_idle()  # both the batch and the chunked path: no bomb
-    assert h.done and hl.done
-    eager = ServingEngine(fitted, num_slots=1, max_len=24,
-                          prefill_mode="eager")
-    eager.submit(PROMPT, 2)
-    with pytest.raises(AssertionError, match="hot path"):
-        eager.run_until_idle()
+    eng.run_until_idle()  # the batch, the chunked and the decode programs
+    assert h.done and hl.done and calls
+    # the wrapper does tell the two apart: offline generate prefills op
+    # by op and trips it
+    with pytest.raises(AssertionError, match="op by op"):
+        fitted.generate(PROMPT[None], 2, max_len=24)
 
 
 def test_decode_iteration_transfer_discipline(fitted):
@@ -333,18 +324,13 @@ def test_pow2_bucket_ladder():
 
 
 def test_prefill_knob_validation(fitted):
-    with pytest.raises(ValueError, match="prefill_mode"):
-        ServingEngine(fitted, num_slots=1, max_len=24,
-                      prefill_mode="turbo")
     with pytest.raises(ValueError, match="prefill_chunk"):
         ServingEngine(fitted, num_slots=1, max_len=24, prefill_chunk=0)
 
 
 def test_respawn_clone_carries_prefill_knobs(fitted):
-    eng = ServingEngine(fitted, num_slots=2, max_len=24,
-                        prefill_mode="eager", prefill_chunk=16)
+    eng = ServingEngine(fitted, num_slots=2, max_len=24, prefill_chunk=16)
     clone = eng.respawn_clone()
-    assert clone.prefill_mode == "eager"
     assert clone.prefill_chunk == 16
 
 
@@ -379,27 +365,24 @@ def draft():
 
 @pytest.mark.parametrize("draft_kind", ["self", "random"])
 @pytest.mark.parametrize("spec_len", [1, 3])
-def test_spec_greedy_token_identity_vs_eager(fitted, draft, draft_kind,
-                                             spec_len):
+def test_spec_greedy_token_identity_vs_generate(fitted, draft, draft_kind,
+                                                spec_len):
     """The tentpole contract: greedy speculation is TOKEN-IDENTICAL to
     the non-speculative engine whatever the draft proposes — a self-draft
     (high accept: rows ride the fast lane) and an independent random
     draft (near-floor accept: every round falls back to the correction
-    token) both reproduce the eager reference bit for bit, with MIXED
-    prompt lengths (so mixed accept lengths) sharing one batch."""
+    token) both reproduce offline greedy ``generate`` bit for bit, with
+    MIXED prompt lengths (so mixed accept lengths) sharing one batch."""
     d = fitted if draft_kind == "self" else draft
     subs = [(np.arange(1, 1 + p, dtype=np.int32) % VOCAB, 5 + p % 3)
             for p in (2, 4, 7)]
-    eager = ServingEngine(fitted, num_slots=3, max_len=24,
-                          prefill_mode="eager", prefills_per_step=3)
-    want = [eager.submit(pr, n) for pr, n in subs]
-    eager.run_until_idle()
     eng = ServingEngine(fitted, num_slots=3, max_len=24, spec_draft=d,
                         spec_len=spec_len, prefills_per_step=3)
     got = [eng.submit(pr, n) for pr, n in subs]
     eng.run_until_idle()
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(g.result(), w.result())
+    for g in got:
+        np.testing.assert_array_equal(g.result(),
+                                      _want(fitted, g, max_len=24))
     assert eng.stats["verify_calls"] >= 1
     assert eng.stats["drafted"] >= spec_len
     assert 0 <= eng.stats["accepted"] <= eng.stats["drafted"]
@@ -408,13 +391,9 @@ def test_spec_greedy_token_identity_vs_eager(fitted, draft, draft_kind,
 def test_spec_rolling_token_identity(windowed):
     """Rolling pools under speculation: the ring carries spec_len slack
     slots so the L-token verify never overwrites the oldest query's
-    window — greedy output still matches the eager rolling reference."""
+    window — greedy output still matches rolling ``generate``."""
     subs = [(np.arange(1, 8, dtype=np.int32) % VOCAB, 10),
             (np.array([1, 2], np.int32), 6)]
-    eager = ServingEngine(windowed, num_slots=2, max_len=24, rolling=True,
-                          prefill_mode="eager", prefills_per_step=2)
-    want = [eager.submit(pr, n) for pr, n in subs]
-    eager.run_until_idle()
     eng = ServingEngine(windowed, num_slots=2, max_len=24, rolling=True,
                         spec_draft=windowed, spec_len=3,
                         prefills_per_step=2)
@@ -422,14 +401,15 @@ def test_spec_rolling_token_identity(windowed):
     assert eng.caches[2]["k"].shape[1] == 6 + 3
     got = [eng.submit(pr, n) for pr, n in subs]
     eng.run_until_idle()
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(g.result(), w.result())
+    for g in got:
+        np.testing.assert_array_equal(
+            g.result(), _want(windowed, g, max_len=24, rolling=True))
 
 
 def test_spec_sampled_deterministic_and_greedy_rows_exact(fitted):
     """A mixed greedy + sampled batch under speculation: sampled rows are
     deterministic per seed (run twice, identical) and the GREEDY rows in
-    the same batch stay bit-identical to the eager reference — per-row
+    the same batch stay bit-identical to offline ``generate`` — per-row
     independence of the accept/commit machinery."""
     subs = [((PROMPT, 8), {}),
             ((np.array([1, 2], np.int32), 6),
@@ -442,18 +422,14 @@ def test_spec_sampled_deterministic_and_greedy_rows_exact(fitted):
                             prefills_per_step=3)
         hs = [eng.submit(*a, **k) for a, k in subs]
         eng.run_until_idle()
-        return [h.result() for h in hs]
+        return hs
 
-    rows1, rows2 = run(), run()
-    for a, b in zip(rows1, rows2):
-        np.testing.assert_array_equal(a, b)
-    eager = ServingEngine(fitted, num_slots=2, max_len=24,
-                          prefill_mode="eager", prefills_per_step=2)
-    w0 = eager.submit(*subs[0][0])
-    w2 = eager.submit(*subs[2][0])
-    eager.run_until_idle()
-    np.testing.assert_array_equal(rows1[0], w0.result())
-    np.testing.assert_array_equal(rows1[2], w2.result())
+    hs1, hs2 = run(), run()
+    for a, b in zip(hs1, hs2):
+        np.testing.assert_array_equal(a.result(), b.result())
+    for h in (hs1[0], hs1[2]):
+        np.testing.assert_array_equal(h.result(),
+                                      _want(fitted, h, max_len=24))
 
 
 def test_spec_chunked_prefill_and_eos(fitted):
@@ -529,12 +505,6 @@ def test_spec_and_quant_validation(fitted, draft):
     with pytest.raises(ValueError, match="spec_len"):
         ServingEngine(fitted, num_slots=1, max_len=24, spec_draft=fitted,
                       spec_len=0)
-    with pytest.raises(ValueError, match="bit-exactness reference"):
-        ServingEngine(fitted, num_slots=1, max_len=24,
-                      prefill_mode="eager", spec_draft=fitted)
-    with pytest.raises(ValueError, match="bit-exactness reference"):
-        ServingEngine(fitted, num_slots=1, max_len=24,
-                      prefill_mode="eager", kv_dtype="int8")
     with pytest.raises(ValueError, match="quantize"):
         ServingEngine(fitted, num_slots=1, max_len=24, quantize="fp4")
     with pytest.raises(ValueError, match="kv_dtype"):
@@ -616,40 +586,6 @@ def test_defaults_unchanged_no_spec_counters_move(fitted):
     eng.run_until_idle()
     np.testing.assert_array_equal(h.result(), _want(fitted, h, max_len=24))
     assert eng.stats["drafted"] == 0 and eng.stats["verify_calls"] == 0
-
-
-# ---------------------------------------------------------------------------
-# perf smoke (slow): compiled batched prefill beats sequential eager
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_batched_prefill_beats_sequential_eager_prefill(fitted):
-    """≥ 4 queued prompts: one warmed bucketed engine (batched compiled
-    prefill) finishes the admission burst faster than the eager engine's
-    per-request uncompiled prefills — the wall-clock half of the fast-path
-    acceptance (the counter half is tier-1 above)."""
-    prompts = [((np.arange(8) * (i + 2)) % VOCAB).astype(np.int32)
-               for i in range(8)]
-
-    def run(mode):
-        eng = ServingEngine(fitted, num_slots=8, max_len=24,
-                            prefills_per_step=8, prefill_mode=mode)
-        if mode == "bucketed":
-            eng.warmup()
-        # throwaway round so BOTH modes have their decode/prefill
-        # programs compiled before the timed burst
-        eng.submit(prompts[0], 1)
-        eng.run_until_idle()
-        t0 = time.perf_counter()
-        hs = [eng.submit(p, 1) for p in prompts]
-        eng.run_until_idle()
-        dt = time.perf_counter() - t0
-        assert all(h.done for h in hs)
-        return dt
-
-    eager = run("eager")
-    fast = run("bucketed")
-    assert fast < eager, (fast, eager)
 
 
 # ---------------------------------------------------------------------------
@@ -906,9 +842,6 @@ def test_paged_respawn_clone_fresh_trie_same_arena(fitted):
 
 @pytest.mark.paged
 def test_paged_knob_validation(fitted):
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(fitted, num_slots=1, max_len=24, paged=True,
-                      prefill_mode="eager")
     with pytest.raises(ValueError, match="block_size"):
         ServingEngine(fitted, num_slots=1, max_len=24, paged=True,
                       block_size=0)

@@ -14,6 +14,7 @@ import contextlib
 import glob
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -63,13 +64,28 @@ def engine():
     return eng
 
 
+def wait_until(cond, what, limit_s=5.0):
+    end = time.monotonic() + limit_s
+    while not cond():
+        assert time.monotonic() < end, f"{what}: not within {limit_s} s"
+        time.sleep(0.002)
+
+
 def serve(eng):
-    """The three prompts through the engine's own thread; tokens served."""
+    """The three prompts through the engine's own thread; tokens served.
+    The loop is seen to go idle before ``stop()``: with nothing queued or
+    running at most one more ``step()`` has work (the lookahead's flush),
+    ``step()`` counts its idle calls too, and between two idle calls of
+    ``_loop`` lies one ``serve.idle_wait`` (its wait times out in 0.05 s)."""
     eng.start()
     try:
         handles = [eng.submit(p, n, tenant="vip" if i == 2 else None)
                    for i, (p, n) in enumerate(PROMPTS)]
         assert all(h.wait(120) for h in handles)
+        wait_until(lambda: not eng.queue_depth and not eng.active_requests,
+                   "engine drained")
+        seen = eng._iterations
+        wait_until(lambda: eng._iterations >= seen + 2, "two more iterations")
     finally:
         eng.stop()
     return [list(h.tokens) for h in handles]
@@ -312,7 +328,10 @@ def test_scopes_leave_the_arithmetic_as_it_was(program, monkeypatch):
     assert lower().as_text() == scoped
 
 
-def test_the_kernels_carry_their_names():
+@pytest.fixture(scope="module")
+def kernels_lowered():
+    """Flash attention and the fused CE, forward and backward, lowered for
+    a TPU once."""
     from distkeras_tpu.ops.flash_attention import flash_attention
     from distkeras_tpu.ops.fused_ce import fused_softmax_cross_entropy
 
@@ -324,11 +343,23 @@ def test_the_kernels_carry_their_names():
     q = jax.ShapeDtypeStruct((2, 128, 2, 64), jnp.bfloat16)
     logits = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     labels = jax.ShapeDtypeStruct((256,), jnp.int32)
-    text = jax.jit(jax.grad(both, argnums=(0, 1))).trace(
+    return jax.jit(jax.grad(both, argnums=(0, 1))).trace(
         q, logits, labels).lower(lowering_platforms=("tpu",)).as_text()
-    for name in ("flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd",
-                 "fused_ce_bwd"):
-        assert f'kernel_name = "{name}"' in text
+
+
+# paged_decode has the test below: its program is the engine's decode step
+@pytest.mark.parametrize("name", [k for k in metrics.KERNEL_NAMES
+                                  if k != "paged_decode"])
+def test_the_kernels_carry_their_names(kernels_lowered, name):
+    assert f'kernel_name = "{name}"' in kernels_lowered
+
+
+def test_chip_smoke_requires_kernels_the_package_names():
+    """``chip_smoke.py`` runs on the chip alone; tier 1 sees its names."""
+    import chip_smoke  # conftest.py puts the checkout's root on sys.path
+    wanted = {k for names in chip_smoke.REQUIRED_KERNELS.values()
+              for k in names}
+    assert wanted and wanted <= set(metrics.KERNEL_NAMES)
 
 
 def test_the_paged_decode_step_names_its_kernel_under_attn_core(monkeypatch):
@@ -352,6 +383,7 @@ def test_the_paged_decode_step_names_its_kernel_under_attn_core(monkeypatch):
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     # the layers share ONE lowering of the kernel (the wrapper is jitted);
     # each calls it from under its own attn_core
+    assert "paged_decode" in metrics.KERNEL_NAMES
     assert text.count('kernel_name = "paged_decode"') == 1
     for block in ("block_0", "block_1"):
         assert f"{block}/attn/attn_core/jit(paged_decode_attention)" in text
