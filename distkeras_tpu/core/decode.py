@@ -766,23 +766,39 @@ def filter_logits_batched(logits, top_k, top_p):
     what ``_filter_logits(row, K, P)`` computes (the k-th value comes from
     a descending sort instead of ``lax.top_k`` — the same exact selection —
     and the k-then-p composition order is preserved), so one jitted program
-    serves a slot batch with heterogeneous sampling configs."""
+    serves a slot batch with heterogeneous sampling configs.
+
+    The work follows the rows: a call none of whose rows filters returns
+    its logits as they came (what every row's two ``where``s would leave),
+    decided on the device by a ``lax.cond`` — no sort runs.  A call that
+    does filter sorts ONCE: the nucleus is taken over the k-filtered
+    logits in descending order, and that array is the first sort's result
+    with the entries below the k-th value sent to ``-inf`` (they are the
+    tail already, the order of the rest is untouched, ties at the k-th
+    value stay on both sides)."""
     v = logits.shape[-1]
     top_k = jnp.asarray(top_k, jnp.int32)
     top_p = jnp.asarray(top_p, jnp.float32)
-    sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
-    k = jnp.clip(top_k, 1, v)
-    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
-    logits = jnp.where((top_k > 0)[:, None] & (logits < kth),
-                       -jnp.inf, logits)
-    # p filter runs on the k-filtered logits (k-then-p, as _filter_logits)
-    sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
-    probs = jax.nn.softmax(sorted_desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    kept = jnp.sum((cum - probs) < top_p[:, None], axis=-1, keepdims=True)
-    cut = jnp.take_along_axis(sorted_desc, jnp.maximum(kept, 1) - 1, axis=-1)
-    return jnp.where((top_p > 0)[:, None] & (logits < cut),
-                     -jnp.inf, logits)
+    k_on, p_on = (top_k > 0)[:, None], (top_p > 0)[:, None]
+
+    def filtered():
+        sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
+        k = jnp.clip(top_k, 1, v)
+        kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+        k_logits = jnp.where(k_on & (logits < kth), -jnp.inf, logits)
+        # p filter runs on the k-filtered logits (k-then-p, as
+        # _filter_logits): their descending sort, derived
+        sorted_desc = jnp.where(k_on & (sorted_desc < kth), -jnp.inf,
+                                sorted_desc)
+        probs = jax.nn.softmax(sorted_desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        kept = jnp.sum((cum - probs) < top_p[:, None], axis=-1,
+                       keepdims=True)
+        cut = jnp.take_along_axis(sorted_desc, jnp.maximum(kept, 1) - 1,
+                                  axis=-1)
+        return jnp.where(p_on & (k_logits < cut), -jnp.inf, k_logits)
+
+    return jax.lax.cond(jnp.any(k_on | p_on), filtered, lambda: logits)
 
 
 @jax.named_scope("sample")
@@ -798,14 +814,28 @@ def sample_logits_batched(logits, positions, temperature, rngs,
     ``sample_logits`` on that row's scalar params — vmapped ``fold_in`` +
     ``categorical`` draw the same counter-based random bits as the
     unbatched calls, which is what makes the serving engine's output
-    bit-identical to offline ``generate``."""
+    bit-identical to offline ``generate``.
+
+    The call does what its rows ask for and no more, in ONE program (the
+    parameters are traced, so the choice is a ``lax.cond`` on the device):
+    no row samples → the ``argmax`` alone; some row samples → the divide
+    and the draw, and the filter's sort only if a SAMPLING row filters (a
+    greedy row's ``top_k``/``top_p`` shape nothing).  A caller whose batch
+    has rows it will discard hands them ``temperature`` 0."""
     temp = jnp.asarray(temperature, jnp.float32)
-    safe = jnp.where(temp > 0.0, temp, 1.0)
-    warped = filter_logits_batched(logits / safe[:, None], top_k, top_p)
-    keys = jax.vmap(jax.random.fold_in)(rngs, positions)
-    sampled = jax.vmap(jax.random.categorical)(keys, warped)
-    greedy = jnp.argmax(logits, axis=-1)
-    return jnp.where(temp > 0.0, sampled, greedy).astype(jnp.int32)
+    samples = temp > 0.0
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def draw():
+        safe = jnp.where(samples, temp, 1.0)
+        warped = filter_logits_batched(
+            logits / safe[:, None], jnp.where(samples, top_k, 0),
+            jnp.where(samples, top_p, 0.0))
+        keys = jax.vmap(jax.random.fold_in)(rngs, positions)
+        sampled = jax.vmap(jax.random.categorical)(keys, warped)
+        return jnp.where(samples, sampled.astype(jnp.int32), greedy)
+
+    return jax.lax.cond(jnp.any(samples), draw, lambda: greedy)
 
 
 def _to_ring(full_cache, p_len: int, window: int):

@@ -42,7 +42,14 @@ Serving (``serving.py``; the engine's thread unless said):
                       and V in place through ``paged_decode``; ``gather``:
                       it gathers each slot's view — settled when the
                       program is built; ``stats["paged_kernel_steps"]``
-                      counts the ``kernel`` dispatches)
+                      counts the ``kernel`` dispatches), ``sample`` (what
+                      the step's live rows ask of the sampler:
+                      ``greedy`` = none samples, the argmax alone;
+                      ``draw`` = some sample, none filters; ``filter`` = a
+                      sampling row has ``top_k``/``top_p``, the sort runs;
+                      ``stats["sampler_draw_steps"]`` counts ``draw`` and
+                      ``filter``, ``stats["sampler_filter_steps"]``
+                      ``filter``)
 ``serve.fetch``       the device→host read of one in-flight step — the time
                       the host WAITS for the device: ``step`` of the entry
                       drained (joins it to its ``serve.decode_dispatch``;

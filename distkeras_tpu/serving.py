@@ -494,6 +494,22 @@ def _pow2_buckets(cap: int) -> List[int]:
     return out
 
 
+def _sampler_work(entries) -> str:
+    """What the live rows of a decode step ask of the sampler, from the
+    handles the host holds: ``"greedy"`` (none samples: the argmax alone),
+    ``"draw"`` (some sample, none of them filters) or ``"filter"`` (a
+    sampling row carries ``top_k``/``top_p``: the sort runs).  The device
+    decides the same from the per-slot arrays
+    (``decode.sample_logits_batched``); this is the host's account of it."""
+    work = "greedy"
+    for _, h in entries:
+        if h.temperature > 0.0:
+            if h.top_k is not None or h.top_p is not None:
+                return "filter"
+            work = "draw"
+    return work
+
+
 class _PrefillJob:
     """Scheduler-side state of one chunked prefill in flight: the slot is
     claimed (``engine._handles``) but not yet decoding; ``written`` prompt
@@ -1192,6 +1208,11 @@ class ServingEngine:
             # decode steps whose program reads K and V in place through
             # the paged decode kernel (every step or none of an engine's)
             "paged_kernel_steps": 0,
+            # decode steps whose live rows opened the sampler's gates
+            # (_sampler_work): some row samples (the divide and the draw
+            # ran) / some sampling row filters (the sort ran too); a step
+            # in neither took the argmax alone
+            "sampler_draw_steps": 0, "sampler_filter_steps": 0,
             "queue_peak": 0, "slot_requests": [0] * self.num_slots,
             "weight_reloads": 0,
             # hot-reload hardening observables (docs/serving.md): reloads
@@ -1366,8 +1387,13 @@ class ServingEngine:
                 pv = _dec.PagedView(bt, page, view, ring=rolling)
                 logits, caches = _dec.decode_step(model, params, caches,
                                                   tok, positions, paged=pv)
-                nxt = _dec.sample_logits_batched(logits, positions, temp,
-                                                 keys, topk, topp)
+                # a retired slot keeps its temp/topk/topp (_build_deact_fn
+                # clears act alone) and its row's token is discarded below:
+                # it reaches the sampler as a greedy row, so a stale
+                # sampling slot costs a greedy batch no draw and no sort
+                nxt = _dec.sample_logits_batched(
+                    logits, positions, jnp.where(active, temp, 0.0), keys,
+                    topk, topp)
                 out = jnp.where(active, nxt, tok)
                 positions = jnp.where(active, positions + 1, positions)
                 return out, caches, positions
@@ -1378,8 +1404,9 @@ class ServingEngine:
                  keys):
             logits, caches = _dec.decode_step(model, params, caches, tok,
                                               positions, rolling)
-            nxt = _dec.sample_logits_batched(logits, positions, temp, keys,
-                                             topk, topp)
+            nxt = _dec.sample_logits_batched(
+                logits, positions, jnp.where(active, temp, 0.0), keys, topk,
+                topp)
             out = jnp.where(active, nxt, tok)
             positions = jnp.where(active, positions + 1, positions)
             return out, caches, positions
@@ -1507,6 +1534,16 @@ class ServingEngine:
                     if bt is not None else None)
             pv_d = (_dec.PagedView(dbt, page, d_view)
                     if dbt is not None else None)
+
+            # filtered logits reach a committed token only through rows
+            # that sample and are live (every other row commits the argmax
+            # chain, or nothing): the rest filter nothing, so a round whose
+            # live rows are greedy or unfiltered runs no sort
+            # (filter_logits_batched's own gate; a retired slot keeps its
+            # topk/topp).  The draws themselves stay as they are
+            filt = sampled & act
+            topk = jnp.where(filt, topk, 0)
+            topp = jnp.where(filt, topp, 0.0)
 
             def warp(l):
                 return _dec.filter_logits_batched(l / safe_t[:, None],
@@ -3391,8 +3428,11 @@ class ServingEngine:
         self.stats["decode_steps"] += 1
         step = self.stats["decode_steps"]
         self.stats["paged_kernel_steps"] += self._decode_attn == "kernel"
+        sample = _sampler_work(entries)
+        self.stats["sampler_draw_steps"] += sample != "greedy"
+        self.stats["sampler_filter_steps"] += sample == "filter"
         with span("serve.decode_dispatch", active=len(entries), step=step,
-                  attn=self._decode_attn):
+                  attn=self._decode_attn, sample=sample):
             if self._draft_model is not None:
                 # speculative round: k draft steps + one batched verify in
                 # ONE program; rows commit 1..spec_len+1 tokens each, packed

@@ -16,6 +16,8 @@ The invariants pinned here are the engine's whole contract:
    row for row.
 """
 
+import contextlib
+import re
 import threading
 
 import numpy as np
@@ -415,6 +417,226 @@ def test_batched_sampler_matches_scalar_rows():
             int(topk[r]) or None,
             float(topp[r]) or None)
         assert got[r] == int(np.asarray(want)[0]), f"row {r}"
+
+
+def _two_sort_filter(logits, top_k, top_p):
+    """``filter_logits_batched`` as it stood before it sorted once: the
+    k-th value from one full sort, the nucleus from a second one over the
+    k-filtered logits, whatever the rows ask for.  The plain reference."""
+    v = logits.shape[-1]
+    top_k = jnp.asarray(top_k, jnp.int32)
+    top_p = jnp.asarray(top_p, jnp.float32)
+    sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
+    k = jnp.clip(top_k, 1, v)
+    kth = jnp.take_along_axis(sorted_desc, (k - 1)[:, None], axis=-1)
+    logits = jnp.where((top_k > 0)[:, None] & (logits < kth),
+                       -jnp.inf, logits)
+    sorted_desc = jnp.sort(logits, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    kept = jnp.sum((cum - probs) < top_p[:, None], axis=-1, keepdims=True)
+    cut = jnp.take_along_axis(sorted_desc, jnp.maximum(kept, 1) - 1, axis=-1)
+    return jnp.where((top_p > 0)[:, None] & (logits < cut),
+                     -jnp.inf, logits)
+
+
+# (temperature, top_k, top_p) a row; 0 = off, as the engine's slot arrays
+_GREEDY, _PLAIN = (0.0, 0, 0.0), (0.8, 0, 0.0)
+_K, _P, _KP = (0.7, 4, 0.0), (1.3, 0, 0.6), (0.5, 3, 0.9)
+SAMPLER_CASES = {
+    "all_greedy": [_GREEDY] * 4,
+    "all_sampling_no_filter": [_PLAIN, (0.5, 0, 0.0), (1.3, 0, 0.0), _PLAIN],
+    "top_k_only": [_K, (0.7, 1, 0.0), (1.0, 9, 0.0), _K],
+    "top_p_only": [_P, (0.7, 0, 0.1), (1.0, 0, 1.0), _P],
+    "top_k_and_top_p": [_KP, (0.7, 5, 0.5), (1.0, 2, 1.0), _KP],
+    "mixed_batch": [_GREEDY, _PLAIN, _K, _P, _KP, _GREEDY],
+    # logits in half steps: exact ties at the k-th value and at the
+    # nucleus' cut; top_k at and past the vocabulary keeps every token
+    "ties_and_k_past_vocab": [(1.0, 3, 0.0), (1.0, 5, 0.7), (1.0, VOCAB, 0.9),
+                              (1.0, 4 * VOCAB, 0.0), (1.0, 4 * VOCAB, 0.5),
+                              (1.0, 6, 1.0)],
+}
+
+
+@pytest.mark.parametrize("case", list(SAMPLER_CASES))
+def test_batched_sampler_does_what_its_rows_ask(case):
+    """Whatever mix of rows a call carries (so whichever branch of the
+    sampler's two ``lax.cond``s it takes), ``filter_logits_batched`` equals
+    the two-sort formula bit for bit and ``sample_logits_batched`` equals
+    the scalar ``sample_logits`` row for row."""
+    rows = SAMPLER_CASES[case]
+    b = len(rows)
+    rng = np.random.default_rng(7)
+    raw = rng.standard_normal((b, VOCAB)) * 2
+    if case == "ties_and_k_past_vocab":
+        raw = np.round(raw * 2) / 2
+    logits = jnp.asarray(raw, jnp.float32)
+    temp = jnp.asarray([r[0] for r in rows], jnp.float32)
+    topk = jnp.asarray([r[1] for r in rows], jnp.int32)
+    topp = jnp.asarray([r[2] for r in rows], jnp.float32)
+    keys = jnp.stack([jax.random.PRNGKey(i + 20) for i in range(b)])
+    positions = jnp.arange(b) * 3 + 1
+
+    scaled = logits / jnp.where(temp > 0, temp, 1.0)[:, None]
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(decode.filter_logits_batched)(scaled, topk, topp)),
+        np.asarray(jax.jit(_two_sort_filter)(scaled, topk, topp)))
+
+    got = np.asarray(jax.jit(decode.sample_logits_batched)(
+        logits, positions, temp, keys, topk, topp))
+    assert got.dtype == np.int32
+    for r, (t, k, p) in enumerate(rows):
+        want = decode.sample_logits(logits[r:r + 1], int(positions[r]), t,
+                                    jax.random.PRNGKey(r + 20), k or None,
+                                    p or None)
+        assert got[r] == int(np.asarray(want)[0]), f"row {r}"
+
+
+def _computations(hlo):
+    """A compiled module's text by computation: ``{name: lines}``, and for
+    every ``conditional`` the computation it sits in and those it branches
+    into, ``[(holder, branches)]``."""
+    bodies, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if m:
+            name = m.group(1)
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    conds = []
+    for name, body in bodies.items():
+        for line in body:
+            if re.search(r"\sconditional\(", line):
+                group, = re.findall(r"branch_computations=\{([^}]*)\}", line)
+                conds.append((name, {n.strip().lstrip("%")
+                                     for n in group.split(",")}))
+    return bodies, conds
+
+
+def test_compiled_sampler_sorts_once_inside_a_conditional():
+    """The compiled program holds ONE sort (the second is derived from the
+    first), in a branch of a conditional (a call whose rows do not filter
+    never runs it) that itself sits in a branch of the other (a greedy call
+    runs neither the divide nor the draw): the gates survive the compiler,
+    none is flattened into a select."""
+    b = 4
+    args = (jnp.zeros((b, VOCAB), jnp.float32), jnp.zeros((b,), jnp.int32),
+            jnp.zeros((b,), jnp.float32), jnp.zeros((b, 2), jnp.uint32),
+            jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.float32))
+    hlo = jax.jit(decode.sample_logits_batched).lower(
+        *args).compile().as_text()
+    bodies, conds = _computations(hlo)
+    is_sort = re.compile(r"=\s.*\ssort\(")
+    sorts = [n for n, body in bodies.items() for line in body
+             if is_sort.search(line)]
+    assert len(sorts) == 1, sorts
+    assert len(conds) == 2, conds
+    (inner_at, inner), = [c for c in conds if sorts[0] in c[1]]
+    (outer_at, outer), = [c for c in conds if inner_at in c[1]]
+    assert outer_at.startswith("main") and outer != inner
+    # the yardstick of that reading: the two-sort formula, ungated
+    plain = jax.jit(_two_sort_filter).lower(
+        args[0], args[4], args[5]).compile().as_text()
+    assert len(is_sort.findall(plain)) == 2 and "conditional(" not in plain
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_a_retired_sampling_slot_costs_later_greedy_steps_nothing(
+        fitted, monkeypatch, paged):
+    """A ``top_p`` request retires while greedy requests go on in other
+    slots.  Its slot keeps its temperature and ``top_p`` on the device
+    (retirement clears the active flag alone), yet the decode step hands
+    the sampler live rows only: every later step is a greedy one to the
+    sampler (all-zero temperatures: no draw, no sort), to the host's
+    counters and to the ``serve.decode_dispatch`` span, and every request's
+    tokens are ``generate``'s."""
+    from distkeras_tpu import serving
+
+    temps, spans = [], []
+    real = decode.sample_logits_batched
+
+    def spy(logits, positions, temperature, rngs, top_k, top_p):
+        jax.debug.callback(lambda t: temps.append(np.asarray(t)),
+                           temperature)
+        return real(logits, positions, temperature, rngs, top_k, top_p)
+
+    @contextlib.contextmanager
+    def record(name, **fields):
+        spans.append((name, fields))
+        yield
+
+    monkeypatch.setattr(decode, "sample_logits_batched", spy)
+    monkeypatch.setattr(serving, "span", record)
+    kw = dict(paged=True, block_size=4) if paged else {}
+    eng = ServingEngine(fitted, num_slots=3, max_len=24, **kw)
+    long_a = eng.submit(PROMPT, 14)
+    nucleus = eng.submit(PROMPT[::-1].copy(), 3, temperature=0.7, top_p=0.9,
+                         seed=11)
+    long_b = eng.submit(PROMPT + 1, 14)
+    while nucleus.finish is None:
+        assert eng.step()
+    jax.effects_barrier()
+    assert not long_a.done and not long_b.done
+    stats = dict(eng.stats)
+    assert stats["sampler_filter_steps"] >= 2
+    assert stats["sampler_draw_steps"] == stats["sampler_filter_steps"]
+    assert any(f["sample"] == "filter" for n, f in spans
+               if n == "serve.decode_dispatch")
+    del temps[:], spans[:]
+
+    eng.run_until_idle()
+    jax.effects_barrier()
+    # the retired slot is stale on the device, not cleared
+    stale, = np.flatnonzero(np.asarray(eng._dev_topp) > 0)
+    assert np.asarray(eng._dev_temp)[stale] == np.float32(0.7)
+    assert not np.asarray(eng._dev_act)[stale]
+    steps = eng.stats["decode_steps"] - stats["decode_steps"]
+    assert steps >= 8
+    assert eng.stats["sampler_filter_steps"] == stats["sampler_filter_steps"]
+    assert eng.stats["sampler_draw_steps"] == stats["sampler_draw_steps"]
+    sent = [f for n, f in spans if n == "serve.decode_dispatch"]
+    assert len(sent) == steps
+    assert {f["sample"] for f in sent} == {"greedy"}
+    seen = [t for t in temps if t.shape == (3,)]
+    assert len(seen) == steps and not np.any(seen)
+
+    for h, prompt in ((long_a, PROMPT), (long_b, PROMPT + 1)):
+        np.testing.assert_array_equal(h.result(), np.asarray(
+            fitted.generate(prompt[None], 14, max_len=24))[0])
+    np.testing.assert_array_equal(nucleus.result(), np.asarray(
+        fitted.generate(PROMPT[::-1][None], 3, max_len=24, temperature=0.7,
+                        top_p=0.9, rng=jax.random.PRNGKey(11)))[0])
+
+
+def test_speculative_round_tokens_unchanged_by_the_gated_filter(
+        fitted, monkeypatch):
+    """The speculative round warps through ``filter_logits_batched`` too
+    (k draft steps, the verify, the bonus).  Greedy, filtered and
+    unfiltered sampling rows side by side, then a second wave beside the
+    first one's retired slots: every token equals what the round emits
+    with the ungated two-sort formula in the filter's place."""
+    def served():
+        eng = ServingEngine(fitted, num_slots=4, max_len=28,
+                            spec_draft=_fitted(seed=5), spec_len=3,
+                            prefills_per_step=4)
+        hs = [eng.submit(PROMPT, 12),
+              eng.submit(PROMPT[::-1].copy(), 3, temperature=0.7, top_p=0.8,
+                         seed=3),
+              eng.submit(PROMPT + 1, 14, temperature=0.9, top_k=4, top_p=0.9,
+                         seed=4),
+              eng.submit(PROMPT + 2, 13, temperature=1.1, seed=5)]
+        eng.run_until_idle()
+        hs += [eng.submit(PROMPT + 3, 9),
+               eng.submit(PROMPT + 4, 9, temperature=0.8, seed=6)]
+        eng.run_until_idle()
+        return [list(h.tokens) for h in hs], dict(eng.stats)
+
+    got, stats = served()
+    assert 0 < stats["sampler_filter_steps"] < stats["sampler_draw_steps"] \
+        < stats["decode_steps"]
+    monkeypatch.setattr(decode, "filter_logits_batched", _two_sort_filter)
+    assert served()[0] == got
 
 
 def test_generate_unchanged_by_sampling_factor():

@@ -135,7 +135,7 @@ SERVE_SPANS = {
     "serve.schedule": (),
     "serve.admit": ("rid",),
     "serve.prefill_unit": ("rid", "tokens", "kind", "width", "hit"),
-    "serve.decode_dispatch": ("active", "step", "attn"),
+    "serve.decode_dispatch": ("active", "step", "attn", "sample"),
     "serve.fetch": ("step",),
     "serve.emit": ("kind", "rows", "step"),
     "serve.retire": ("rid", "reason"),
@@ -218,6 +218,8 @@ def test_step_joins_a_dispatch_to_the_fetch_that_drains_it(serve_trace):
     assert all(s.fields["active"] > 0 for s in sent.values())
     # the CPU's decode program gathers; a TPU's reads through the kernel
     assert {s.fields["attn"] for s in sent.values()} == {"gather"}
+    # the three requests are greedy: no step asks the sampler for a draw
+    assert {s.fields["sample"] for s in sent.values()} == {"greedy"}
     # a step's tokens are emitted once, as a decode entry of its live rows
     emitted = {s.fields["step"]: s for s in spans
                if s.name == "serve.emit" and s.fields["kind"] == "decode"}
