@@ -30,28 +30,49 @@ import jax.numpy as jnp
 
 tmap = jax.tree_util.tree_map
 
-from .layers import (Dense, Embedding, LayerNormalization,
-                     MultiHeadAttention, PositionalEmbedding,
-                     TransformerBlock, _apply_activation, _project,
-                     scope_names)
+from .layers import (Dense, Embedding, HybridBlock, LayerNormalization,
+                     MultiHeadAttention, PositionalEmbedding, RMSNorm,
+                     TransformerBlock, _project, scope_names)
 
-_STATELESS = (LayerNormalization, Dense)
+_STATELESS = (LayerNormalization, RMSNorm, Dense)
+#: the layers that keep per-request state.  What they keep is their mixer's
+#: ``state_kind`` — ``kv``: keys and values of every position (a dense slab,
+#: or rows of ``Hkv * Dh`` features in a paged arena); ``recurrent``: a
+#: fixed-size pytree a row, whatever the context — and the cached step
+#: dispatches on it (``_CACHED_MIX``), not on the block's class
+_BLOCKS = (TransformerBlock, HybridBlock)
 
 
 def _check_supported(model) -> None:
     for layer in model.layers:
-        if not isinstance(layer, (Embedding, PositionalEmbedding,
-                                  TransformerBlock) + _STATELESS):
+        if not isinstance(layer, (Embedding, PositionalEmbedding)
+                          + _BLOCKS + _STATELESS):
             raise ValueError(
-                f"decode: unsupported layer kind {layer.kind!r} — KV-cache "
-                "decoding walks Embedding/PositionalEmbedding/"
-                "TransformerBlock/LayerNormalization/Dense sequences "
-                "(the transformer_lm family)")
-        if isinstance(layer, TransformerBlock) and not layer.causal:
+                f"decode: unsupported layer kind {layer.kind!r} — cached "
+                "decoding walks Embedding / PositionalEmbedding / "
+                "TransformerBlock / HybridBlock (a GatedAttention or "
+                "KimiDeltaAttention mixer over a SparseMoE) / "
+                "LayerNormalization / RMSNorm / Dense sequences "
+                "(transformer_lm and hybrid_lm)")
+        if isinstance(layer, _BLOCKS) and not layer.causal:
             raise ValueError(
                 "decode: TransformerBlock(causal=False) — autoregressive "
                 "decoding is only meaningful for causal models, and the "
                 "cached step would silently diverge from the full forward")
+        if isinstance(layer, _BLOCKS) and \
+                layer.state_kind not in _CACHED_MIX:
+            raise ValueError(f"decode: no cached step for state kind "
+                             f"{layer.state_kind!r} ({layer.kind})")
+
+
+def has_recurrent_state(model) -> bool:
+    """Does some layer keep a fixed-size recurrent state instead of keys and
+    values?  Then a request's past is NOT all in its KV blocks: prefix
+    sharing, preemption and block transfer, which assume it is, do not
+    apply (``ServingEngine`` decides from this, never from an option)."""
+    return any(isinstance(layer, _BLOCKS)
+               and layer.state_kind == "recurrent"
+               for layer in model.layers)
 
 
 def _context_limit(model) -> Optional[int]:
@@ -72,12 +93,14 @@ def _validate_rolling(model) -> None:
     """Every block must carry a window for a ring cache to be sound:
     without one, old positions stay visible and must stay cached."""
     for layer in model.layers:
-        if isinstance(layer, TransformerBlock) and \
-                layer._mha().attention_window is None:
+        if isinstance(layer, _BLOCKS) and (
+                layer.state_kind != "kv"
+                or layer.mixer().attention_window is None):
             raise ValueError(
                 "rolling=True needs attention_window on every "
                 "TransformerBlock: without a window, old positions stay "
-                "visible and must stay cached")
+                "visible and must stay cached (and a recurrent layer keeps "
+                "no positions to roll over)")
 
 
 def init_cache(model, batch: int, max_len: int,
@@ -121,8 +144,10 @@ def init_cache(model, batch: int, max_len: int,
     dtype = model._cdtype
     caches: List[Any] = []
     for layer in model.layers:
-        if isinstance(layer, TransformerBlock):
-            mha = layer._mha()
+        if isinstance(layer, _BLOCKS) and layer.state_kind == "recurrent":
+            caches.append(layer.mixer().init_state(batch, dtype))
+        elif isinstance(layer, _BLOCKS):
+            mha = layer.mixer()
             slots = max_len
             if rolling:
                 slots = min(mha.attention_window + int(ring_slack), max_len)
@@ -170,8 +195,12 @@ class PagedView:
 
 
 def init_paged_arena(model, num_blocks: int, block_size: int,
-                     kv_dtype: Optional[str] = None) -> List[Any]:
-    """The paged slot pool's backing store: per TransformerBlock a FLAT
+                     kv_dtype: Optional[str] = None,
+                     num_slots: Optional[int] = None) -> List[Any]:
+    """The paged slot pool's backing store, layer by layer by state kind.  A
+    ``recurrent`` layer gets its fixed-size state for ``num_slots`` slots
+    (``{"S": f32[slots, H, Dk, Dv], "conv": [slots, c - 1, 3 H Dk]}``: no
+    blocks, nothing to page).  A ``kv`` layer gets a FLAT
     arena of ``num_blocks + 1`` fixed-size blocks laid out contiguously —
     ``{"k", "v"}`` of shape ((num_blocks + 1) * block_size, num_kv_heads *
     key_dim): a position's kv heads side by side in ONE row (plus
@@ -208,8 +237,17 @@ def init_paged_arena(model, num_blocks: int, block_size: int,
     dtype = model._cdtype
     caches: List[Any] = []
     for layer in model.layers:
-        if isinstance(layer, TransformerBlock):
-            mha = layer._mha()
+        if isinstance(layer, _BLOCKS) and layer.state_kind == "recurrent":
+            if num_slots is None:
+                raise ValueError("a recurrent layer's state is per slot: "
+                                 "init_paged_arena needs num_slots")
+            if kv_dtype is not None:
+                raise ValueError(
+                    f"kv_dtype={kv_dtype!r} quantises keys and values; a "
+                    "recurrent state is float32 and has no such form")
+            caches.append(layer.mixer().init_state(int(num_slots), dtype))
+        elif isinstance(layer, _BLOCKS):
+            mha = layer.mixer()
             shape = (arena_len, mha._kv_heads() * mha.key_dim)
             if kv_dtype == "int8":
                 scales = (arena_len, mha._kv_heads())
@@ -337,13 +375,14 @@ def paged_kernel_applies(mha: MultiHeadAttention, cache, paged: "PagedView",
 
 def paged_step_on_kernel(model, caches, batch: int, page: int, view: int,
                          ring: bool = False) -> bool:
-    """True when EVERY attention layer of a single-token paged step over
+    """True when EVERY attention layer (the layers of state kind ``kv``; a
+    recurrent layer reads no keys) of a single-token paged step over
     ``caches`` reads through the decode kernel — what the serving engine's
     decode program is built on (``paged_kernel_applies`` layer by layer,
     with the shapes that step will trace)."""
     probe = PagedView(None, page, view, ring=ring)
-    blocks = [(layer._mha(), c) for layer, c in zip(model.layers, caches)
-              if isinstance(layer, TransformerBlock)]
+    blocks = [(layer.mixer(), c) for layer, c in zip(model.layers, caches)
+              if isinstance(layer, _BLOCKS) and layer.state_kind == "kv"]
     return bool(blocks) and all(
         paged_kernel_applies(m, c, probe, jax.ShapeDtypeStruct(
             (batch, 1, m.num_heads, m.key_dim), model._cdtype))
@@ -567,31 +606,105 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
                                              (0, pos, 0, 0))
         out = attend(k, v, q_offset=pos, kv_length=pos + length)
     out = out.reshape(b, length, mha.num_heads * dh)
+    out = mha.gate(params, h, out, cdtype)
     bias_o = params.get("bo") if mha.use_bias else None
     y = _project(out, params["wo"], bias_o, cdtype)
     return y, (new_cache if new_cache is not None else {"k": k, "v": v})
 
 
-def _block_forward(block: TransformerBlock, params, x, cache, pos, cdtype,
+class RowView:
+    """Which per-slot state each row of a program's batch owns, for the
+    layers whose state is per slot and not paged (state kind
+    ``recurrent``).  ``slots`` (B,) int32: row r continues slot
+    ``slots[r]`` of the state arrays — from ZERO where the row starts at
+    position 0, which is how a slot is cleared on admission — and writes it
+    back (an index past the last slot drops the write: ``warmup`` and the
+    unused rows of a bucket program); None: row r IS slot r, advanced in
+    place (the decode step, and every offline walker).  ``live`` (B,) bool:
+    the rows that hold a request; a dead row's state stays as it is."""
+
+    __slots__ = ("slots", "live")
+
+    def __init__(self, slots=None, live=None):
+        self.slots = slots
+        self.live = live
+
+
+def _token_mask(length: int, pos, paged: Optional[PagedView],
+                rows: Optional[RowView]):
+    """(B, L) bool, the positions of a step that count: not the right-pad
+    past a row's ``ceil``, not a dead row's.  None: all of them."""
+    mask = None
+    if paged is not None and paged.ceil is not None:
+        idx = pos[:, None] + jnp.arange(length)[None, :]
+        mask = idx < jnp.reshape(paged.ceil, (-1, 1))
+    if rows is not None and rows.live is not None:
+        live = jnp.broadcast_to(rows.live[:, None],
+                                (rows.live.shape[0], length))
+        mask = live if mask is None else mask & live
+    return mask
+
+
+def _recurrent_forward(mixer, params, h, cache, pos, cdtype, rolling,
+                       paged, rows, token_mask):
+    """Cached step of a ``recurrent`` mixer over (B, L, D): each row
+    continues its slot's state (``RowView``) through ``mixer.mix`` and
+    leaves the state after its last live token.  A prefill unit of any
+    length carries the state on; the single-token step of the serving pool
+    goes through the fused kernel (``ops.kda.kda_decode``) on a TPU."""
+    from ..ops import kda
+    slots = None if rows is None else rows.slots
+    state = cache
+    if slots is not None:
+        n = cache["S"].shape[0]
+        fresh = jnp.reshape(pos, (-1,)) == 0
+        state = tmap(
+            lambda a: jnp.where(
+                fresh.reshape((-1,) + (1,) * (a.ndim - 1)),
+                jnp.zeros((), a.dtype), a[jnp.clip(slots, 0, n - 1)]),
+            cache)
+    fused = (h.shape[1] == 1 and slots is None and _on_tpu()
+             and kda.kernel_tiles(state["S"].shape, state["S"].dtype))
+    y, new = mixer.mix(params, h, state, compute_dtype=cdtype,
+                       token_mask=token_mask, fused_step=fused)
+    if slots is not None:
+        new = tmap(lambda big, row: big.at[slots].set(row, mode="drop"),
+                   cache, new)
+    return y, new
+
+
+def _kv_forward(mixer, params, h, cache, pos, cdtype, rolling, paged, rows,
+                token_mask):
+    return _mha_forward(mixer, params, h, cache, pos, cdtype, rolling, paged)
+
+
+#: the cached step of a block's mixer, by the kind of state it keeps
+_CACHED_MIX = {"kv": _kv_forward, "recurrent": _recurrent_forward}
+
+
+def _block_forward(block, params, x, cache, pos, cdtype,
                    rolling: bool = False,
-                   paged: Optional[PagedView] = None):
-    """Mirrors ``TransformerBlock.apply`` (train=False) with cached MHA."""
-    ln = LayerNormalization()
-    with jax.named_scope("attn"):
-        h = ln.apply(params["ln1"], x, compute_dtype=cdtype)
-        h, cache = _mha_forward(block._mha(), params["attn"], h, cache, pos,
-                                cdtype, rolling, paged)
-        x = x + h.astype(x.dtype)
-    with jax.named_scope("mlp"):
-        h = ln.apply(params["ln2"], x, compute_dtype=cdtype)
-        h = _project(h, params["mlp_w1"], params["mlp_b1"], cdtype)
-        h = _apply_activation(block.activation, h).astype(cdtype)
-        h = _project(h, params["mlp_w2"], params["mlp_b2"], cdtype)
-        return x + h.astype(x.dtype), cache
+                   paged: Optional[PagedView] = None,
+                   rows: Optional[RowView] = None, token_mask=None):
+    """``block.run`` (the block's own norms, residuals and feed-forward
+    part: the lines its full-sequence ``apply`` runs) around the CACHED step
+    of its mixer.  Returns ``(y, new cache, counters or None)``."""
+    box = {}
+
+    def mix(mixer, mixer_params, h):
+        y, box["cache"] = _CACHED_MIX[mixer.state_kind](
+            mixer, mixer_params, h, cache, pos, cdtype, rolling, paged,
+            rows, token_mask)
+        return y
+
+    x, counters = block.run(params, x, mix, compute_dtype=cdtype,
+                            token_mask=token_mask)
+    return x, box["cache"], counters
 
 
 def _forward(model, params, caches, toks, pos, rolling: bool = False,
-             paged: Optional[PagedView] = None):
+             paged: Optional[PagedView] = None,
+             rows: Optional[RowView] = None, aux: Optional[list] = None):
     """Walk the layer stack over (B, L) tokens starting at position
     ``pos``; returns ((B, L, V) f32 logits, new caches).  L == 1 is a
     decode step, L == P is the batched prompt prefill.  ``pos`` may be a
@@ -601,10 +714,17 @@ def _forward(model, params, caches, toks, pos, rolling: bool = False,
     continuation positions in one forward).  L > 1
     batches may be right-padded to a shared length (the serving engine's
     bucketed prefill) — see ``_mha_forward`` for why the causal mask
-    alone keeps pad tokens out of every real position's numerics."""
+    alone keeps pad tokens out of every real position's numerics.  ``rows``
+    (a :class:`RowView`) places the batch's rows on the slots of per-slot
+    state; ``aux``, a list, receives each block's counters (a ``SparseMoE``
+    feed-forward part's) in layer order."""
     cdtype = model._cdtype
     x = None
     new_caches: List[Any] = []
+    token_mask = None
+    if any(isinstance(layer, _BLOCKS) and layer.wants_token_mask
+           for layer in model.layers):
+        token_mask = _token_mask(toks.shape[1], pos, paged, rows)
     for layer, p, cache, scope in zip(model.layers, params, caches,
                                       scope_names(model.layers)):
         with jax.named_scope(scope):
@@ -628,17 +748,21 @@ def _forward(model, params, caches, toks, pos, rolling: bool = False,
                     pe = jax.lax.dynamic_slice_in_dim(
                         jnp.asarray(p["embedding"]), pos, toks.shape[1])
                     x = x + pe.astype(x.dtype)[None]
-            elif isinstance(layer, TransformerBlock):
-                x, cache = _block_forward(layer, p, x, cache, pos, cdtype,
-                                          rolling, paged)
-            else:  # LayerNormalization / Dense: position-independent
+            elif isinstance(layer, _BLOCKS):
+                x, cache, counters = _block_forward(
+                    layer, p, x, cache, pos, cdtype, rolling, paged, rows,
+                    token_mask)
+                if aux is not None and counters is not None:
+                    aux.append(counters)
+            else:  # norms / Dense: position-independent
                 x = layer.apply(p, x, compute_dtype=cdtype, train=False)
         new_caches.append(cache)
     return x.astype(jnp.float32), new_caches
 
 
 def decode_step(model, params, caches, tok, pos, rolling: bool = False,
-                paged: Optional[PagedView] = None):
+                paged: Optional[PagedView] = None,
+                rows: Optional[RowView] = None, aux: Optional[list] = None):
     """Advance one position.  tok: (B,) int32 current tokens; pos: scalar
     int32 position (0-based), or a (B,) int32 vector advancing every row
     at its OWN position (the serving engine's slot batch — each row writes
@@ -649,7 +773,7 @@ def decode_step(model, params, caches, tok, pos, rolling: bool = False,
     in ``jax.jit`` (or let ``generate`` do it) for real use;
     ``jit_decode_step`` packages exactly that."""
     logits, caches = _forward(model, params, caches, tok[:, None], pos,
-                              rolling, paged)
+                              rolling, paged, rows, aux)
     return logits[:, 0], caches
 
 

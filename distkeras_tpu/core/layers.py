@@ -537,6 +537,11 @@ class MultiHeadAttention(Layer):
     rope: bool = False  # rotary position embeddings on q/k
     rope_theta: float = 10000.0  # RoPE base (raise via ntk_theta to extend)
     rope_scale: float = 1.0      # linear position-interpolation factor
+    #: ``out * sigmoid(x @ wg)`` before the output projection
+    #: (``GatedAttention`` sets it)
+    output_gate: bool = False
+    #: what a cached step keeps for this mixer (``core/decode.py``)
+    state_kind = "kv"
 
     def __init__(self, num_heads: int, key_dim: int, causal: bool = False,
                  use_bias: bool = True, attention_impl: Optional[str] = None,
@@ -589,6 +594,9 @@ class MultiHeadAttention(Layer):
             "wv": init_weight(ks[2], (d, inner_kv)),
             "wo": init_weight(ks[3], (inner, d)),
         }
+        if self.output_gate:
+            params["wg"] = init_weight(jax.random.fold_in(rng, 4),
+                                       (d, inner))
         if self.use_bias:
             params.update(bq=jnp.zeros((inner,), jnp.float32),
                           bk=jnp.zeros((inner_kv,), jnp.float32),
@@ -625,8 +633,18 @@ class MultiHeadAttention(Layer):
                             window=self.attention_window,
                             segment_ids=segment_ids)
         out = out.reshape(b, s, self.num_heads * dh)
+        out = self.gate(params, x, out, compute_dtype)
         bias_o = params.get("bo") if self.use_bias else None
         return _project(out, params["wo"], bias_o, compute_dtype)
+
+    def gate(self, params, x, out, compute_dtype):
+        """The output gate, where the layer has one: the heads' outputs
+        (B, S, H * Dh) times ``sigmoid(x @ wg)``, elementwise, in float32."""
+        if not self.output_gate:
+            return out
+        with jax.named_scope("attn_gate"):
+            g = jax.nn.sigmoid(_project(x, params["wg"], None, compute_dtype))
+            return (out.astype(jnp.float32) * g).astype(out.dtype)
 
 
 class TransformerBlock(Layer):
@@ -704,18 +722,38 @@ class TransformerBlock(Layer):
         return params, tuple(in_shape)
 
     takes_segment_ids = True
+    state_kind = "kv"
+    #: what the cached step and the serving engine ask of a block
+    #: (``HybridBlock`` answers from its parts)
+    routes_tokens = False
+    wants_token_mask = False
+    int8_weights = True
+
+    def mixer(self) -> MultiHeadAttention:
+        return self._mha()
 
     def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
               rng=None, segment_ids=None):
+        def full(mha, p, h):
+            return mha.apply(p, h, compute_dtype=compute_dtype, train=train,
+                             rng=None, segment_ids=segment_ids)
+        return self.run(params, x, full, compute_dtype=compute_dtype,
+                        train=train, rng=rng)[0]
+
+    def run(self, params, x, mix, *, compute_dtype=jnp.bfloat16,
+            train=False, rng=None, token_mask=None):
+        """The block around its mixer: ``mix(mixer, mixer params, normed
+        input) -> mixed`` is the full-sequence attention (``apply``) or the
+        cached one (``core/decode.py``); norms, residuals and the MLP are
+        the same lines either way.  Returns ``(y, None)``: this block has
+        no counters."""
         ln = LayerNormalization()
         drop_rngs = (jax.random.split(rng, 2) if rng is not None else
                      (None, None))
 
         with jax.named_scope("attn"):
             h = ln.apply(params["ln1"], x, compute_dtype=compute_dtype)
-            h = self._mha().apply(params["attn"], h,
-                                  compute_dtype=compute_dtype, train=train,
-                                  rng=None, segment_ids=segment_ids)
+            h = mix(self._mha(), params["attn"], h)
             x = x + _dropout(drop_rngs[0], self.dropout, h.astype(x.dtype),
                              train)
         with jax.named_scope("mlp"):
@@ -726,7 +764,7 @@ class TransformerBlock(Layer):
             h = _project(h, params["mlp_w2"], params["mlp_b2"],
                          compute_dtype)
             return x + _dropout(drop_rngs[1], self.dropout,
-                                h.astype(x.dtype), train)
+                                h.astype(x.dtype), train), None
 
 
 class Embedding(Layer):
@@ -744,24 +782,381 @@ class Embedding(Layer):
         return params["embedding"].astype(compute_dtype)[x]
 
 
+# ---------------------------------------------------------------------------
+# Hybrid blocks: a mixer (attention or a linear recurrence) and a
+# feed-forward part (a gated MLP or sparse experts) under RMSNorm
+# ---------------------------------------------------------------------------
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the trailing dim with a learned scale, no
+    offset; float32 arithmetic."""
+
+    def __init__(self, epsilon: float = 1e-5):
+        self.epsilon = float(epsilon)
+
+    def init(self, rng, in_shape):
+        return {"scale": jnp.ones((in_shape[-1],), jnp.float32)}, \
+            tuple(in_shape)
+
+    def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
+              rng=None):
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + self.epsilon)
+        return (y * params["scale"]).astype(x.dtype)
+
+
+class GatedAttention(MultiHeadAttention):
+    """Causal grouped-query attention with NO position signal (NoPE), no
+    biases, and an output gate: ``o = attn * sigmoid(x @ wg)`` elementwise
+    over the ``num_heads * key_dim`` features, then ``wo``.  The cached step
+    is ``MultiHeadAttention``'s (state kind ``kv``)."""
+
+    output_gate = True
+
+    def __init__(self, num_heads: int, key_dim: int,
+                 num_kv_heads: Optional[int] = None):
+        super().__init__(num_heads, key_dim, causal=True, use_bias=False,
+                         num_kv_heads=num_kv_heads)
+
+
+def _l2_normalise(x):
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+                             + 1e-6)
+
+
+class KimiDeltaAttention(Layer):
+    """Kimi Delta Attention (arXiv:2510.26692): gated delta-rule linear
+    attention with a per-channel decay, a short causal depthwise convolution
+    on q, k and v, and a gated, per-head-normalised output.  The arithmetic
+    of the recurrence is ``ops/kda.py``'s; here are the projections around
+    it.  Per request the layer keeps a FIXED-SIZE state (state kind
+    ``recurrent``): ``S`` (H, Dk, Dv) float32 and the last ``conv_size - 1``
+    inputs of the convolution.
+
+    ``neg_eigval``: ``beta = 2 * sigmoid(.)`` (the state transition may have
+    negative eigenvalues) instead of ``sigmoid(.)``.  ``gate_rank``: the
+    decay and output gates are low-rank, ``d -> gate_rank -> H * Dh``."""
+
+    state_kind = "recurrent"
+
+    def __init__(self, num_heads: int, head_dim: int, conv_size: int = 4,
+                 gate_rank: int = 128, neg_eigval: bool = True,
+                 norm_eps: float = 1e-5):
+        self.num_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        self.conv_size = int(conv_size)
+        self.gate_rank = int(gate_rank)
+        self.neg_eigval = bool(neg_eigval)
+        self.norm_eps = float(norm_eps)
+
+    def init(self, rng, in_shape):
+        s, d = in_shape
+        h, dh, r = self.num_heads, self.head_dim, self.gate_rank
+        inner = h * dh
+        ks = iter(jax.random.split(rng, 16))
+        params = {
+            "wq": init_weight(next(ks), (d, inner)),
+            "wk": init_weight(next(ks), (d, inner)),
+            "wv": init_weight(next(ks), (d, inner)),
+            "wo": init_weight(next(ks), (inner, d)),
+            "wb": init_weight(next(ks), (d, h)),
+            "wf_down": init_weight(next(ks), (d, r)),
+            "wf_up": init_weight(next(ks), (r, inner)),
+            "wg_down": init_weight(next(ks), (d, r)),
+            "wg_up": init_weight(next(ks), (r, inner)),
+            # decays spread over (0, 1): a_log in [log 1/16, log 4)
+            "a_log": jnp.log(jax.random.uniform(next(ks), (h,), jnp.float32,
+                                                1.0 / 16.0, 4.0)),
+            "dt_bias": jnp.zeros((inner,), jnp.float32),
+            "o_norm": jnp.ones((dh,), jnp.float32),
+        }
+        for name in ("conv_q", "conv_k", "conv_v"):
+            params[name] = init_weight(next(ks), (self.conv_size, inner),
+                                       "glorot_normal")
+        return params, tuple(in_shape)
+
+    def init_state(self, batch: int, dtype):
+        """A zero state for ``batch`` rows: what a request starts from."""
+        h, dh = self.num_heads, self.head_dim
+        return {"S": jnp.zeros((batch, h, dh, dh), jnp.float32),
+                "conv": jnp.zeros((batch, self.conv_size - 1, 3 * h * dh),
+                                  dtype)}
+
+    def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
+              rng=None):
+        state = self.init_state(x.shape[0], compute_dtype)
+        return self.mix(params, x, state, compute_dtype=compute_dtype)[0]
+
+    def mix(self, params, x, state, *, compute_dtype=jnp.bfloat16,
+            token_mask=None, fused_step: bool = False):
+        """(B, L, D) inputs continuing ``state`` -> ``(y (B, L, D) float32,
+        the state after each row's last live token)``.  ``token_mask``
+        (B, L) bool: the positions that count, a PREFIX of each row
+        (right-padding and dead rows are False: they leave the state
+        alone).  ``fused_step``: take the single-token step through the
+        ``kda_decode`` kernel, which skips the rows the mask marks dead."""
+        from ..ops import kda
+        f32 = jnp.float32
+        b, length, _ = x.shape
+        h, dh = self.num_heads, self.head_dim
+        inner = h * dh
+        if token_mask is None:
+            token_mask = jnp.ones((b, length), bool)
+        n_live = jnp.sum(token_mask, axis=1).astype(jnp.int32)     # (B,)
+
+        with jax.named_scope("kda_conv"):
+            qkv = jnp.concatenate(
+                [_project(x, params[w], None, compute_dtype)
+                 for w in ("wq", "wk", "wv")], axis=-1).astype(compute_dtype)
+            hist = jnp.concatenate([state["conv"].astype(compute_dtype),
+                                    qkv], axis=1)          # (B, c-1 + L, 3I)
+            taps = jnp.concatenate(
+                [params[w] for w in ("conv_q", "conv_k", "conv_v")],
+                axis=-1).astype(f32)                       # (c, 3I)
+            conv = sum(hist[:, i:i + length].astype(f32) * taps[i]
+                       for i in range(self.conv_size))
+            conv = jax.nn.silu(conv)
+            # the inputs of the last c-1 live positions: position t sits at
+            # hist[t + c-1], so they are hist[n .. n + c-2]
+            idx = n_live[:, None] + jnp.arange(self.conv_size - 1)[None, :]
+            new_conv = jnp.take_along_axis(hist, idx[:, :, None], axis=1)
+            q, k, v = (conv[..., i * inner:(i + 1) * inner]
+                       .reshape(b, length, h, dh) for i in range(3))
+            q = _l2_normalise(q) * (dh ** -0.5)
+            k = _l2_normalise(k)
+
+        def low_rank(down, up):
+            mid = _project(x, params[down], None, compute_dtype)
+            return _project(mid.astype(compute_dtype), params[up], None,
+                            compute_dtype)
+
+        with jax.named_scope("kda_gates"):
+            decay = jax.nn.softplus(low_rank("wf_down", "wf_up")
+                                    + params["dt_bias"].astype(f32))
+            g = -jnp.exp(params["a_log"].astype(f32))[:, None] \
+                * decay.reshape(b, length, h, dh)
+            beta = jax.nn.sigmoid(_project(x, params["wb"], None,
+                                           compute_dtype))
+            if self.neg_eigval:
+                beta = 2.0 * beta
+            g = jnp.where(token_mask[:, :, None, None], g, 0.0)
+            beta = jnp.where(token_mask[:, :, None], beta, 0.0)
+
+        with jax.named_scope("kda_core"):
+            if length == 1 and fused_step:
+                o, new_s = kda.kda_decode(
+                    q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                    state["S"], token_mask[:, 0])
+                o = o[:, None]
+            elif length == 1:
+                o, new_s = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                        beta[:, 0], state["S"])
+                o = o[:, None]
+            else:
+                o, new_s = kda.kda_chunk(q, k, v, g, beta, state["S"])
+
+        with jax.named_scope("kda_gate_out"):
+            o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1,
+                                           keepdims=True) + self.norm_eps)
+            o = o * params["o_norm"].astype(f32)
+            gate = jax.nn.sigmoid(low_rank("wg_down", "wg_up"))
+            o = (o.reshape(b, length, inner) * gate).astype(compute_dtype)
+            y = _project(o, params["wo"], None, compute_dtype)
+        return y, {"S": new_s, "conv": new_conv.astype(state["conv"].dtype)}
+
+
+def _gated_mlp(x, w_in, w_out, compute_dtype):
+    """``(silu(x @ gate) * (x @ up)) @ down`` with gate and up side by side
+    in ``w_in`` (D, 2F)."""
+    h = _project(x, w_in, None, compute_dtype)
+    f = h.shape[-1] // 2
+    h = (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(compute_dtype)
+    return _project(h, w_out, None, compute_dtype)
+
+
+class SparseMoE(Layer):
+    """Sparse experts WITHOUT drops, told which experts it holds.
+
+    The router scores all ``num_experts`` in float32 (softmax, the ``top_k``
+    largest, their weights renormalised to 1); each expert is a gated MLP of
+    width ``expert_dim``; one shared expert of width ``shared_dim`` (0: none)
+    is added for every token.  ``held`` = (first, count) names the experts
+    whose weights live HERE — one chip's share of an expert-parallel
+    deployment; default all.  The layer computes the terms of its own experts
+    for the tokens routed to them (``ops/experts.py``: assignments sorted by
+    expert, one grouped matmul in and one out, no capacity and no dropped
+    token under any skew) plus the shared expert; what experts held
+    elsewhere would add is left out, and that partial result goes on."""
+
+    routes_tokens = True
+
+    def __init__(self, num_experts: int, top_k: int, expert_dim: int,
+                 held: Optional[Tuple[int, int]] = None, shared_dim: int = 0):
+        self.num_experts = int(num_experts)
+        self.top_k = int(top_k)
+        self.expert_dim = int(expert_dim)
+        self.held = (0, self.num_experts) if held is None else \
+            (int(held[0]), int(held[1]))
+        self.shared_dim = int(shared_dim)
+        if not (0 <= self.held[0]
+                and self.held[0] + self.held[1] <= self.num_experts):
+            raise ValueError(f"held={self.held} outside the "
+                             f"{self.num_experts} experts")
+
+    def init(self, rng, in_shape):
+        d = in_shape[-1]
+        n, f = self.held[1], self.expert_dim
+        k_r, k_i, k_o, k_si, k_so = jax.random.split(rng, 5)
+        std_in, std_out = (2.0 / (d + f)) ** 0.5, (2.0 / (f + d)) ** 0.5
+        params = {
+            "router": init_weight(k_r, (d, self.num_experts)),
+            "w_in": std_in * jax.random.normal(k_i, (n, d, 2 * f)),
+            "w_out": std_out * jax.random.normal(k_o, (n, f, d)),
+        }
+        if self.shared_dim:
+            params["shared_in"] = init_weight(k_si, (d, 2 * self.shared_dim))
+            params["shared_out"] = init_weight(k_so, (self.shared_dim, d))
+        return params, tuple(in_shape)
+
+    def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
+              rng=None):
+        return self.mix(params, x, compute_dtype=compute_dtype)[0]
+
+    def mix(self, params, x, *, compute_dtype=jnp.bfloat16, token_mask=None):
+        """``(y, counters)``: the layer's output and ``[assignments to held
+        experts, held experts that got a token, the fullest held expert's
+        rows]`` (int32) over the tokens ``token_mask`` counts."""
+        from ..ops import experts as ops
+        f32 = jnp.float32
+        lead, d = x.shape[:-1], x.shape[-1]
+        flat = x.reshape(-1, d)
+        live = None if token_mask is None else token_mask.reshape(-1)
+        with jax.named_scope("moe"):
+            with jax.named_scope("moe_route"):
+                logits = jnp.matmul(flat.astype(f32),
+                                    params["router"].astype(f32),
+                                    precision=jax.lax.Precision.HIGHEST)
+                chosen, weights = ops.route(logits, self.top_k)
+            with jax.named_scope("moe_dispatch"):
+                token, weight, sizes, total = ops.dispatch(
+                    chosen, weights, self.held, live)
+                rows = flat.astype(compute_dtype)[token]
+            with jax.named_scope("moe_experts"):
+                h = ops.grouped_matmul(
+                    rows, params["w_in"].astype(compute_dtype), sizes)
+                f = self.expert_dim
+                h = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(compute_dtype)
+                out = ops.grouped_matmul(
+                    h, params["w_out"].astype(compute_dtype), sizes)
+            with jax.named_scope("moe_combine"):
+                y = jnp.zeros((flat.shape[0], d), f32).at[token].add(
+                    out * weight[:, None])
+            if self.shared_dim:
+                with jax.named_scope("moe_shared"):
+                    y = y + _gated_mlp(flat, params["shared_in"],
+                                       params["shared_out"], compute_dtype)
+        counters = jnp.stack([total, jnp.sum(sizes > 0),
+                              jnp.max(sizes)]).astype(jnp.int32)
+        return y.reshape(lead + (d,)), counters
+
+
+class HybridBlock(Layer):
+    """Pre-norm block that TAKES its parts: ``h = x + mixer(RMSNorm(x))``,
+    ``y = h + ffn(RMSNorm(h))``, no biases.  ``mixer`` is a
+    ``GatedAttention`` or a ``KimiDeltaAttention``; ``ffn`` a ``SparseMoE``.
+    The parts are kept as their configs (the spec stays JSON-serialisable)
+    and rebuilt on use.  What the cached step and the serving engine need to
+    know of a block they ask the block (``state_kind``, ``routes_tokens``,
+    ``wants_token_mask``, ``int8_weights``), never its class."""
+
+    #: ``core.quant.quantize_params`` finds matmul weights by
+    #: ``TransformerBlock``'s names and would leave these as they are
+    int8_weights = False
+
+    def __init__(self, mixer, ffn, epsilon: float = 1e-5):
+        self.mixer_config = (mixer.get_config() if isinstance(mixer, Layer)
+                             else dict(mixer))
+        self.ffn_config = (ffn.get_config() if isinstance(ffn, Layer)
+                           else dict(ffn))
+        self.epsilon = float(epsilon)
+
+    def mixer(self) -> Layer:
+        return Layer.from_config(self.mixer_config)
+
+    def ffn(self) -> Layer:
+        return Layer.from_config(self.ffn_config)
+
+    @property
+    def state_kind(self) -> str:
+        return self.mixer().state_kind
+
+    @property
+    def routes_tokens(self) -> bool:
+        """The feed-forward part routes tokens to experts and returns
+        counters of it (``SparseMoE``)."""
+        return self.ffn().routes_tokens
+
+    @property
+    def wants_token_mask(self) -> bool:
+        """Padding and dead rows must be told apart from live tokens: they
+        would advance a recurrent state, or be routed and counted."""
+        return self.state_kind == "recurrent" or self.routes_tokens
+
+    @property
+    def causal(self) -> bool:
+        return True
+
+    def init(self, rng, in_shape):
+        k_m, k_f = jax.random.split(rng)
+        norm = RMSNorm(self.epsilon)
+        return {"norm1": norm.init(None, in_shape)[0],
+                "mixer": self.mixer().init(k_m, in_shape)[0],
+                "norm2": norm.init(None, in_shape)[0],
+                "ffn": self.ffn().init(k_f, in_shape)[0]}, tuple(in_shape)
+
+    def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
+              rng=None):
+        def full(mixer, p, h):
+            return mixer.apply(p, h, compute_dtype=compute_dtype)
+        return self.run(params, x, full, compute_dtype=compute_dtype)[0]
+
+    def run(self, params, x, mix, *, compute_dtype=jnp.bfloat16,
+            train=False, rng=None, token_mask=None):
+        """As ``TransformerBlock.run``: the block around ``mix``.  Returns
+        ``(y, counters)``, the feed-forward part's counters (``SparseMoE``)
+        or None.  ``token_mask`` (B, L) bool keeps padding and dead rows
+        out of the experts' routing."""
+        norm = RMSNorm(self.epsilon)
+        mixer, ffn = self.mixer(), self.ffn()
+        scope = "kda" if mixer.state_kind == "recurrent" else "attn"
+        with jax.named_scope(scope):
+            h = norm.apply(params["norm1"], x, compute_dtype=compute_dtype)
+            x = x + mix(mixer, params["mixer"], h).astype(x.dtype)
+        h = norm.apply(params["norm2"], x, compute_dtype=compute_dtype)
+        h, counters = ffn.mix(params["ffn"], h, compute_dtype=compute_dtype,
+                              token_mask=token_mask)
+        return x + h.astype(x.dtype), counters
+
+
 def scope_names(layers: Sequence[Layer]) -> List[str]:
     """The ``jax.named_scope`` each layer of a stack runs under, in the
     training forward and the decode forward alike (``metrics.py`` lists
     them): ``embed`` for the token and position tables, ``block_<i>`` for
-    the i-th ``TransformerBlock``, and — in a stack that has blocks —
+    the i-th ``TransformerBlock`` or ``HybridBlock``, and — in a stack that has blocks —
     ``final_norm`` for the normalization after the last one and ``lm_head``
     for a closing ``Dense``.  Any other layer runs under its class name in
     lower case."""
     blocks = [i for i, l in enumerate(layers)
-              if isinstance(l, TransformerBlock)]
+              if isinstance(l, (TransformerBlock, HybridBlock))]
     names = []
     for i, layer in enumerate(layers):
         if isinstance(layer, (Embedding, PositionalEmbedding)):
             name = "embed"
-        elif isinstance(layer, TransformerBlock):
+        elif isinstance(layer, (TransformerBlock, HybridBlock)):
             name = f"block_{blocks.index(i)}"
         elif (blocks and i > blocks[-1]
-              and isinstance(layer, LayerNormalization)):
+              and isinstance(layer, (LayerNormalization, RMSNorm))):
             name = "final_norm"
         elif blocks and i == len(layers) - 1 and isinstance(layer, Dense):
             name = "lm_head"

@@ -49,7 +49,10 @@ Serving (``serving.py``; the engine's thread unless said):
                       sampling row has ``top_k``/``top_p``, the sort runs;
                       ``stats["sampler_draw_steps"]`` counts ``draw`` and
                       ``filter``, ``stats["sampler_filter_steps"]``
-                      ``filter``)
+                      ``filter``), ``state`` (the kinds of per-request
+                      state the dispatched step advances: ``kv``, or
+                      ``kv+recurrent`` for a model with recurrent layers,
+                      whose per-slot state the step updates in place)
 ``serve.fetch``       the device→host read of one in-flight step — the time
                       the host WAITS for the device: ``step`` of the entry
                       drained (joins it to its ``serve.decode_dispatch``;
@@ -69,6 +72,15 @@ Serving (``serving.py``; the engine's thread unless said):
 ``rid`` is ``RequestHandle.id``: the spans of one request share it
 (submit → admit → prefill_unit... → retire).
 
+Counters of a model of hybrid blocks (``ServingEngine.stats``; the decode
+step's program hands them back behind its tokens, so they cost no device
+read of their own): ``moe_assignments_held`` (live rows' assignments to the
+experts held here), ``moe_experts_touched`` (held experts that got one),
+``moe_load_max`` (the fullest held expert's rows), each summed over expert
+layers and decode steps, ``moe_layer_steps`` the (layer, step) pairs summed
+over; ``recurrent_slots_cleared``, admissions whose slot's recurrent state
+was started from zero.  The benchmark's ``.hybrid`` readers read them.
+
 Training (``DistributedTrainer.train``, epoch and per-round paths):
 ``train.epoch`` (``epoch``) with children ``train.shuffle``,
 ``train.shape`` (``shape_epoch_data``), ``train.dispatch`` (host→device
@@ -80,12 +92,22 @@ transfer and launch of ``run_epoch``/``run_round``: ``rounds``),
 time) name the compiled programs' phases in every profile and HLO dump:
 ``embed``, ``block_<i>`` ⊃ ``attn`` ⊃ ``attn_core``, ``mlp``,
 ``final_norm``, ``lm_head`` (model forward, training and decoding alike);
+in a ``HybridBlock``, ``attn`` ⊃ ``attn_core``, ``attn_gate`` (the output
+gate) or ``kda`` ⊃ ``kda_conv`` (projections, convolution, normalisation),
+``kda_gates``, ``kda_core`` (the recurrence: ``kda_chunk`` in a prefill
+unit, the ``kda_decode`` kernel in the decode step), ``kda_gate_out``; and
+``moe`` ⊃ ``moe_route``, ``moe_dispatch``, ``moe_experts`` (the grouped
+matmuls), ``moe_combine``, ``moe_shared``;
 ``loss``, ``optimizer``, ``commit`` (train step and the SPMD round);
 ``kv_write``, ``kv_gather``, ``sample`` (decode step; on the kernel path
 ``kv_gather`` holds only the row lengths' preparation).  Pallas kernels
 carry the names in ``KERNEL_NAMES``: ``flash_fwd``/``flash_dq``/
-``flash_dkv``, ``fused_ce_fwd``/``fused_ce_bwd`` and ``paged_decode``
-(under ``attn_core`` of the paged single-token step).
+``flash_dkv``, ``fused_ce_fwd``/``fused_ce_bwd``, ``paged_decode``
+(under ``attn_core`` of the paged single-token step) and ``kda_decode``
+(under ``kda_core`` of the same step).  The experts' grouped matmul is
+jax's own Pallas kernel (``jax.experimental.pallas.ops.tpu.megablox``),
+which carries no name of this package: it is found by its scope,
+``moe_experts``.
 """
 
 from __future__ import annotations
@@ -101,7 +123,7 @@ import jax
 #: ``chip_smoke.py``'s ``require_kernels`` and ``tests/test_tracing.py`` look
 #: the kernels up by.
 KERNEL_NAMES = ("flash_fwd", "flash_dq", "flash_dkv",
-                "fused_ce_fwd", "fused_ce_bwd", "paged_decode")
+                "fused_ce_fwd", "fused_ce_bwd", "paged_decode", "kda_decode")
 
 
 class MetricsLogger:

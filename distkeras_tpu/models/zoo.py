@@ -9,7 +9,8 @@ from __future__ import annotations
 from ..core import (Sequential, Dense, Conv2D, MaxPooling2D, Flatten, Reshape,
                     Dropout)
 from ..core.layers import (Embedding, PositionalEmbedding, TransformerBlock,
-                           LayerNormalization)
+                           LayerNormalization, RMSNorm, GatedAttention,
+                           KimiDeltaAttention, SparseMoE, HybridBlock)
 
 
 def mnist_mlp(compute_dtype: str = "bfloat16") -> Sequential:
@@ -131,3 +132,61 @@ def transformer_lm(vocab_size: int = 256, seq_len: int = 128,
     layers += [LayerNormalization(), Dense(vocab_size)]
     return Sequential(layers, input_shape=(seq_len,),
                       compute_dtype=compute_dtype, name="transformer_lm")
+
+
+def hybrid_lm(config: dict, compute_dtype: str = "bfloat16",
+              held=None) -> Sequential:
+    """A decoder-only LM of hybrid blocks, built from the keys of a published
+    ``config.json`` of the Solar-Open2 kind: ``gqa_layers`` names the layers
+    whose mixer is gated NoPE grouped-query attention (``num_attention_heads``
+    over ``num_key_value_heads`` of ``head_dim``, ``use_gqa_gate``); every
+    other layer's is Kimi Delta Attention (``linear_attn_config``:
+    ``num_heads``, ``head_dim``, ``short_conv_kernel_size``;
+    ``kda_allow_neg_eigval``; ``kda_use_full_proj`` false: its decay and
+    output gates are low-rank through ``head_dim``).  Every layer has sparse
+    experts (``n_routed_experts``, ``num_experts_per_tok``,
+    ``moe_intermediate_size``, ``n_shared_experts``;
+    ``first_k_dense_replace`` 0).  RMSNorm (``rms_norm_eps``) everywhere, no
+    position signal (``use_rope`` false), no biases, an untied head.
+
+    ``held`` = (first, count): the experts whose weights live here, of the
+    ``n_routed_experts`` the router scores — one chip's share of an
+    expert-parallel deployment (default: all).  ``num_hidden_layers`` and
+    ``vocab_size`` are taken as given, so a configuration cut in depth or to
+    a slice of the vocabulary builds as it reads."""
+    if config.get("use_rope", False):
+        raise ValueError("hybrid_lm builds NoPE attention (use_rope false); "
+                         "this config asks for rotary positions")
+    if not config.get("use_gqa_gate", True):
+        raise ValueError("hybrid_lm builds gated attention (use_gqa_gate)")
+    if int(config.get("first_k_dense_replace", 0)):
+        raise ValueError("hybrid_lm builds sparse experts in every layer "
+                         "(first_k_dense_replace 0); this config asks for "
+                         "dense feed-forward layers first")
+    d = int(config["hidden_size"])
+    eps = float(config["rms_norm_eps"])
+    lin = config["linear_attn_config"]
+    gqa = {int(i) for i in config["gqa_layers"]}
+    experts = int(config["n_routed_experts"])
+    moe_dim = int(config["moe_intermediate_size"])
+    layers = [Embedding(int(config["vocab_size"]), d)]
+    for i in range(int(config["num_hidden_layers"])):
+        if i in gqa:
+            mixer = GatedAttention(int(config["num_attention_heads"]),
+                                   int(config["head_dim"]),
+                                   int(config["num_key_value_heads"]))
+        else:
+            mixer = KimiDeltaAttention(
+                int(lin["num_heads"]), int(lin["head_dim"]),
+                conv_size=int(lin["short_conv_kernel_size"]),
+                gate_rank=int(lin["head_dim"]),
+                neg_eigval=bool(config.get("kda_allow_neg_eigval", False)),
+                norm_eps=eps)
+        ffn = SparseMoE(
+            experts, int(config["num_experts_per_tok"]), moe_dim, held=held,
+            shared_dim=int(config.get("n_shared_experts", 0)) * moe_dim)
+        layers.append(HybridBlock(mixer, ffn, epsilon=eps))
+    layers += [RMSNorm(eps), Dense(int(config["vocab_size"]),
+                                   use_bias=False)]
+    return Sequential(layers, input_shape=(8,), compute_dtype=compute_dtype,
+                      name="hybrid_lm")

@@ -1,0 +1,87 @@
+"""A sparse expert layer's compute outside ``shard_map``: dropless routing
+over ALL experts, and a grouped matmul over the experts held here.
+
+``parallel/moe.py`` routes with a capacity and drops what exceeds it, inside
+an all-to-all; decoding cannot drop a token, and one chip of an
+expert-parallel deployment computes only its own experts' terms.  Here every
+(token, expert) assignment is kept: the assignments to held experts are
+sorted by expert, and ONE grouped matmul over the held experts computes them
+(``group_sizes`` rows a group, no capacity, any skew).  Assignments to experts
+held elsewhere are left out — nothing stands in for the other chips.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import jax
+import jax.numpy as jnp
+
+#: rows of a grouped-matmul tile on the TPU: the sorted assignments are padded
+#: to a whole number of them
+_TILE_M = 128
+
+
+def route(logits, top_k: int):
+    """Softmax over all experts in float32, the ``top_k`` largest, their
+    weights renormalised to sum to 1.  ``logits``: (T, E) float32.  Returns
+    ``(experts (T, K) int32, weights (T, K) float32)``."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, e = jax.lax.top_k(probs, int(top_k))
+    return e.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
+def dispatch(experts, weights, held: Tuple[int, int], token_live=None):
+    """Sort the assignments to held experts by expert.  ``experts``,
+    ``weights``: (T, K); ``held`` = (first, count) of the experts held here;
+    ``token_live``: (T,) bool or None, tokens whose assignments count (a dead
+    slot's junk token is routed nowhere).  Returns ``(token (M,), weight
+    (M,), group_sizes (count,), total)`` with ``M = T * K``: the sorted
+    assignments' tokens and weights — rows at and past ``total`` belong to no
+    held expert and carry weight 0 — and how many rows each held expert
+    got."""
+    first, count = int(held[0]), int(held[1])
+    t, k = experts.shape
+    local = experts - first
+    mine = (local >= 0) & (local < count)
+    if token_live is not None:
+        mine = mine & token_live[:, None]
+    key = jnp.where(mine, local, count).reshape(-1)            # (M,)
+    order = jnp.argsort(key, stable=True)
+    token = (jnp.arange(t * k, dtype=jnp.int32) // k)[order]
+    weight = jnp.where(mine, weights, 0.0).reshape(-1)[order]
+    group_sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    return token, weight, group_sizes, jnp.sum(group_sizes)
+
+
+def _tiling(k: int, n: int):
+    """(tm, tk, tn) of the megablox kernel: weight tiles of a few MB, so a
+    step's DMA outweighs its fixed cost and two of them fit scoped VMEM."""
+    def fit(x):
+        return next((t for t in (1280, 1024, 512, 256) if x % t == 0), 128)
+    return _TILE_M, fit(k), fit(n)
+
+
+def grouped_matmul(x, w, group_sizes, kernel=None):
+    """``x[rows of group g] @ w[g]`` for every group, float32 out.  ``x``:
+    (M, K) sorted by group; ``w``: (G, K, N); ``group_sizes``: (G,) int32
+    summing to at most M (rows past the sum come back 0).  On a TPU this is
+    the Pallas grouped matmul of ``jax.experimental.pallas.ops.tpu.megablox``
+    — it visits (group, row-tile) pairs that hold rows and no others, so an
+    expert no token chose costs no weight read; elsewhere ``lax.ragged_dot``
+    (``kernel``: None asks the backend)."""
+    m = x.shape[0]
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu"
+    if kernel:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        pad = -m % _TILE_M      # whole row tiles; the added rows are past
+        if pad:                 # the groups' sum and are never visited
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+        out = gmm(x, w, group_sizes, jnp.float32,
+                  _tiling(x.shape[1], w.shape[2]))[:m]
+    else:
+        out = jax.lax.ragged_dot(x, w, group_sizes,
+                                 preferred_element_type=jnp.float32)
+    live = jnp.arange(m) < jnp.sum(group_sizes)
+    return jnp.where(live[:, None], out, 0.0)

@@ -936,6 +936,48 @@ class ServingEngine:
                     f"role={role!r} does not compose with spec_draft: the "
                     "draft arena is engine-private and never shipped")
         self.role = role
+        # -- what the model itself shows: a layer with a fixed-size recurrent
+        #    state (state kind "recurrent") keeps a request's past OUTSIDE
+        #    its KV blocks, so everything that moves, shares or rolls a
+        #    request by its blocks alone is refused here, by name
+        self._recurrent = _dec.has_recurrent_state(self.model)
+        blocks = [layer for layer in self.model.layers
+                  if isinstance(layer, _dec._BLOCKS)]
+        self._moe_layers = sum(1 for b in blocks if b.routes_tokens)
+        if self._recurrent:
+            if role != "unified":
+                raise ValueError(
+                    f"role={role!r} on a model with recurrent layers: block "
+                    "transfer ships a request's KV blocks, and its recurrent "
+                    "state is not in them — shipping the per-slot state "
+                    "beside the blocks is not written")
+            if rolling:
+                raise ValueError(
+                    "rolling=True on a model with recurrent layers: a "
+                    "recurrent layer keeps no positions to roll over, and "
+                    "its attention layers carry no window")
+            if spec_draft is not None:
+                raise ValueError(
+                    "spec_draft on a model with recurrent layers: a rejected "
+                    "draft token has already advanced the recurrent state, "
+                    "and rolling it back needs a state snapshot per round, "
+                    "which is not written")
+            if kv_dtype == "int8":
+                raise ValueError(
+                    "kv_dtype='int8' on a model with recurrent layers: the "
+                    "recurrent state is float32 and has no quantised form; "
+                    "an engine half of whose state is int8 is not written")
+            if not paged:
+                raise ValueError(
+                    "a model with recurrent layers needs paged=True: the "
+                    "dense pool's staging and row-commit programs copy keys "
+                    "and values only")
+        if quantize == "int8" and not all(b.int8_weights for b in blocks):
+            raise ValueError(
+                "quantize='int8' on a model with a block the weight "
+                "quantiser does not know: it finds matmul weights by "
+                "TransformerBlock's names, and would leave the other "
+                "blocks as they are without saying so")
         # -- speculation + quantization knobs (all default OFF: the engine
         #    is bit-identical to its pre-speculation self until asked)
         if quantize not in (None, "int8", "bf16"):
@@ -1053,7 +1095,8 @@ class ServingEngine:
                     f"max-length request ({self._blocks_per_slot} blocks "
                     f"of {bs} tokens)")
             self.caches = _dec.init_paged_arena(self.model, self.kv_blocks,
-                                                bs, kv_dtype=kv_dtype)
+                                                bs, kv_dtype=kv_dtype,
+                                                num_slots=self.num_slots)
             if self._draft_model is not None:
                 self.d_caches = _dec.init_paged_arena(
                     self._draft_model, self.kv_blocks, bs,
@@ -1114,7 +1157,8 @@ class ServingEngine:
         self._int_blocked = False
         self._can_preempt = (self.paged and not self.rolling
                              and self._draft_model is None
-                             and self.role == "unified")
+                             and self.role == "unified"
+                             and not self._recurrent)
         self._swap_gather_fn = None
         self._swap_ingest_fn = None
 
@@ -1285,11 +1329,26 @@ class ServingEngine:
             "kv_blocks_resumed": 0, "kv_block_bytes_resumed": 0,
             "preempt_swap_ms": [], "preempt_resume_ms": [],
             "quota_refused": 0, "tenants": {},
+            # hybrid models.  The expert layers' load, read off the decode
+            # step's own token fetch (no second device read), summed over
+            # layers and decode steps: assignments of live rows to experts
+            # held here, held experts that got at least one, the fullest
+            # expert's rows; moe_layer_steps counts the (layer, step) pairs
+            # summed over.  recurrent_slots_cleared: admissions whose slot's
+            # recurrent state was started from zero
+            "moe_assignments_held": 0, "moe_experts_touched": 0,
+            "moe_load_max": 0, "moe_layer_steps": 0,
+            "recurrent_slots_cleared": 0,
         }
         if self.paged:
-            self._pool = _PagedKVPool(self.kv_blocks, self.block_size,
-                                      share=not self.rolling,
-                                      stats=self.stats)
+            # a recurrent layer's state is not in the blocks: a matched
+            # prefix would skip tokens that state has to see, so the radix
+            # index matches and inserts nothing (every prompt is prefilled
+            # whole; prefix_hit_tokens stays 0)
+            self._pool = _PagedKVPool(
+                self.kv_blocks, self.block_size,
+                share=not self.rolling and not self._recurrent,
+                stats=self.stats)
 
         # -- lock-free load snapshot (the routing surface).  A plain dict
         #    republished by REFERENCE assignment from submit/step/drain/
@@ -1382,11 +1441,15 @@ class ServingEngine:
                     ring=rolling):
                 self._decode_attn = "kernel"
 
+            moe = self._moe_layers > 0
+
             def pstep(params, caches, bt, tok, positions, active, temp,
                       topk, topp, keys):
                 pv = _dec.PagedView(bt, page, view, ring=rolling)
-                logits, caches = _dec.decode_step(model, params, caches,
-                                                  tok, positions, paged=pv)
+                aux = [] if moe else None
+                logits, caches = _dec.decode_step(
+                    model, params, caches, tok, positions, paged=pv,
+                    rows=_dec.RowView(live=active), aux=aux)
                 # a retired slot keeps its temp/topk/topp (_build_deact_fn
                 # clears act alone) and its row's token is discarded below:
                 # it reaches the sampler as a greedy row, so a stale
@@ -1396,6 +1459,11 @@ class ServingEngine:
                     topk, topp)
                 out = jnp.where(active, nxt, tok)
                 positions = jnp.where(active, positions + 1, positions)
+                if moe:
+                    # the experts' counters ride behind the tokens in the
+                    # one array the host fetches
+                    return out, caches, positions, jnp.concatenate(
+                        [out, sum(aux)])
                 return out, caches, positions
 
             return jax.jit(pstep, donate_argnums=(1, 4))
@@ -1440,8 +1508,10 @@ class ServingEngine:
                 row = jax.lax.dynamic_slice_in_dim(leaf, src * bs, bs, 0)
                 return jax.lax.dynamic_update_slice_in_dim(leaf, row,
                                                            dst * bs, 0)
-            return [None if c is None else {k: cp(v) for k, v in c.items()}
-                    for c in caches]
+            # blocks are keys and values: a recurrent layer's per-slot
+            # state has none to copy
+            return [c if c is None or "k" not in c
+                    else {k: cp(v) for k, v in c.items()} for c in caches]
 
         if self._draft_model is None:
             return jax.jit(copy_one, donate_argnums=(0,))
@@ -1714,8 +1784,9 @@ class ServingEngine:
             if not rolling:
                 pv = _dec.PagedView(row_bt, page, t_view, floor=match,
                                     ceil=p_lens, qcap=p_lens - 1)
-                logits, pool = _dec._forward(model, params, pool, prompts,
-                                             match, paged=pv)
+                logits, pool = _dec._forward(
+                    model, params, pool, prompts, match, paged=pv,
+                    rows=_dec.RowView(slots=slots))
                 idx = jnp.clip(p_lens - match - 1, 0, width - 1)
                 last = jnp.take_along_axis(logits, idx[:, None, None],
                                            axis=1)[:, 0]
@@ -1808,11 +1879,15 @@ class ServingEngine:
         model, draft = self.model, self._draft_model
         page, t_view, d_view = self.block_size, self._t_view, self.max_len
 
-        def stage(params, pool, toks, offset, p_len, row_bt):
+        def stage(params, pool, toks, offset, p_len, row_bt, slot):
             pv = _dec.PagedView(row_bt, page, t_view, floor=offset,
                                 ceil=p_len, qcap=p_len - 1)
-            _, pool = _dec._forward(model, params, pool, toks, offset,
-                                    paged=pv)
+            # per-slot (recurrent) state is carried from unit to unit in
+            # the slot itself: the decode step leaves a prefilling slot's
+            # state alone (its row is not live)
+            _, pool = _dec._forward(
+                model, params, pool, toks, offset, paged=pv,
+                rows=_dec.RowView(slots=jnp.reshape(slot, (1,))))
             return pool
 
         if draft is None:
@@ -1846,8 +1921,9 @@ class ServingEngine:
                   r_key):
             pv = _dec.PagedView(row_bt, page, t_view, floor=offset,
                                 ceil=p_len, qcap=p_len - 1)
-            logits, pool = _dec._forward(model, params, pool, toks, offset,
-                                         paged=pv)
+            logits, pool = _dec._forward(
+                model, params, pool, toks, offset, paged=pv,
+                rows=_dec.RowView(slots=jnp.reshape(slot, (1,))))
             first = _dec.sample_logits_batched(
                 logits[0, last_idx][None], p_len - 1, r_temp, r_key,
                 r_topk, r_topp)
@@ -2908,6 +2984,7 @@ class ServingEngine:
                     self._mirror_admit(slot, h)
                     self.stats["prefills"] += 1
                     self.stats["slot_requests"][slot] += 1
+                    self.stats["recurrent_slots_cleared"] += self._recurrent
                     self.stats["prefill_tokens"] += p - m
                     entries.append((slot, h))
                 if self.paged:
@@ -2967,6 +3044,7 @@ class ServingEngine:
         self._prefilling[slot] = job
         self.stats["prefills"] += 1
         self.stats["slot_requests"][slot] += 1
+        self.stats["recurrent_slots_cleared"] += self._recurrent
         job.hit = job.written
         self._advance_chunk(slot)
 
@@ -3006,7 +3084,7 @@ class ServingEngine:
                     else:
                         self.caches = self._stage_fn(width)(
                             self.params, self.caches, toks_d, off_vec,
-                            plen_vec, job.bt)
+                            plen_vec, job.bt, slot)
                 elif self._draft_model is not None:
                     job.staging, job.d_staging = self._stage_fn(width)(
                         self.params, self._draft_params, job.staging,
@@ -3432,7 +3510,8 @@ class ServingEngine:
         self.stats["sampler_draw_steps"] += sample != "greedy"
         self.stats["sampler_filter_steps"] += sample == "filter"
         with span("serve.decode_dispatch", active=len(entries), step=step,
-                  attn=self._decode_attn, sample=sample):
+                  attn=self._decode_attn, sample=sample,
+                  state="kv+recurrent" if self._recurrent else "kv"):
             if self._draft_model is not None:
                 # speculative round: k draft steps + one batched verify in
                 # ONE program; rows commit 1..spec_len+1 tokens each, packed
@@ -3446,11 +3525,14 @@ class ServingEngine:
                 self.stats["active_slot_steps"] += len(entries)
                 self._pending.append(("spec", out, entries, step))
                 return
-            out, self.caches, self._dev_pos = self._decode_fn(
+            out, self.caches, self._dev_pos, *packed = self._decode_fn(
                 self.params, *self._state_args())
             self._dev_tok = out
             self.stats["active_slot_steps"] += len(entries)
-            self._pending.append(("decode", out, entries, step))
+            # a model with expert layers hands back the tokens with its
+            # counters behind them: still one fetch a step
+            self._pending.append(("decode", packed[0] if packed else out,
+                                  entries, step))
 
     def _drain_pending(self, flush: bool = False) -> bool:
         """Emit the tokens of in-flight steps older than the lookahead
@@ -3467,6 +3549,12 @@ class ServingEngine:
                 vals = self._fetch(arr)
             with span("serve.emit", kind=kind, rows=len(entries),
                       step=step):
+                if kind == "decode" and len(vals) > self.num_slots:
+                    held, touched, fullest = vals[self.num_slots:]
+                    self.stats["moe_assignments_held"] += int(held)
+                    self.stats["moe_experts_touched"] += int(touched)
+                    self.stats["moe_load_max"] += int(fullest)
+                    self.stats["moe_layer_steps"] += self._moe_layers
                 for i, (slot, h) in enumerate(entries):
                     if h.finish is not None or self._handles[slot] is not h:
                         continue
@@ -3813,7 +3901,7 @@ class ServingEngine:
                 jax.block_until_ready(self._dev_tok)
         else:
             with program("decode"):
-                out, self.caches, self._dev_pos = self._decode_fn(
+                out, self.caches, self._dev_pos, *_ = self._decode_fn(
                     self.params, *self._state_args())
                 self._dev_tok = out
                 jax.block_until_ready(out)
@@ -3914,7 +4002,7 @@ class ServingEngine:
                         else:
                             self.caches = self._stage_fn(width)(
                                 self.params, self.caches, toks, off, plen,
-                                bt1)
+                                bt1, self.num_slots)
                             self._apply_state(self._final_fn(width)(
                                 *self._prog_args(), toks, self.num_slots,
                                 off, plen, 0, bt1, *one))

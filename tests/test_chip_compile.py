@@ -175,6 +175,65 @@ def test_paged_decode_compiles_for_v5e(one_chip, shape, dtype):
                 if " copy(" in line and arena in line.split(" copy(")[0]]
 
 
+# -- the hybrid serving cell's kernels at its published shapes ---------------
+
+def test_paged_decode_compiles_for_v5e_at_the_gqa_shape(one_chip):
+    """serve-reason-solar2's attention layer: 64 query heads over 8 KV heads
+    of 128, 128 slots, a 262,144-token pool, views of 4,096."""
+    b, h, hkv, dh, blocks, page, view = 128, 64, 8, 128, 16384, 16, 4096
+    slots, f, cols = (blocks + 1) * page, hkv * dh, view // page + 1
+
+    def step(ka, va, q, tables, lengths):
+        return paged_decode_attention(q, ka, va, tables, lengths, page,
+                                      interpret=False)
+
+    S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    bf = jnp.bfloat16
+    text = jax.jit(step).lower(
+        S((slots, f), bf), S((slots, f), bf), S((b, h, dh), bf),
+        S((b, cols), jnp.int32), S((b,), jnp.int32)).compile().as_text()
+    assert text.count(KERNEL) == 1
+
+
+def test_kda_decode_compiles_for_v5e(one_chip):
+    """The fused decode step of a KDA layer over the cell's 128 slots of
+    64 x 128 x 128 float32 state: one kernel, the donated state updated in
+    place (no copy of it anywhere in the program)."""
+    from distkeras_tpu.ops.kda import kda_decode, kernel_tiles
+    b, h, d = 128, 64, 128
+    assert kernel_tiles((b, h, d, d), jnp.float32)
+
+    def step(q, k, v, g, beta, state, live):
+        return kda_decode(q, k, v, g, beta, state, live, interpret=False)
+
+    S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    f32 = jnp.float32
+    vec = S((b, h, d), f32)
+    text = jax.jit(step, donate_argnums=(5,)).lower(
+        vec, vec, vec, vec, S((b, h), f32), S((b, h, d, d), f32),
+        S((b,), jnp.bool_)).compile().as_text()
+    assert text.count(KERNEL) == 1
+    state = f"[{b},{h},{d},{d}]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and state in line.split(" copy(")[0]]
+
+
+@pytest.mark.parametrize("m,k,n", [(1024, 4096, 2560), (1024, 1280, 4096),
+                                   (2048, 4096, 2560), (2048, 1280, 4096)],
+                         ids=lambda x: str(x))
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n):
+    """The experts' grouped matmuls (gate/up, down) over the 40 held experts
+    at the published widths: a decode step's 128 x 8 assignments and a
+    prefill unit's 256 x 8."""
+    from distkeras_tpu.ops.experts import grouped_matmul
+    S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    bf = jnp.bfloat16
+    text = jax.jit(functools.partial(grouped_matmul, kernel=True)).lower(
+        S((m, k), bf), S((40, k, n), bf), S((40,), jnp.int32)
+    ).compile().as_text()
+    assert text.count(KERNEL) == 1
+
+
 # -- kernels inside shard_map on the 2x2 mesh --------------------------------
 
 @pytest.fixture(scope="module")

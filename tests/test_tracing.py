@@ -135,7 +135,7 @@ SERVE_SPANS = {
     "serve.schedule": (),
     "serve.admit": ("rid",),
     "serve.prefill_unit": ("rid", "tokens", "kind", "width", "hit"),
-    "serve.decode_dispatch": ("active", "step", "attn", "sample"),
+    "serve.decode_dispatch": ("active", "step", "attn", "sample", "state"),
     "serve.fetch": ("step",),
     "serve.emit": ("kind", "rows", "step"),
     "serve.retire": ("rid", "reason"),
@@ -332,21 +332,30 @@ def test_scopes_leave_the_arithmetic_as_it_was(program, monkeypatch):
 
 @pytest.fixture(scope="module")
 def kernels_lowered():
-    """Flash attention and the fused CE, forward and backward, lowered for
-    a TPU once."""
+    """Flash attention and the fused CE, forward and backward, and the
+    recurrent layers' fused decode step, lowered for a TPU once."""
     from distkeras_tpu.ops.flash_attention import flash_attention
     from distkeras_tpu.ops.fused_ce import fused_softmax_cross_entropy
+    from distkeras_tpu.ops.kda import kda_decode
 
     def both(q, logits, labels):
         attn = flash_attention(q, q, q, causal=True, interpret=False)
         ce = fused_softmax_cross_entropy(logits, labels, interpret=False)
         return attn.astype(jnp.float32).sum() + ce.sum()
 
+    def step(vec, state):
+        return kda_decode(vec, vec, vec, vec, vec[..., 0], state,
+                          jnp.ones((1,), bool), interpret=False)
+
     q = jax.ShapeDtypeStruct((2, 128, 2, 64), jnp.bfloat16)
     logits = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     labels = jax.ShapeDtypeStruct((256,), jnp.int32)
-    return jax.jit(jax.grad(both, argnums=(0, 1))).trace(
+    vec = jax.ShapeDtypeStruct((1, 8, 128), jnp.float32)
+    state = jax.ShapeDtypeStruct((1, 8, 128, 128), jnp.float32)
+    return (jax.jit(jax.grad(both, argnums=(0, 1))).trace(
         q, logits, labels).lower(lowering_platforms=("tpu",)).as_text()
+        + jax.jit(step).trace(vec, state).lower(
+            lowering_platforms=("tpu",)).as_text())
 
 
 # paged_decode has the test below: its program is the engine's decode step
