@@ -74,7 +74,10 @@ TINY = dict(
 #: holds them to it here, ``tests/test_tracing.py`` in tier 1 (the package
 #: itself is imported only after ``build_native``, so not up here).
 REQUIRED_KERNELS = {
-    "SingleTrainer step": ("flash_fwd", "flash_dq", "flash_dkv"),
+    # 8 x 1,024 x 50,257 logits: the kernel's side of the loss's rule
+    # (``core.losses.fused_ce_applies``)
+    "SingleTrainer step": ("flash_fwd", "flash_dq", "flash_dkv",
+                           "fused_ce_fwd", "fused_ce_bwd"),
     "ParallelTransformerLM step": ("fused_ce_fwd", "fused_ce_bwd"),
     "long-context forward": ("flash_fwd",),
     "2x1x2 step": ("fused_ce_fwd", "fused_ce_bwd"),

@@ -184,14 +184,21 @@ class Dense(Layer):
     def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
               rng=None):
         k = params["kernel"].astype(compute_dtype)
+        # rows flattened up to the result, so that it is born (rows, units)
+        # row-major: a (batch, seq, units) dot_general comes out of the
+        # TPU compiler sequence-minor, and a consumer that needs rows of
+        # units (the fused cross-entropy over an LM head's logits) then
+        # pays a relayout copy of the whole result.  The bias joins before
+        # the reshape: after it XLA folds the reshape back into the product
         y = jax.lax.dot_general(
-            x.astype(compute_dtype), k,
-            (((x.ndim - 1,), (0,)), ((), ())),
+            x.astype(compute_dtype).reshape(-1, x.shape[-1]), k,
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         if self.use_bias:
             y = y + params["bias"]
-        return _apply_activation(self.activation, y)
+        return _apply_activation(self.activation, y).reshape(
+            x.shape[:-1] + (self.units,))
 
 
 def _conv_f32_acc(x, k, strides, padding):

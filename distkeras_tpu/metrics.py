@@ -82,7 +82,10 @@ over; ``recurrent_slots_cleared``, admissions whose slot's recurrent state
 was started from zero.  The benchmark's ``.hybrid`` readers read them.
 
 Training (``DistributedTrainer.train``, epoch and per-round paths):
-``train.epoch`` (``epoch``) with children ``train.shuffle``,
+``train.epoch`` (``epoch``; ``ce`` = ``kernel``/``xla``: whether the step's
+sparse cross-entropy runs in the ``fused_ce_*`` kernels, by
+``core.losses.fused_ce_applies`` on what the model hands the loss) with
+children ``train.shuffle``,
 ``train.shape`` (``shape_epoch_data``), ``train.dispatch`` (host→device
 transfer and launch of ``run_epoch``/``run_round``: ``rounds``),
 ``train.fetch`` (the host waits for the device's losses), ``train.log``,

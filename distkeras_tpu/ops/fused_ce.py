@@ -23,8 +23,12 @@ write, and nothing (T, V)-shaped is ever written:
    extent are masked to -inf / zero contribution, so any (T, V) shape works
    without host-side padding copies.
 
+Every trainer reaches it through ``core.losses`` (the sparse
+cross-entropies from logits, by ``fused_ce_applies``' rule on shape, dtype
+and backend); ``ParallelTransformerLM(fused_ce=True)`` calls it directly.
+
 On non-TPU backends the kernel runs in Pallas interpret mode (tests); the
-XLA path (``core.losses.sparse_categorical_crossentropy`` on log_softmax)
+XLA path (``log_softmax`` + ``take_along_axis``, ``core.losses``' other side)
 stays the correctness oracle — value/grad parity asserted in
 tests/test_fused_ce.py.  No reference counterpart (the reference's losses
 are whole-array Keras ops; SURVEY.md §2.1 row 21) — this exists because a
@@ -34,7 +38,7 @@ TPU-first LM stack is HBM-bound exactly here.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +49,25 @@ from ._vma import _vma_of, out_struct
 
 NEG_INF = float("-inf")
 _LANES = 128
+
+# the largest block (``_tiles``) and what the kernels may ask of VMEM for it:
+# 256 x 2,048 of f32 is 2 MiB, so the backward's double-buffered blocks
+# (logits in, ``dlogits`` out) and its f32 temporaries (``s``, ``p``, the
+# column ids, the product) come to 16 MiB, over the compiler's default
+_MAX_T, _MAX_V = 256, 2048
+VMEM_LIMIT = 32 << 20
+
+
+def _tiles(t: int, v: int) -> Tuple[int, int]:
+    """(block_t, block_v) for a call on ``(t, v)`` logits: up to ``_MAX_T``
+    rows by the widest vocab block of whole lane tiles up to ``_MAX_V``
+    (the stream's direction: a grid step costs a third of a microsecond
+    whatever it moves).  The head and loss of ``train-adag-gpt2s`` read
+    19.6 ms at 256 x 2,048 against 20.9 at 256 x 512 (PERF.md section 6,
+    PR 33).  A short or narrow operand is one block along that axis;
+    ragged edges are masked in the kernels, so nothing has to divide."""
+    return (min(t, _MAX_T),
+            v if v <= _LANES else min(_MAX_V, v // _LANES * _LANES))
 
 
 def _col_ids(v0, bt, bv):
@@ -123,6 +146,14 @@ def _specs(bt, bv):
     )
 
 
+def _params(vocab_axis: str):
+    """Token blocks are independent; the forward carries its running
+    statistics along the vocabulary."""
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", vocab_axis),
+        vmem_limit_bytes=VMEM_LIMIT)
+
+
 def _fwd_call(logits, labels, block_t, block_v, interpret):
     t, v = logits.shape
     bt = min(block_t, t)
@@ -140,6 +171,7 @@ def _fwd_call(logits, labels, block_t, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((bt, _LANES), jnp.float32),
                         pltpu.VMEM((bt, _LANES), jnp.float32),
                         pltpu.VMEM((bt, _LANES), jnp.float32)],
+        compiler_params=_params("arbitrary"),
         interpret=interpret,
         name="fused_ce_fwd",
     )(logits, labels.reshape(t, 1).astype(jnp.int32))
@@ -152,8 +184,9 @@ def _fused_ce(logits, labels, block_t: int, block_v: int, interpret: bool):
     return loss
 
 
-def fused_softmax_cross_entropy(logits, labels, block_t: int = 256,
-                                block_v: int = 512,
+def fused_softmax_cross_entropy(logits, labels,
+                                block_t: Optional[int] = None,
+                                block_v: Optional[int] = None,
                                 interpret: Optional[bool] = None):
     """Per-token ``-log_softmax(logits)[label]`` without materializing the
     (T, V) log-probability matrix.
@@ -161,7 +194,8 @@ def fused_softmax_cross_entropy(logits, labels, block_t: int = 256,
     logits: (T, V) any float dtype; labels: (T,) integer class ids.
     Returns (T,) f32 losses — sum/mean (and psum, under shard_map) are the
     caller's.  Differentiable wrt ``logits`` (grad streams block-wise from
-    an O(T) logsumexp residual, written in the logits dtype).
+    an O(T) logsumexp residual, written in the logits dtype).  The blocks
+    are chosen from the shape (``_tiles``) unless given.
 
     Under shard_map on a non-TPU backend the call falls back to the XLA
     math: interpret-mode kernels inline into the traced program, where the
@@ -174,7 +208,8 @@ def fused_softmax_cross_entropy(logits, labels, block_t: int = 256,
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         return -jnp.take_along_axis(
             logp, labels.astype(jnp.int32)[:, None], axis=-1)[:, 0]
-    return _fused_ce(logits, labels, block_t, block_v, interpret)
+    bt, bv = _tiles(*logits.shape)
+    return _fused_ce(logits, labels, block_t or bt, block_v or bv, interpret)
 
 
 def _ce_fwd(logits, labels, block_t, block_v, interpret):
@@ -197,6 +232,7 @@ def _ce_bwd(block_t, block_v, interpret, res, g):
         grid=(pl.cdiv(t, bt), pl.cdiv(v, bv)),
         in_specs=[sp["logits"], sp["rows"], sp["lanes"], sp["lanes"]],
         out_specs=sp["logits"],
+        compiler_params=_params("parallel"),
         interpret=interpret,
         name="fused_ce_bwd",
     )(logits, labels.reshape(t, 1).astype(jnp.int32), lse_b, ct)

@@ -523,9 +523,15 @@ class DistributedTrainer(Trainer):
                                num_chips=self.num_workers)
         self.metrics = metrics.logger.events
         rngs = engine.worker_rngs(self.seed + 17)
+        # where the step's cross-entropy runs (kernel / xla), asked of the
+        # loss's own predicate on what the model hands it
+        from .core.losses import ce_path
+        ce = ce_path(engine.loss_fn, jax.eval_shape(
+            engine.model.apply, self._state.center, jax.ShapeDtypeStruct(
+                (self.batch_size,) + x.shape[1:], x.dtype)))
         try:
             for epoch in range(start_epoch, self.num_epoch):
-                with span("train.epoch", epoch=epoch):
+                with span("train.epoch", epoch=epoch, ce=ce):
                     t0 = time.time()
                     with span("train.shuffle"):
                         if shuffle:

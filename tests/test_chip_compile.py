@@ -134,6 +134,75 @@ def test_fused_ce_compiles_for_v5e(one_chip, shape, dtype, mode):
     assert KERNEL in text
 
 
+def test_trainers_step_at_the_cells_widths_holds_the_ce_kernels(
+        topo, monkeypatch):
+    """``train-adag-gpt2s``'s epoch program (ADAG on the SPMD engine, one
+    worker, 8 x 1,024 tokens, vocabulary 50,257; ONE block for the
+    compile's sake, 10 s) for the described v5e: the loss is the two
+    kernels; nothing logits-shaped is scattered into, sliced, or copied
+    between layouts (the head's product is born ``(8192, 50257)``
+    row-major: ``Dense.apply`` flattens its rows); no loop but the two
+    scans.  The parent's program had a ``while`` of 8,192 scalar updates
+    into a zero-filled ``f32[8,1024,50257]`` here (PERF.md section 6,
+    PR 33)."""
+    import re
+    from distkeras_tpu.core import optimizers as opt_lib
+    from distkeras_tpu.models import transformer_lm
+    from distkeras_tpu.parallel.spmd import (DistState, SPMDEngine,
+                                             WORKER_AXIS)
+    # the dispatch rules ask the backend (the CPU here); the program is
+    # compiled for the chip, so they are given the chip's answer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    batch, seq, vocab, window, rounds = 8, 1024, 50257, 12, 2
+    mesh = Mesh(np.array(topo.devices[:1]), (WORKER_AXIS,))
+    model = transformer_lm(vocab_size=vocab, seq_len=seq, d_model=768,
+                           num_heads=12, num_layers=1, mlp_dim=3072,
+                           compute_dtype="bfloat16")
+    eng = SPMDEngine(model, "sparse_categorical_crossentropy_from_logits",
+                     "adam", mesh, "adag", communication_window=window,
+                     learning_rate=1e-3)
+    params = jax.eval_shape(lambda k: model.init(k, (seq,)),
+                            jax.random.PRNGKey(0))
+    eng.tx = opt_lib.build_tx(eng.optimizer, params)
+    rep = NamedSharding(mesh, P())
+    per_worker = NamedSharding(mesh, P(WORKER_AXIS))
+    columns = NamedSharding(mesh, P(None, None, WORKER_AXIS))
+    stacked = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype,
+                                       sharding=per_worker), tree)
+    state = DistState(
+        jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), params),
+        stacked(params), stacked(jax.eval_shape(eng.tx.init, params)),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=rep))
+    tokens = jax.ShapeDtypeStruct((rounds, window, 1, batch, seq),
+                                  jnp.int32, sharding=columns)
+    mask = jax.ShapeDtypeStruct((rounds, window, 1, batch), jnp.float32,
+                                sharding=columns)
+    keys = jax.ShapeDtypeStruct((1, 2), jnp.uint32, sharding=per_worker)
+    text = eng._build_epoch_fn().lower(state, tokens, tokens, mask,
+                                       keys).compile().as_text()
+
+    calls = lambda name: len(re.findall(rf"%{name}[.\d]* = ", text))
+    assert calls("fused_ce_fwd") == 1 and calls("fused_ce_bwd") == 1
+    assert calls("flash_fwd") == calls("flash_dq") == calls("flash_dkv") == 1
+    assert len(re.findall(r" while\(", text)) == 2  # rounds, window
+    logits = re.compile(rf"f32\[({batch},{seq}|{batch * seq}),{vocab}\]")
+    moved = [line.split(" = ")[0].strip() for line in text.splitlines()
+             if re.search(r"\b(copy|scatter|dynamic-update-slice|"
+                          r"dynamic-slice)\(", line)
+             and logits.search(line.split("(")[0])]
+    assert moved == [], (
+        f"{moved}: the logits are moved on their way to or from the loss's "
+        "kernels.  The invariant: Dense.apply flattens its rows BEFORE the "
+        "product and adds the bias BEFORE reshaping back, so that the head's "
+        "result is born f32[8192,50257] row-major; with the bias after the "
+        "reshape XLA folds the reshape into the product, which then comes "
+        "out sequence-minor, and a 1.65-GB relayout copy stands before "
+        "fused_ce_fwd (core/layers.py; PERF.md section 6, PR 33)")
+    assert not re.search(r"scatter\(.*op_name=\"[^\"]*loss", text)
+
+
 # -- paged decode attention --------------------------------------------------
 
 PAGED_SHAPES = [  # slots B, heads H, kv heads, Dh, blocks, page; dtype

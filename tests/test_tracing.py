@@ -237,7 +237,9 @@ def test_an_epoch_holds_its_phases_in_order(train_trace, last):
     spans = train_trace[0]
     epochs = [s for s in spans if s.name == "train.epoch"]
     # two trainers: one with a checkpoint directory, one with validation
-    assert [e.fields for e in epochs] == [{"epoch": 0}, {"epoch": 1}] * 2
+    # ten classes: the loss's rule says XLA on any backend
+    assert [e.fields for e in epochs] == [{"epoch": 0, "ce": "xla"},
+                                          {"epoch": 1, "ce": "xla"}] * 2
     e = epochs[-1 if last else 1]
     inside = [s.name for s in spans if s.thread == e.thread
               and s is not e and e.start <= s.start and s.end <= e.end]
@@ -247,6 +249,46 @@ def test_an_epoch_holds_its_phases_in_order(train_trace, last):
     dispatch, = (s for s in spans if s.name == "train.dispatch"
                  and e.start <= s.start and s.end <= e.end)
     assert dispatch.fields == {"rounds": 2}
+
+
+@pytest.mark.parametrize("model,tpu,want", [
+    ("lm", True, "kernel"), ("lm", False, "xla"), ("mlp", True, "xla")])
+def test_the_epoch_span_says_where_the_cross_entropy_runs(monkeypatch, model,
+                                                          tpu, want):
+    """``ce`` on ``train.epoch`` is the loss's own predicate on what the
+    model hands it: ``kernel`` for an LM's (4, 128, 1024) logits on a TPU,
+    ``xla`` off it and for ten classes.  No session: the trainer's ``span``
+    is replaced by a recorder (the field's way through a real session is
+    the ``train_trace`` fixture's, above)."""
+    from distkeras_tpu import trainers
+    from distkeras_tpu.core import losses
+    seen = []
+
+    @contextlib.contextmanager
+    def recorder(name, **fields):
+        seen.append((name, fields))
+        yield
+
+    monkeypatch.setattr(trainers, "span", recorder)
+    monkeypatch.setattr(losses, "_on_tpu", lambda: tpu)
+    rng = np.random.default_rng(0)
+    if model == "lm":
+        x = rng.integers(0, 1024, (8, 128)).astype(np.int32)
+        net = transformer_lm(vocab_size=1024, seq_len=128, d_model=32,
+                             num_heads=2, num_layers=1, mlp_dim=64,
+                             compute_dtype="float32")
+        data = Dataset({"features": x, "label": (x + 1) % 1024})
+    else:
+        net = mnist_mlp("float32")
+        data = Dataset({
+            "features": rng.normal(size=(8, 784)).astype(np.float32),
+            "label": rng.integers(0, 10, 8).astype(np.int32)})
+    ADAG(net, num_workers=1, batch_size=4, num_epoch=1,
+         communication_window=2, worker_optimizer="adam",
+         loss="sparse_categorical_crossentropy" + (
+             "_from_logits" if model == "lm" else "")).train(data)
+    epochs = [f for n, f in seen if n == "train.epoch"]
+    assert epochs == [{"epoch": 0, "ce": want}]
 
 
 def test_served_tokens_are_the_same_with_and_without_a_session(serve_trace):
