@@ -1,0 +1,98 @@
+"""Driver of kind ``serve_nemotronh``: ``ServingEngine`` serving a model whose
+layers are ONE part each (Mamba-2 state-space mixers, attention, sparse
+experts), built through ``lib/program_nemotronh.py``.
+
+The window is ``serve_hybrid``'s own (set-up, lead-in, window, drain, the
+records the readers read): that driver is loaded a second time, as a module of
+this driver's own, and given another ``build_engine``, another ``score`` (the
+reference of ``correct`` is ``lib/reference_nemotronh.py``) and the engine's
+counters of the experts over PREFILL units beside the decode steps'.  Nothing
+of the window is written again here.  ``tools/read_limits_solar.py`` and
+``tools/sweep_serve.py`` take this kind as they take that one
+(``serve_window``, ``score``, ``build_engine``, ``Tracked``, ``offer_open``,
+``wait_all``).
+
+What is added: two lines of the run's log that the cell's table in PERF.md
+holds (how many requests the window held, and the share of the window's decode
+steps that shared their iteration with a prefill unit), and ``ttft_p95_ms``
+among them, printed whether or not the manifest lists it.
+"""
+
+from __future__ import annotations
+
+from typing import Dict
+
+from benchmarks.lib import harness, manifest as mf, program_nemotronh
+
+_sh = mf.load_driver("serve_hybrid")        # this driver's own copy
+Tracked, offer_open, wait_all = _sh.Tracked, _sh.offer_open, _sh.wait_all
+pick_sample, Window = _sh.pick_sample, _sh.Window
+
+
+def build_engine(ctx: harness.RunContext):
+    return program_nemotronh.build_engine(ctx.cfg, ctx.seed)
+
+
+def score(ctx: harness.RunContext, served) -> Dict[str, Dict[str, float]]:
+    """``serve_hybrid.score`` over ``reference_nemotronh``: each sampled
+    request once with int8 matmuls (its own first tokens: the yardstick and
+    the control) and once as it is, scoring the served tokens and the
+    control's at every served position.  Rows are padded to multiples of
+    ``correct.pad_to`` so that the reference compiles few programs."""
+    from benchmarks.lib import reference_nemotronh as ref
+    from benchmarks.lib.counts_nemotronh import dims
+    from benchmarks.lib.weights_nemotronh import make_weights
+    cfg = ctx.cfg
+    w = make_weights(cfg, ctx.seed, cfg["precision"]["params"])
+    d = dims(cfg)
+    step = int(ctx.traffic["correct"]["pad_to"])
+    names = ["program", "int8"]
+    gaps: Dict[str, list] = {n: [] for n in names}
+    for prompt, toks in served:
+        pad = ref.pad_length(len(prompt) + len(toks), step)
+        cands = [toks, ref.served_position_scores(
+            w, prompt, toks, [], d, pad, mm=ref.int8_matmul)[1]]
+        both, _ = ref.served_position_scores(w, prompt, toks, cands, d, pad)
+        for n, g in zip(names, both):
+            gaps[n].append(g)
+    out = {n: _sh._numbers(g) for n, g in gaps.items()}
+    yard = out["int8"]["served_token_mean_gap"]
+    for nums in out.values():
+        mean = nums["served_token_mean_gap"]
+        nums["served_mean_gap_vs_int8"] = (
+            mean / yard if yard > 0 else 0.0 if mean == 0 else float("inf"))
+    return out
+
+
+_sh.build_engine = build_engine
+_sh.score = score
+_sh._COUNTERS = _sh._COUNTERS + (
+    "moe_prefill_assignments_held", "moe_prefill_experts_touched",
+    "moe_prefill_layer_units")
+check, precision_below_stated = _sh.check, _sh.precision_below_stated
+
+
+def serve_window(ctx: harness.RunContext) -> Window:
+    w = _sh.serve_window(ctx)
+    c = w.records["window_counters"]
+    units = c["prefill_chunks"] + c["prefill_batches"]
+    ctx.log(driver="serve_nemotronh", window_requests=w.attempted,
+            prefill_units=units, decode_steps=w.records["decode_steps"],
+            # an iteration spends at most one prefill unit
+            # (prefills_per_step 1) before its decode step: the share of the
+            # window's row-gaps that carry one
+            gaps_with_prefill_unit_pct=100.0 * units
+            / max(w.records["decode_steps"], 1),
+            prefill_tokens=c["prefill_tokens"],
+            moe_prefill_rows_per_touched_expert=(
+                c["moe_prefill_assignments_held"]
+                / max(c["moe_prefill_experts_touched"], 1)))
+    return w
+
+
+def run(ctx: harness.RunContext) -> harness.RunResult:
+    w = serve_window(ctx)
+    return harness.RunResult(
+        compared=check(ctx, w.served, w.below_stated), attempted=w.attempted,
+        failed=w.failed, end_to_end=w.end_to_end, records=w.records,
+        memory_peak_bytes=w.memory_peak_bytes, trace_path=w.trace_path)

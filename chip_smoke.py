@@ -81,6 +81,8 @@ REQUIRED_KERNELS = {
     "ParallelTransformerLM step": ("fused_ce_fwd", "fused_ce_bwd"),
     "long-context forward": ("flash_fwd",),
     "2x1x2 step": ("fused_ce_fwd", "fused_ce_bwd"),
+    # a Mamba-2 layer's state in the paged pool's decode step
+    "state-space decode step": ("ssd_decode",),
 }
 
 
@@ -789,6 +791,91 @@ def parallel_lm_4(cfg, seed, on_tpu):
 
 # ---------------------------------------------------------------------------
 
+def state_space(cfg, seed, on_tpu):
+    """A stack of one-part layers (Mamba-2, experts, attention) through the
+    paged engine: the recurrence's three forms agree on the device, the
+    decode program holds the ``ssd_decode`` kernel, and what the engine
+    serves (a bucket prompt and a chunked one, slots reused) is what the
+    model's own full forward puts first."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distkeras_tpu.core.model import FittedModel
+    from distkeras_tpu.models import hybrid_lm
+    from distkeras_tpu.ops import ssd
+    from distkeras_tpu.serving import ServingEngine
+
+    rng = np.random.default_rng(seed)
+    nrm = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    b, length, h, p, g, n = 4, 200, 8, 64, 2, 128
+    x, bm, cm = nrm(b, length, h, p), nrm(b, length, g, n), nrm(b, length,
+                                                                g, n)
+    dt = jax.nn.softplus(nrm(b, length, h) - 2.0)
+    a = -jnp.exp(jnp.asarray(rng.uniform(0.0, 2.5, h), jnp.float32))
+    s0 = nrm(b, h, p, n)
+
+    def token(state, i):
+        y, state = ssd.ssd_step(x[:, i], dt[:, i], a, bm[:, i], cm[:, i],
+                                state)
+        return state, y
+    s_ref, y_ref = jax.lax.scan(token, s0, jnp.arange(length))
+    y, s = ssd.ssd_chunk(x, dt, a, bm, cm, s0)
+    scale = float(jnp.abs(y_ref).max())
+    err = float(jnp.abs(y - jnp.moveaxis(y_ref, 0, 1)).max()) / scale
+    check(err < 1e-3, f"ssd_chunk vs the recurrence: {err} of the largest")
+    live = jnp.asarray([True, False, True, True])
+    yk, sk = ssd.ssd_decode(x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
+                            s0 + 0, live)
+    ys, ss = ssd.ssd_step(x[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0], s0)
+    kerr = float(jnp.abs(jnp.where(live[:, None, None], yk - ys, yk)).max())
+    check(kerr < 1e-3 * float(jnp.abs(ys).max())
+          and bool((sk[1] == s0[1]).all()),
+          f"ssd_decode vs ssd_step: {kerr}; dead slot touched: "
+          f"{not bool((sk[1] == s0[1]).all())}")
+
+    config = dict(
+        hybrid_override_pattern="ME*ME", num_hidden_layers=5,
+        hidden_size=256, vocab_size=512, layer_norm_epsilon=1e-5,
+        mamba_num_heads=8, mamba_head_dim=64, ssm_state_size=128,
+        n_groups=2, conv_kernel=4, chunk_size=64,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=64,
+        n_routed_experts=8, num_experts_per_tok=2, moe_intermediate_size=128,
+        n_shared_experts=1, moe_shared_expert_intermediate_size=256,
+        routed_scaling_factor=2.5)
+    model = hybrid_lm(config, compute_dtype="float32", held=(0, 4))
+    params = model.init(jax.random.PRNGKey(seed), (8,))
+    eng = ServingEngine(FittedModel(model, params), num_slots=2, max_len=256,
+                        paged=True, block_size=16, kv_blocks=64,
+                        prefill_chunk=64)
+    found = require_kernels(
+        kernel_names(eng._decode_fn.lower(
+            eng.params, *eng._state_args()).as_text()),
+        "state-space decode step", on_tpu)
+    prompts = [rng.integers(0, 512, k).astype(np.int32)
+               for k in (20, 150, 64, 7)]
+    handles = [eng.submit(q, cfg["new_tokens"]) for q in prompts]
+    eng.run_until_idle()
+    worst = 0.0
+    for q, hd in zip(prompts, handles):
+        check(hd.finish == "length", f"request ended {hd.finish}")
+        toks = np.asarray(hd.tokens, np.int32)
+        row = np.concatenate([q, toks[:-1]])
+        logits = np.asarray(model.apply(params, jnp.asarray(row)[None])[0],
+                            np.float32)[len(q) - 1:]
+        gap = logits.max(-1) - logits[np.arange(len(toks)), toks]
+        worst = max(worst, float(gap.max()))
+    # the chip's default float32 matmuls round as bfloat16 passes do: a
+    # near-tie may flip between two programs; a lost state is off by tenths
+    check(worst < 0.05, f"a served token lies {worst} below the full "
+          "forward's best logit")
+    return dict(ssd_chunk_vs_recurrence_rel_err=err,
+                ssd_decode_vs_step_abs_err=kerr, ssd_decode_kernel=found,
+                state_kinds=eng._state_kinds, served_token_worst_gap=worst,
+                slot_requests=eng.stats["slot_requests"],
+                prefill_chunks=eng.stats["prefill_chunks"])
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
@@ -846,6 +933,7 @@ def main() -> int:
         run.phase("serve", serve, cfg, args.seed)
         run.phase("host_ps", host_ps, cfg, args.seed, native)
         run.phase("kernels", kernels, cfg, args.seed, on_tpu)
+        run.phase("state_space", state_space, cfg, args.seed, on_tpu)
     if run.failed:
         print(f"chip_smoke.py: failed phases: {run.failed}", file=sys.stderr)
         return 1

@@ -38,8 +38,9 @@ _STATELESS = (LayerNormalization, RMSNorm, Dense)
 #: the layers that keep per-request state.  What they keep is their mixer's
 #: ``state_kind`` — ``kv``: keys and values of every position (a dense slab,
 #: or rows of ``Hkv * Dh`` features in a paged arena); ``recurrent``: a
-#: fixed-size pytree a row, whatever the context — and the cached step
-#: dispatches on it (``_CACHED_MIX``), not on the block's class
+#: fixed-size pytree a row, whatever the context; ``none``: the block has no
+#: mixer and keeps nothing — and the cached step dispatches on it
+#: (``_CACHED_MIX``), not on the block's class
 _BLOCKS = (TransformerBlock, HybridBlock)
 
 
@@ -50,8 +51,9 @@ def _check_supported(model) -> None:
             raise ValueError(
                 f"decode: unsupported layer kind {layer.kind!r} — cached "
                 "decoding walks Embedding / PositionalEmbedding / "
-                "TransformerBlock / HybridBlock (a GatedAttention or "
-                "KimiDeltaAttention mixer over a SparseMoE) / "
+                "TransformerBlock / HybridBlock (a GatedAttention, "
+                "MultiHeadAttention, KimiDeltaAttention or Mamba2Mixer "
+                "mixer, a SparseMoE, or both) / "
                 "LayerNormalization / RMSNorm / Dense sequences "
                 "(transformer_lm and hybrid_lm)")
         if isinstance(layer, _BLOCKS) and not layer.causal:
@@ -144,9 +146,10 @@ def init_cache(model, batch: int, max_len: int,
     dtype = model._cdtype
     caches: List[Any] = []
     for layer in model.layers:
-        if isinstance(layer, _BLOCKS) and layer.state_kind == "recurrent":
+        kind = layer.state_kind if isinstance(layer, _BLOCKS) else "none"
+        if kind == "recurrent":
             caches.append(layer.mixer().init_state(batch, dtype))
-        elif isinstance(layer, _BLOCKS):
+        elif kind == "kv":
             mha = layer.mixer()
             slots = max_len
             if rolling:
@@ -198,9 +201,10 @@ def init_paged_arena(model, num_blocks: int, block_size: int,
                      kv_dtype: Optional[str] = None,
                      num_slots: Optional[int] = None) -> List[Any]:
     """The paged slot pool's backing store, layer by layer by state kind.  A
-    ``recurrent`` layer gets its fixed-size state for ``num_slots`` slots
-    (``{"S": f32[slots, H, Dk, Dv], "conv": [slots, c - 1, 3 H Dk]}``: no
-    blocks, nothing to page).  A ``kv`` layer gets a FLAT
+    ``recurrent`` layer gets its mixer's fixed-size state for ``num_slots``
+    slots (``mixer.init_state``, e.g. ``{"S": f32[slots, H, Dk, Dv], "conv":
+    [slots, c - 1, 3 H Dk]}``: no blocks, nothing to page); a block of state
+    kind ``none`` gets nothing.  A ``kv`` layer gets a FLAT
     arena of ``num_blocks + 1`` fixed-size blocks laid out contiguously —
     ``{"k", "v"}`` of shape ((num_blocks + 1) * block_size, num_kv_heads *
     key_dim): a position's kv heads side by side in ONE row (plus
@@ -237,7 +241,8 @@ def init_paged_arena(model, num_blocks: int, block_size: int,
     dtype = model._cdtype
     caches: List[Any] = []
     for layer in model.layers:
-        if isinstance(layer, _BLOCKS) and layer.state_kind == "recurrent":
+        kind = layer.state_kind if isinstance(layer, _BLOCKS) else "none"
+        if kind == "recurrent":
             if num_slots is None:
                 raise ValueError("a recurrent layer's state is per slot: "
                                  "init_paged_arena needs num_slots")
@@ -246,7 +251,7 @@ def init_paged_arena(model, num_blocks: int, block_size: int,
                     f"kv_dtype={kv_dtype!r} quantises keys and values; a "
                     "recurrent state is float32 and has no such form")
             caches.append(layer.mixer().init_state(int(num_slots), dtype))
-        elif isinstance(layer, _BLOCKS):
+        elif kind == "kv":
             mha = layer.mixer()
             shape = (arena_len, mha._kv_heads() * mha.key_dim)
             if kv_dtype == "int8":
@@ -651,12 +656,12 @@ def _recurrent_forward(mixer, params, h, cache, pos, cdtype, rolling,
     continues its slot's state (``RowView``) through ``mixer.mix`` and
     leaves the state after its last live token.  A prefill unit of any
     length carries the state on; the single-token step of the serving pool
-    goes through the fused kernel (``ops.kda.kda_decode``) on a TPU."""
-    from ..ops import kda
+    goes through the mixer's fused kernel (``kda_decode``, ``ssd_decode``)
+    on a TPU."""
     slots = None if rows is None else rows.slots
     state = cache
     if slots is not None:
-        n = cache["S"].shape[0]
+        n = jax.tree_util.tree_leaves(cache)[0].shape[0]
         fresh = jnp.reshape(pos, (-1,)) == 0
         state = tmap(
             lambda a: jnp.where(
@@ -664,7 +669,7 @@ def _recurrent_forward(mixer, params, h, cache, pos, cdtype, rolling,
                 jnp.zeros((), a.dtype), a[jnp.clip(slots, 0, n - 1)]),
             cache)
     fused = (h.shape[1] == 1 and slots is None and _on_tpu()
-             and kda.kernel_tiles(state["S"].shape, state["S"].dtype))
+             and mixer.kernel_tiles(state))
     y, new = mixer.mix(params, h, state, compute_dtype=cdtype,
                        token_mask=token_mask, fused_step=fused)
     if slots is not None:
@@ -678,8 +683,11 @@ def _kv_forward(mixer, params, h, cache, pos, cdtype, rolling, paged, rows,
     return _mha_forward(mixer, params, h, cache, pos, cdtype, rolling, paged)
 
 
-#: the cached step of a block's mixer, by the kind of state it keeps
-_CACHED_MIX = {"kv": _kv_forward, "recurrent": _recurrent_forward}
+#: the cached step of a block's mixer, by the kind of state it keeps; a
+#: block without a mixer (``none``) has no cached step, no cache entry, and
+#: is never asked for one
+_CACHED_MIX = {"kv": _kv_forward, "recurrent": _recurrent_forward,
+               "none": None}
 
 
 def _block_forward(block, params, x, cache, pos, cdtype,
@@ -699,7 +707,7 @@ def _block_forward(block, params, x, cache, pos, cdtype,
 
     x, counters = block.run(params, x, mix, compute_dtype=cdtype,
                             token_mask=token_mask)
-    return x, box["cache"], counters
+    return x, box.get("cache", cache), counters
 
 
 def _forward(model, params, caches, toks, pos, rolling: bool = False,

@@ -549,6 +549,8 @@ class MultiHeadAttention(Layer):
     output_gate: bool = False
     #: what a cached step keeps for this mixer (``core/decode.py``)
     state_kind = "kv"
+    #: the ``jax.named_scope`` a block runs this mixer under
+    scope = "attn"
 
     def __init__(self, num_heads: int, key_dim: int, causal: bool = False,
                  use_bias: bool = True, attention_impl: Optional[str] = None,
@@ -790,8 +792,8 @@ class Embedding(Layer):
 
 
 # ---------------------------------------------------------------------------
-# Hybrid blocks: a mixer (attention or a linear recurrence) and a
-# feed-forward part (a gated MLP or sparse experts) under RMSNorm
+# Hybrid blocks: a mixer (attention or a linear recurrence), a feed-forward
+# part (sparse experts), or both, under RMSNorm
 # ---------------------------------------------------------------------------
 
 class RMSNorm(Layer):
@@ -827,6 +829,27 @@ class GatedAttention(MultiHeadAttention):
                          num_kv_heads=num_kv_heads)
 
 
+def _short_conv(history, x, taps, n_live, bias=None):
+    """The short causal depthwise convolution of a recurrent mixer, then
+    SiLU, continuing ``history`` (B, c - 1, F), the inputs of the last
+    ``c - 1`` positions: ``x`` (B, L, F) in the compute type, ``taps``
+    (c, F) float32, ``n_live`` (B,) how many positions of each row count.
+    Returns ``(SiLU(conv) (B, L, F) float32, the history after each row's
+    last live position)``."""
+    f32 = jnp.float32
+    size, length = taps.shape[0], x.shape[1]
+    hist = jnp.concatenate([history.astype(x.dtype), x], axis=1)
+    conv = sum(hist[:, i:i + length].astype(f32) * taps[i]
+               for i in range(size))
+    if bias is not None:
+        conv = conv + bias
+    # the inputs of the last c-1 live positions: position t sits at
+    # hist[t + c-1], so they are hist[n .. n + c-2]
+    idx = n_live[:, None] + jnp.arange(size - 1)[None, :]
+    return jax.nn.silu(conv), jnp.take_along_axis(hist, idx[:, :, None],
+                                                  axis=1)
+
+
 def _l2_normalise(x):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True)
                              + 1e-6)
@@ -846,6 +869,7 @@ class KimiDeltaAttention(Layer):
     decay and output gates are low-rank, ``d -> gate_rank -> H * Dh``."""
 
     state_kind = "recurrent"
+    scope = "kda"
 
     def __init__(self, num_heads: int, head_dim: int, conv_size: int = 4,
                  gate_rank: int = 128, neg_eigval: bool = True,
@@ -890,6 +914,11 @@ class KimiDeltaAttention(Layer):
                 "conv": jnp.zeros((batch, self.conv_size - 1, 3 * h * dh),
                                   dtype)}
 
+    def kernel_tiles(self, state) -> bool:
+        """Can the fused decode kernel (``kda_decode``) take ``state``?"""
+        from ..ops import kda
+        return kda.kernel_tiles(state["S"].shape, state["S"].dtype)
+
     def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
               rng=None):
         state = self.init_state(x.shape[0], compute_dtype)
@@ -916,18 +945,10 @@ class KimiDeltaAttention(Layer):
             qkv = jnp.concatenate(
                 [_project(x, params[w], None, compute_dtype)
                  for w in ("wq", "wk", "wv")], axis=-1).astype(compute_dtype)
-            hist = jnp.concatenate([state["conv"].astype(compute_dtype),
-                                    qkv], axis=1)          # (B, c-1 + L, 3I)
             taps = jnp.concatenate(
                 [params[w] for w in ("conv_q", "conv_k", "conv_v")],
                 axis=-1).astype(f32)                       # (c, 3I)
-            conv = sum(hist[:, i:i + length].astype(f32) * taps[i]
-                       for i in range(self.conv_size))
-            conv = jax.nn.silu(conv)
-            # the inputs of the last c-1 live positions: position t sits at
-            # hist[t + c-1], so they are hist[n .. n + c-2]
-            idx = n_live[:, None] + jnp.arange(self.conv_size - 1)[None, :]
-            new_conv = jnp.take_along_axis(hist, idx[:, :, None], axis=1)
+            conv, new_conv = _short_conv(state["conv"], qkv, taps, n_live)
             q, k, v = (conv[..., i * inner:(i + 1) * inner]
                        .reshape(b, length, h, dh) for i in range(3))
             q = _l2_normalise(q) * (dh ** -0.5)
@@ -973,13 +994,156 @@ class KimiDeltaAttention(Layer):
         return y, {"S": new_s, "conv": new_conv.astype(state["conv"].dtype)}
 
 
-def _gated_mlp(x, w_in, w_out, compute_dtype):
-    """``(silu(x @ gate) * (x @ up)) @ down`` with gate and up side by side
-    in ``w_in`` (D, 2F)."""
-    h = _project(x, w_in, None, compute_dtype)
+class Mamba2Mixer(Layer):
+    """A Mamba-2 state-space mixer (arXiv:2405.21060): one input projection
+    to ``[z | x B C | dt]``, a short causal depthwise convolution with bias
+    and SiLU on ``x B C``, the recurrence of ``ops/ssd.py`` (a scalar decay
+    a head, ``B`` and ``C`` shared by the heads of a group), the skip ``D x``,
+    an output gate ``silu(z)`` and an RMSNorm taken inside each group's
+    channels, then the output projection.  No bias but the convolution's.
+    Per request the layer keeps a FIXED-SIZE state (state kind
+    ``recurrent``): ``S`` (H, P, N) float32 and the last ``conv_size - 1``
+    inputs of the convolution."""
+
+    state_kind = "recurrent"
+    scope = "ssm"
+
+    def __init__(self, num_heads: int, head_dim: int, state_size: int,
+                 num_groups: int = 1, conv_size: int = 4,
+                 chunk_size: int = 128, norm_eps: float = 1e-5):
+        self.num_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        self.state_size = int(state_size)
+        self.num_groups = int(num_groups)
+        self.conv_size = int(conv_size)
+        self.chunk_size = int(chunk_size)
+        self.norm_eps = float(norm_eps)
+        if self.num_heads % self.num_groups:
+            raise ValueError(f"num_heads={self.num_heads} not divisible by "
+                             f"num_groups={self.num_groups}")
+
+    def _sizes(self):
+        inner = self.num_heads * self.head_dim
+        return inner, inner + 2 * self.num_groups * self.state_size
+
+    def init(self, rng, in_shape):
+        s, d = in_shape
+        h = self.num_heads
+        inner, conv_dim = self._sizes()
+        ks = iter(jax.random.split(rng, 8))
+        # steps spread log-uniformly over (0.001, 0.1): dt_bias is the
+        # inverse softplus of the step a zero projection gives
+        dt = jnp.exp(jax.random.uniform(next(ks), (h,), jnp.float32,
+                                        math.log(1e-3), math.log(1e-1)))
+        params = {
+            "w_in": init_weight(next(ks), (d, inner + conv_dim + h)),
+            "conv_w": init_weight(next(ks), (self.conv_size, conv_dim),
+                                  "glorot_normal"),
+            "conv_b": jnp.zeros((conv_dim,), jnp.float32),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "a_log": jnp.log(jax.random.uniform(next(ks), (h,), jnp.float32,
+                                                1.0, 16.0)),
+            "d_skip": jnp.ones((h,), jnp.float32),
+            "norm": jnp.ones((inner,), jnp.float32),
+            "w_out": init_weight(next(ks), (inner, d)),
+        }
+        return params, tuple(in_shape)
+
+    def init_state(self, batch: int, dtype):
+        """A zero state for ``batch`` rows: what a request starts from."""
+        return {"S": jnp.zeros((batch, self.num_heads, self.head_dim,
+                                self.state_size), jnp.float32),
+                "conv": jnp.zeros((batch, self.conv_size - 1,
+                                   self._sizes()[1]), dtype)}
+
+    def kernel_tiles(self, state) -> bool:
+        """Can the fused decode kernel (``ssd_decode``) take ``state``?"""
+        from ..ops import ssd
+        return ssd.kernel_tiles(state["S"].shape, state["S"].dtype)
+
+    def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
+              rng=None):
+        state = self.init_state(x.shape[0], compute_dtype)
+        return self.mix(params, x, state, compute_dtype=compute_dtype)[0]
+
+    def mix(self, params, x, state, *, compute_dtype=jnp.bfloat16,
+            token_mask=None, fused_step: bool = False):
+        """As ``KimiDeltaAttention.mix``: (B, L, D) inputs continuing
+        ``state`` -> ``(y (B, L, D) float32, the state after each row's last
+        live token)``; ``token_mask`` (B, L) bool, a PREFIX of each row;
+        ``fused_step``: the single-token step through the ``ssd_decode``
+        kernel, which skips the rows the mask marks dead."""
+        from ..ops import ssd
+        f32 = jnp.float32
+        b, length, _ = x.shape
+        h, p, n, g = (self.num_heads, self.head_dim, self.state_size,
+                      self.num_groups)
+        inner, conv_dim = self._sizes()
+        if token_mask is None:
+            token_mask = jnp.ones((b, length), bool)
+        n_live = jnp.sum(token_mask, axis=1).astype(jnp.int32)     # (B,)
+
+        with jax.named_scope("ssm_proj"):
+            zxd = _project(x, params["w_in"], None, compute_dtype)
+            z = zxd[..., :inner]
+            xbc = zxd[..., inner:inner + conv_dim].astype(compute_dtype)
+            dt = jax.nn.softplus(zxd[..., inner + conv_dim:].astype(f32)
+                                 + params["dt_bias"].astype(f32))
+            dt = jnp.where(token_mask[:, :, None], dt, 0.0)     # (B, L, H)
+
+        with jax.named_scope("ssm_conv"):
+            conv, new_conv = _short_conv(
+                state["conv"], xbc, params["conv_w"].astype(f32), n_live,
+                bias=params["conv_b"].astype(f32))
+            xs = conv[..., :inner].reshape(b, length, h, p)
+            bm = conv[..., inner:inner + g * n].reshape(b, length, g, n)
+            cm = conv[..., inner + g * n:].reshape(b, length, g, n)
+
+        a = -jnp.exp(params["a_log"].astype(f32))
+        with jax.named_scope("ssm_core"):
+            if length == 1 and fused_step:
+                y, new_s = ssd.ssd_decode(xs[:, 0], dt[:, 0], a, bm[:, 0],
+                                          cm[:, 0], state["S"],
+                                          token_mask[:, 0])
+                y = y[:, None]
+            elif length == 1:
+                y, new_s = ssd.ssd_step(xs[:, 0], dt[:, 0], a, bm[:, 0],
+                                        cm[:, 0], state["S"])
+                y = y[:, None]
+            else:
+                y, new_s = ssd.ssd_chunk(xs, dt, a, bm, cm, state["S"],
+                                         chunk=self.chunk_size)
+
+        with jax.named_scope("ssm_out"):
+            y = y + params["d_skip"].astype(f32)[:, None] * xs
+            y = y.reshape(b, length, inner) * jax.nn.silu(z.astype(f32))
+            # gate first, then the norm inside each group's channels
+            yg = y.reshape(b, length, g, inner // g)
+            yg = yg * jax.lax.rsqrt(jnp.mean(jnp.square(yg), axis=-1,
+                                             keepdims=True) + self.norm_eps)
+            y = (yg.reshape(b, length, inner)
+                 * params["norm"].astype(f32)).astype(compute_dtype)
+            out = _project(y, params["w_out"], None, compute_dtype)
+        return out, {"S": new_s,
+                     "conv": new_conv.astype(state["conv"].dtype)}
+
+
+def _swiglu(h):
+    """``silu(gate) * up`` with gate and up side by side on the last axis."""
     f = h.shape[-1] // 2
-    h = (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(compute_dtype)
-    return _project(h, w_out, None, compute_dtype)
+    return jax.nn.silu(h[..., :f]) * h[..., f:]
+
+
+#: an expert's form: (what stands between its two matmuls, columns of its
+#: input weight per unit of width)
+_EXPERT_FORMS = {"gated_silu": (_swiglu, 2),
+                 "relu2": (lambda h: jnp.square(jax.nn.relu(h)), 1)}
+
+
+def _expert_mlp(x, w_in, w_out, form: str, compute_dtype):
+    """One expert over every row: ``act(x @ w_in) @ w_out``."""
+    h = _EXPERT_FORMS[form][0](_project(x, w_in, None, compute_dtype))
+    return _project(h.astype(compute_dtype), w_out, None, compute_dtype)
 
 
 class SparseMoE(Layer):
@@ -994,18 +1158,44 @@ class SparseMoE(Layer):
     for the tokens routed to them (``ops/experts.py``: assignments sorted by
     expert, one grouped matmul in and one out, no capacity and no dropped
     token under any skew) plus the shared expert; what experts held
-    elsewhere would add is left out, and that partial result goes on."""
+    elsewhere would add is left out, and that partial result goes on.
+
+    ``router`` ``"sigmoid_bias"``: the scores are ``sigmoid``s, the ``top_k``
+    are chosen by score PLUS a learned per-expert bias (``router_bias``,
+    selection only), and the chosen experts' UNBIASED scores, renormalised
+    to 1, times ``router_scale`` are the weights.  ``expert_form``
+    ``"relu2"``: every expert (the shared one too) is ``relu(x W_up)^2
+    W_down``, no gate."""
 
     routes_tokens = True
+    #: class-level defaults: configs written before these fields existed
+    #: deserialize as the softmax router over gated-SiLU experts
+    router = "softmax"
+    router_scale = 1.0
+    expert_form = "gated_silu"
 
     def __init__(self, num_experts: int, top_k: int, expert_dim: int,
-                 held: Optional[Tuple[int, int]] = None, shared_dim: int = 0):
+                 held: Optional[Tuple[int, int]] = None, shared_dim: int = 0,
+                 router: str = "softmax", router_scale: float = 1.0,
+                 expert_form: str = "gated_silu"):
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
         self.expert_dim = int(expert_dim)
         self.held = (0, self.num_experts) if held is None else \
             (int(held[0]), int(held[1]))
         self.shared_dim = int(shared_dim)
+        if router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"router must be 'softmax' or 'sigmoid_bias', "
+                             f"got {router!r}")
+        if expert_form not in _EXPERT_FORMS:
+            raise ValueError(f"expert_form must be one of "
+                             f"{sorted(_EXPERT_FORMS)}, got {expert_form!r}")
+        if router != "softmax":
+            self.router, self.router_scale = router, float(router_scale)
+        elif router_scale != 1.0:
+            raise ValueError("router_scale belongs to router='sigmoid_bias'")
+        if expert_form != "gated_silu":
+            self.expert_form = expert_form
         if not (0 <= self.held[0]
                 and self.held[0] + self.held[1] <= self.num_experts):
             raise ValueError(f"held={self.held} outside the "
@@ -1014,15 +1204,20 @@ class SparseMoE(Layer):
     def init(self, rng, in_shape):
         d = in_shape[-1]
         n, f = self.held[1], self.expert_dim
+        cols = _EXPERT_FORMS[self.expert_form][1]
         k_r, k_i, k_o, k_si, k_so = jax.random.split(rng, 5)
         std_in, std_out = (2.0 / (d + f)) ** 0.5, (2.0 / (f + d)) ** 0.5
         params = {
             "router": init_weight(k_r, (d, self.num_experts)),
-            "w_in": std_in * jax.random.normal(k_i, (n, d, 2 * f)),
+            "w_in": std_in * jax.random.normal(k_i, (n, d, cols * f)),
             "w_out": std_out * jax.random.normal(k_o, (n, f, d)),
         }
+        if self.router == "sigmoid_bias":
+            params["router_bias"] = jnp.zeros((self.num_experts,),
+                                              jnp.float32)
         if self.shared_dim:
-            params["shared_in"] = init_weight(k_si, (d, 2 * self.shared_dim))
+            params["shared_in"] = init_weight(
+                k_si, (d, cols * self.shared_dim))
             params["shared_out"] = init_weight(k_so, (self.shared_dim, d))
         return params, tuple(in_shape)
 
@@ -1044,7 +1239,12 @@ class SparseMoE(Layer):
                 logits = jnp.matmul(flat.astype(f32),
                                     params["router"].astype(f32),
                                     precision=jax.lax.Precision.HIGHEST)
-                chosen, weights = ops.route(logits, self.top_k)
+                if self.router == "softmax":
+                    chosen, weights = ops.route(logits, self.top_k)
+                else:
+                    chosen, weights = ops.route(
+                        logits, self.top_k, kind=self.router,
+                        bias=params["router_bias"], scale=self.router_scale)
             with jax.named_scope("moe_dispatch"):
                 token, weight, sizes, total = ops.dispatch(
                     chosen, weights, self.held, live)
@@ -1052,8 +1252,8 @@ class SparseMoE(Layer):
             with jax.named_scope("moe_experts"):
                 h = ops.grouped_matmul(
                     rows, params["w_in"].astype(compute_dtype), sizes)
-                f = self.expert_dim
-                h = (jax.nn.silu(h[:, :f]) * h[:, f:]).astype(compute_dtype)
+                h = _EXPERT_FORMS[self.expert_form][0](h).astype(
+                    compute_dtype)
                 out = ops.grouped_matmul(
                     h, params["w_out"].astype(compute_dtype), sizes)
             with jax.named_scope("moe_combine"):
@@ -1061,48 +1261,60 @@ class SparseMoE(Layer):
                     out * weight[:, None])
             if self.shared_dim:
                 with jax.named_scope("moe_shared"):
-                    y = y + _gated_mlp(flat, params["shared_in"],
-                                       params["shared_out"], compute_dtype)
+                    y = y + _expert_mlp(flat, params["shared_in"],
+                                        params["shared_out"],
+                                        self.expert_form, compute_dtype)
         counters = jnp.stack([total, jnp.sum(sizes > 0),
                               jnp.max(sizes)]).astype(jnp.int32)
         return y.reshape(lead + (d,)), counters
 
 
 class HybridBlock(Layer):
-    """Pre-norm block that TAKES its parts: ``h = x + mixer(RMSNorm(x))``,
-    ``y = h + ffn(RMSNorm(h))``, no biases.  ``mixer`` is a
-    ``GatedAttention`` or a ``KimiDeltaAttention``; ``ffn`` a ``SparseMoE``.
-    The parts are kept as their configs (the spec stays JSON-serialisable)
-    and rebuilt on use.  What the cached step and the serving engine need to
-    know of a block they ask the block (``state_kind``, ``routes_tokens``,
-    ``wants_token_mask``, ``int8_weights``), never its class."""
+    """Pre-norm residual block that TAKES its parts, no biases: a mixer
+    (``h = x + mixer(RMSNorm(x))``), a feed-forward part (``y = h +
+    ffn(RMSNorm(h))``), or both in that order.  ``mixer`` is an attention
+    layer (``GatedAttention``, a plain ``MultiHeadAttention``) or a linear
+    recurrence (``KimiDeltaAttention``, ``Mamba2Mixer``); ``ffn`` a
+    ``SparseMoE``.  A block of ONE part is a layer of a stack whose layers
+    are a mixer OR a feed-forward part alone; without a mixer the block
+    keeps no per-request state (state kind ``none``).  The parts are kept
+    as their configs (the spec stays JSON-serialisable) and rebuilt on use.
+    What the cached step and the serving engine need to know of a block they
+    ask the block (``state_kind``, ``routes_tokens``, ``wants_token_mask``,
+    ``int8_weights``), never its class."""
 
     #: ``core.quant.quantize_params`` finds matmul weights by
     #: ``TransformerBlock``'s names and would leave these as they are
     int8_weights = False
 
-    def __init__(self, mixer, ffn, epsilon: float = 1e-5):
-        self.mixer_config = (mixer.get_config() if isinstance(mixer, Layer)
-                             else dict(mixer))
-        self.ffn_config = (ffn.get_config() if isinstance(ffn, Layer)
-                           else dict(ffn))
+    def __init__(self, mixer=None, ffn=None, epsilon: float = 1e-5):
+        def config(part):
+            if part is None:
+                return None
+            return part.get_config() if isinstance(part, Layer) else \
+                dict(part)
+        if mixer is None and ffn is None:
+            raise ValueError("HybridBlock needs a mixer, a feed-forward "
+                             "part, or both")
+        self.mixer_config = config(mixer)
+        self.ffn_config = config(ffn)
         self.epsilon = float(epsilon)
 
-    def mixer(self) -> Layer:
-        return Layer.from_config(self.mixer_config)
+    def mixer(self) -> Optional[Layer]:
+        return self.mixer_config and Layer.from_config(self.mixer_config)
 
-    def ffn(self) -> Layer:
-        return Layer.from_config(self.ffn_config)
+    def ffn(self) -> Optional[Layer]:
+        return self.ffn_config and Layer.from_config(self.ffn_config)
 
     @property
     def state_kind(self) -> str:
-        return self.mixer().state_kind
+        return self.mixer().state_kind if self.mixer_config else "none"
 
     @property
     def routes_tokens(self) -> bool:
         """The feed-forward part routes tokens to experts and returns
         counters of it (``SparseMoE``)."""
-        return self.ffn().routes_tokens
+        return bool(self.ffn_config) and self.ffn().routes_tokens
 
     @property
     def wants_token_mask(self) -> bool:
@@ -1117,10 +1329,14 @@ class HybridBlock(Layer):
     def init(self, rng, in_shape):
         k_m, k_f = jax.random.split(rng)
         norm = RMSNorm(self.epsilon)
-        return {"norm1": norm.init(None, in_shape)[0],
-                "mixer": self.mixer().init(k_m, in_shape)[0],
-                "norm2": norm.init(None, in_shape)[0],
-                "ffn": self.ffn().init(k_f, in_shape)[0]}, tuple(in_shape)
+        params = {}
+        if self.mixer_config:
+            params.update(norm1=norm.init(None, in_shape)[0],
+                          mixer=self.mixer().init(k_m, in_shape)[0])
+        if self.ffn_config:
+            params.update(norm2=norm.init(None, in_shape)[0],
+                          ffn=self.ffn().init(k_f, in_shape)[0])
+        return params, tuple(in_shape)
 
     def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
               rng=None):
@@ -1130,20 +1346,26 @@ class HybridBlock(Layer):
 
     def run(self, params, x, mix, *, compute_dtype=jnp.bfloat16,
             train=False, rng=None, token_mask=None):
-        """As ``TransformerBlock.run``: the block around ``mix``.  Returns
-        ``(y, counters)``, the feed-forward part's counters (``SparseMoE``)
-        or None.  ``token_mask`` (B, L) bool keeps padding and dead rows
-        out of the experts' routing."""
+        """As ``TransformerBlock.run``: the block around ``mix``, which is
+        not called where the block has no mixer.  Returns ``(y, counters)``,
+        the feed-forward part's counters (``SparseMoE``) or None.
+        ``token_mask`` (B, L) bool keeps padding and dead rows out of the
+        experts' routing."""
         norm = RMSNorm(self.epsilon)
         mixer, ffn = self.mixer(), self.ffn()
-        scope = "kda" if mixer.state_kind == "recurrent" else "attn"
-        with jax.named_scope(scope):
-            h = norm.apply(params["norm1"], x, compute_dtype=compute_dtype)
-            x = x + mix(mixer, params["mixer"], h).astype(x.dtype)
-        h = norm.apply(params["norm2"], x, compute_dtype=compute_dtype)
-        h, counters = ffn.mix(params["ffn"], h, compute_dtype=compute_dtype,
-                              token_mask=token_mask)
-        return x + h.astype(x.dtype), counters
+        counters = None
+        if mixer is not None:
+            with jax.named_scope(mixer.scope):
+                h = norm.apply(params["norm1"], x,
+                               compute_dtype=compute_dtype)
+                x = x + mix(mixer, params["mixer"], h).astype(x.dtype)
+        if ffn is not None:
+            h = norm.apply(params["norm2"], x, compute_dtype=compute_dtype)
+            h, counters = ffn.mix(params["ffn"], h,
+                                  compute_dtype=compute_dtype,
+                                  token_mask=token_mask)
+            x = x + h.astype(x.dtype)
+        return x, counters
 
 
 def scope_names(layers: Sequence[Layer]) -> List[str]:

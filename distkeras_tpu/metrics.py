@@ -98,7 +98,11 @@ time) name the compiled programs' phases in every profile and HLO dump:
 in a ``HybridBlock``, ``attn`` ⊃ ``attn_core``, ``attn_gate`` (the output
 gate) or ``kda`` ⊃ ``kda_conv`` (projections, convolution, normalisation),
 ``kda_gates``, ``kda_core`` (the recurrence: ``kda_chunk`` in a prefill
-unit, the ``kda_decode`` kernel in the decode step), ``kda_gate_out``; and
+unit, the ``kda_decode`` kernel in the decode step), ``kda_gate_out``, or
+``ssm`` ⊃ ``ssm_proj`` (the input projection), ``ssm_conv``, ``ssm_core``
+(the state-space recurrence: ``ssd_chunk`` in a prefill unit, the
+``ssd_decode`` kernel in the decode step), ``ssm_out`` (skip, gate, grouped
+norm, output projection); and, in a block that has a feed-forward part,
 ``moe`` ⊃ ``moe_route``, ``moe_dispatch``, ``moe_experts`` (the grouped
 matmuls), ``moe_combine``, ``moe_shared``;
 ``loss``, ``optimizer``, ``commit`` (train step and the SPMD round);
@@ -106,8 +110,9 @@ matmuls), ``moe_combine``, ``moe_shared``;
 ``kv_gather`` holds only the row lengths' preparation).  Pallas kernels
 carry the names in ``KERNEL_NAMES``: ``flash_fwd``/``flash_dq``/
 ``flash_dkv``, ``fused_ce_fwd``/``fused_ce_bwd``, ``paged_decode``
-(under ``attn_core`` of the paged single-token step) and ``kda_decode``
-(under ``kda_core`` of the same step).  The experts' grouped matmul is
+(under ``attn_core`` of the paged single-token step), ``kda_decode``
+(under ``kda_core`` of the same step) and ``ssd_decode`` (under
+``ssm_core``).  The experts' grouped matmul is
 jax's own Pallas kernel (``jax.experimental.pallas.ops.tpu.megablox``),
 which carries no name of this package: it is found by its scope,
 ``moe_experts``.
@@ -126,7 +131,8 @@ import jax
 #: ``chip_smoke.py``'s ``require_kernels`` and ``tests/test_tracing.py`` look
 #: the kernels up by.
 KERNEL_NAMES = ("flash_fwd", "flash_dq", "flash_dkv",
-                "fused_ce_fwd", "fused_ce_bwd", "paged_decode", "kda_decode")
+                "fused_ce_fwd", "fused_ce_bwd", "paged_decode", "kda_decode",
+                "ssd_decode")
 
 
 class MetricsLogger:

@@ -10,7 +10,8 @@ from ..core import (Sequential, Dense, Conv2D, MaxPooling2D, Flatten, Reshape,
                     Dropout)
 from ..core.layers import (Embedding, PositionalEmbedding, TransformerBlock,
                            LayerNormalization, RMSNorm, GatedAttention,
-                           KimiDeltaAttention, SparseMoE, HybridBlock)
+                           KimiDeltaAttention, Mamba2Mixer,
+                           MultiHeadAttention, SparseMoE, HybridBlock)
 
 
 def mnist_mlp(compute_dtype: str = "bfloat16") -> Sequential:
@@ -134,26 +135,10 @@ def transformer_lm(vocab_size: int = 256, seq_len: int = 128,
                       compute_dtype=compute_dtype, name="transformer_lm")
 
 
-def hybrid_lm(config: dict, compute_dtype: str = "bfloat16",
-              held=None) -> Sequential:
-    """A decoder-only LM of hybrid blocks, built from the keys of a published
-    ``config.json`` of the Solar-Open2 kind: ``gqa_layers`` names the layers
-    whose mixer is gated NoPE grouped-query attention (``num_attention_heads``
-    over ``num_key_value_heads`` of ``head_dim``, ``use_gqa_gate``); every
-    other layer's is Kimi Delta Attention (``linear_attn_config``:
-    ``num_heads``, ``head_dim``, ``short_conv_kernel_size``;
-    ``kda_allow_neg_eigval``; ``kda_use_full_proj`` false: its decay and
-    output gates are low-rank through ``head_dim``).  Every layer has sparse
-    experts (``n_routed_experts``, ``num_experts_per_tok``,
-    ``moe_intermediate_size``, ``n_shared_experts``;
-    ``first_k_dense_replace`` 0).  RMSNorm (``rms_norm_eps``) everywhere, no
-    position signal (``use_rope`` false), no biases, an untied head.
-
-    ``held`` = (first, count): the experts whose weights live here, of the
-    ``n_routed_experts`` the router scores — one chip's share of an
-    expert-parallel deployment (default: all).  ``num_hidden_layers`` and
-    ``vocab_size`` are taken as given, so a configuration cut in depth or to
-    a slice of the vocabulary builds as it reads."""
+def _interleaved_blocks(config: dict, held):
+    """The blocks of a config of the Solar-Open2 kind: every layer a mixer
+    (``gqa_layers``: gated NoPE GQA; the others Kimi Delta Attention) THEN
+    sparse experts."""
     if config.get("use_rope", False):
         raise ValueError("hybrid_lm builds NoPE attention (use_rope false); "
                          "this config asks for rotary positions")
@@ -163,13 +148,11 @@ def hybrid_lm(config: dict, compute_dtype: str = "bfloat16",
         raise ValueError("hybrid_lm builds sparse experts in every layer "
                          "(first_k_dense_replace 0); this config asks for "
                          "dense feed-forward layers first")
-    d = int(config["hidden_size"])
     eps = float(config["rms_norm_eps"])
     lin = config["linear_attn_config"]
     gqa = {int(i) for i in config["gqa_layers"]}
     experts = int(config["n_routed_experts"])
     moe_dim = int(config["moe_intermediate_size"])
-    layers = [Embedding(int(config["vocab_size"]), d)]
     for i in range(int(config["num_hidden_layers"])):
         if i in gqa:
             mixer = GatedAttention(int(config["num_attention_heads"]),
@@ -185,8 +168,113 @@ def hybrid_lm(config: dict, compute_dtype: str = "bfloat16",
         ffn = SparseMoE(
             experts, int(config["num_experts_per_tok"]), moe_dim, held=held,
             shared_dim=int(config.get("n_shared_experts", 0)) * moe_dim)
-        layers.append(HybridBlock(mixer, ffn, epsilon=eps))
-    layers += [RMSNorm(eps), Dense(int(config["vocab_size"]),
-                                   use_bias=False)]
+        yield HybridBlock(mixer, ffn, epsilon=eps)
+
+
+def _pattern_blocks(config: dict, held):
+    """The blocks of a config of the ``nemotron_h`` kind: layer ``l`` is ONE
+    part, named by ``hybrid_override_pattern[l]`` — ``M`` a Mamba-2 mixer,
+    ``*`` causal NoPE grouped-query attention, ``E`` sparse experts (sigmoid
+    scores, a selection bias, ``routed_scaling_factor``; ungated relu^2
+    experts and shared expert).  The first ``num_hidden_layers`` characters
+    are built, so a cut in depth keeps the published pattern."""
+    pattern = str(config["hybrid_override_pattern"])
+    n = int(config["num_hidden_layers"])
+    if n > len(pattern):
+        raise ValueError(f"num_hidden_layers={n} but hybrid_override_pattern "
+                         f"names {len(pattern)} layers")
+    if config.get("mlp_hidden_act", "relu2") != "relu2":
+        raise ValueError("hybrid_lm builds relu2 experts; this config asks "
+                         f"for {config['mlp_hidden_act']!r}")
+    if config.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError("hybrid_lm builds SiLU state-space layers; this "
+                         f"config asks for {config['mamba_hidden_act']!r}")
+    for key in ("use_bias", "attention_bias", "mlp_bias", "mamba_proj_bias"):
+        if config.get(key, False):
+            raise ValueError(f"hybrid_lm builds bias-free projections; this "
+                             f"config sets {key}")
+    if not config.get("use_conv_bias", True):
+        raise ValueError("hybrid_lm builds the state-space convolution with "
+                         "its bias (use_conv_bias)")
+    if int(config.get("n_group", 1)) != 1 or \
+            int(config.get("topk_group", 1)) != 1:
+        raise ValueError("hybrid_lm builds a router without expert groups "
+                         "(n_group 1, topk_group 1)")
+    if not config.get("norm_topk_prob", True):
+        raise ValueError("hybrid_lm builds a router whose chosen weights "
+                         "are renormalised (norm_topk_prob)")
+    eps = float(config["layer_norm_epsilon"])
+    moe_dim = int(config["moe_intermediate_size"])
+    parts = {
+        "M": lambda: dict(mixer=Mamba2Mixer(
+            int(config["mamba_num_heads"]), int(config["mamba_head_dim"]),
+            int(config["ssm_state_size"]), num_groups=int(config["n_groups"]),
+            conv_size=int(config["conv_kernel"]),
+            chunk_size=int(config["chunk_size"]), norm_eps=eps)),
+        "*": lambda: dict(mixer=MultiHeadAttention(
+            int(config["num_attention_heads"]), int(config["head_dim"]),
+            causal=True, use_bias=False,
+            num_kv_heads=int(config["num_key_value_heads"]))),
+        "E": lambda: dict(ffn=SparseMoE(
+            int(config["n_routed_experts"]),
+            int(config["num_experts_per_tok"]), moe_dim, held=held,
+            shared_dim=int(config.get("n_shared_experts", 0)) * int(
+                config.get("moe_shared_expert_intermediate_size", moe_dim)),
+            router="sigmoid_bias",
+            router_scale=float(config.get("routed_scaling_factor", 1.0)),
+            expert_form="relu2")),
+    }
+    for i, ch in enumerate(pattern[:n]):
+        if ch not in parts:
+            raise ValueError(
+                f"hybrid_override_pattern[{i}] = {ch!r}: hybrid_lm builds "
+                "'M' (Mamba-2), '*' (attention) and 'E' (sparse experts); "
+                "a dense MLP layer ('-') is not written")
+        yield HybridBlock(epsilon=eps, **parts[ch]())
+
+
+def hybrid_lm(config: dict, compute_dtype: str = "bfloat16",
+              held=None) -> Sequential:
+    """A decoder-only LM of hybrid blocks, built from the keys of a published
+    ``config.json``.  Two kinds of stack, told apart by the config's own
+    keys:
+
+    - ``hybrid_override_pattern`` (``model_type`` ``nemotron_h``): every
+      layer is ONE residual part, a Mamba-2 mixer (``mamba_num_heads``,
+      ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``, ``conv_kernel``,
+      ``chunk_size``), NoPE grouped-query attention, or sparse experts with
+      a sigmoid router, a selection bias and ungated relu^2 experts
+      (``_pattern_blocks``);
+    - otherwise the Solar-Open2 kind: ``gqa_layers`` names the layers
+      whose mixer is gated NoPE grouped-query attention
+      (``num_attention_heads`` over ``num_key_value_heads`` of ``head_dim``,
+      ``use_gqa_gate``); every other layer's is Kimi Delta Attention
+      (``linear_attn_config``: ``num_heads``, ``head_dim``,
+      ``short_conv_kernel_size``; ``kda_allow_neg_eigval``;
+      ``kda_use_full_proj`` false: its decay and output gates are low-rank
+      through ``head_dim``), and every layer has sparse experts after its
+      mixer (``n_routed_experts``, ``num_experts_per_tok``,
+      ``moe_intermediate_size``, ``n_shared_experts``;
+      ``first_k_dense_replace`` 0).
+
+    RMSNorm everywhere, no position signal, no biases, an untied head.
+
+    ``held`` = (first, count): the experts whose weights live here, of the
+    ``n_routed_experts`` the router scores — one chip's share of an
+    expert-parallel deployment (default: all).  ``num_hidden_layers`` and
+    ``vocab_size`` are taken as given, so a configuration cut in depth or to
+    a slice of the vocabulary builds as it reads."""
+    if config.get("tie_word_embeddings", False):
+        raise ValueError("hybrid_lm builds an untied head; this config ties "
+                         "the embeddings")
+    d = int(config["hidden_size"])
+    if "hybrid_override_pattern" in config:
+        blocks = _pattern_blocks(config, held)
+        eps = float(config["layer_norm_epsilon"])
+    else:
+        blocks = _interleaved_blocks(config, held)
+        eps = float(config["rms_norm_eps"])
+    layers = [Embedding(int(config["vocab_size"]), d), *blocks,
+              RMSNorm(eps), Dense(int(config["vocab_size"]), use_bias=False)]
     return Sequential(layers, input_shape=(8,), compute_dtype=compute_dtype,
                       name="hybrid_lm")

@@ -22,13 +22,27 @@ import jax.numpy as jnp
 _TILE_M = 128
 
 
-def route(logits, top_k: int):
-    """Softmax over all experts in float32, the ``top_k`` largest, their
-    weights renormalised to sum to 1.  ``logits``: (T, E) float32.  Returns
-    ``(experts (T, K) int32, weights (T, K) float32)``."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, e = jax.lax.top_k(probs, int(top_k))
-    return e.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+def route(logits, top_k: int, kind: str = "softmax", bias=None,
+          scale: float = 1.0):
+    """The ``top_k`` experts of every token and their weights, renormalised
+    to sum to 1, in float32.  ``logits``: (T, E).  ``kind`` ``"softmax"``:
+    softmax over all experts, the ``top_k`` largest.  ``"sigmoid_bias"``:
+    the scores are ``sigmoid(logits)``; the ``top_k`` are those with the
+    largest score PLUS ``bias`` (E,), which moves who is chosen and nothing
+    else; the weights are the chosen experts' unbiased scores, renormalised,
+    times ``scale``.  Returns ``(experts (T, K) int32, weights (T, K)
+    float32)``."""
+    if kind == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        w, e = jax.lax.top_k(probs, int(top_k))
+        return e.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+    if kind != "sigmoid_bias":
+        raise ValueError(f"route: unknown router kind {kind!r}")
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, e = jax.lax.top_k(scores + bias.astype(jnp.float32), int(top_k))
+    w = jnp.take_along_axis(scores, e, axis=-1)
+    return (e.astype(jnp.int32),
+            scale * w / jnp.sum(w, axis=-1, keepdims=True))
 
 
 def dispatch(experts, weights, held: Tuple[int, int], token_live=None):
@@ -56,9 +70,18 @@ def dispatch(experts, weights, held: Tuple[int, int], token_live=None):
 
 def _tiling(k: int, n: int):
     """(tm, tk, tn) of the megablox kernel: weight tiles of a few MB, so a
-    step's DMA outweighs its fixed cost and two of them fit scoped VMEM."""
+    step's DMA outweighs its fixed cost and two of them fit scoped VMEM.
+    A width that none of the usual tiles divides takes the largest multiple
+    of 128 that does (2,688 = 3 x 896) or, where none does, itself as one
+    whole tile (1,856 = 14.5 x 128; the kernel masks an irregular last tile,
+    but a tile of 128 at such a width is 300 grid steps an expert of 2 us
+    each: PERF.md section 6, PR 34)."""
     def fit(x):
-        return next((t for t in (1280, 1024, 512, 256) if x % t == 0), 128)
+        for t in (1280, 1024, 512, 256):
+            if x % t == 0:
+                return t
+        whole = next((t for t in range(1152, 128, -128) if x % t == 0), None)
+        return whole or (x if 128 < x <= 2048 else 128)
     return _TILE_M, fit(k), fit(n)
 
 

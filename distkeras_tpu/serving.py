@@ -494,6 +494,15 @@ def _pow2_buckets(cap: int) -> List[int]:
     return out
 
 
+def _moe_unit_counters(aux):
+    """What a prefill unit hands back of its expert layers (``aux``: each
+    layer's ``SparseMoE`` counters): assignments to held experts, held
+    experts touched, the fullest expert's rows, all summed over the layers,
+    and how many layers that was.  int32[4]."""
+    return jnp.concatenate([sum(aux),
+                            jnp.asarray([len(aux)], jnp.int32)])
+
+
 def _sampler_work(entries) -> str:
     """What the live rows of a decode step ask of the sampler, from the
     handles the host holds: ``"greedy"`` (none samples: the argmax alone),
@@ -528,7 +537,7 @@ class _PrefillJob:
     ``dbt`` hold the row's uploaded block tables)."""
 
     __slots__ = ("handle", "staging", "d_staging", "written", "bt", "dbt",
-                 "hit")
+                 "hit", "moe")
 
     def __init__(self, handle: RequestHandle, staging=None, d_staging=None,
                  bt=None, dbt=None):
@@ -539,6 +548,7 @@ class _PrefillJob:
         self.dbt = dbt
         self.written = 0
         self.hit = 0    # prefix-hit tokens, for the first unit's span
+        self.moe = None  # the experts' counters of the units so far (device)
 
 
 class _SuspendedReq:
@@ -944,6 +954,18 @@ class ServingEngine:
         blocks = [layer for layer in self.model.layers
                   if isinstance(layer, _dec._BLOCKS)]
         self._moe_layers = sum(1 for b in blocks if b.routes_tokens)
+        #: the kinds of per-request state the model's blocks keep, for the
+        #: decode dispatch span: "kv", "kv+recurrent", "kv+recurrent+none"
+        self._state_kinds = "+".join(
+            k for k in ("kv", "recurrent", "none")
+            if any(b.state_kind == k for b in blocks)) or "kv"
+        # the arena-direct prefill programs hand the experts' counters back
+        # behind the first token (the one array the host fetches anyway)
+        self._moe_prefill = (self._moe_layers > 0 and paged and not rolling
+                             and spec_draft is None)
+        #: what a chunked request's counters start from (never donated)
+        self._moe_zero = (jnp.zeros((4,), jnp.int32) if self._moe_prefill
+                          else None)
         if self._recurrent:
             if role != "unified":
                 raise ValueError(
@@ -1339,6 +1361,12 @@ class ServingEngine:
             "moe_assignments_held": 0, "moe_experts_touched": 0,
             "moe_load_max": 0, "moe_layer_steps": 0,
             "recurrent_slots_cleared": 0,
+            # the same two counts over PREFILL units (buckets, chunks and
+            # final chunks of the paged pool), added when the unit's first
+            # token is drained; moe_prefill_layer_units counts the (layer,
+            # unit) pairs summed over
+            "moe_prefill_assignments_held": 0,
+            "moe_prefill_experts_touched": 0, "moe_prefill_layer_units": 0,
         }
         if self.paged:
             # a recurrent layer's state is not in the blocks: a matched
@@ -1778,15 +1806,18 @@ class ServingEngine:
         draft = self._draft_model
         page, t_view, d_view = self.block_size, self._t_view, self.max_len
 
+        moe_prefill = self._moe_prefill
+
         def prefill(params, dparams, pool, dpool, bt, dbt, tok, pos, act,
                     temp, topk, topp, keys, prompts, match, p_lens, slots,
                     row_bt, row_dbt, r_temp, r_topk, r_topp, r_keys):
+            aux = [] if moe_prefill else None
             if not rolling:
                 pv = _dec.PagedView(row_bt, page, t_view, floor=match,
                                     ceil=p_lens, qcap=p_lens - 1)
                 logits, pool = _dec._forward(
                     model, params, pool, prompts, match, paged=pv,
-                    rows=_dec.RowView(slots=slots))
+                    rows=_dec.RowView(slots=slots), aux=aux)
                 idx = jnp.clip(p_lens - match - 1, 0, width - 1)
                 last = jnp.take_along_axis(logits, idx[:, None, None],
                                            axis=1)[:, 0]
@@ -1812,7 +1843,8 @@ class ServingEngine:
                 pool = new_pool
             first = _dec.sample_logits_batched(last, p_lens - 1, r_temp,
                                                r_keys, r_topk, r_topp)
-            out = [first, pool]
+            out = [first if not aux else
+                   jnp.concatenate([first, _moe_unit_counters(aux)]), pool]
             if draft is not None:
                 # the draft pool is always full-view (non-rolling): its
                 # prefill runs arena-direct per-row whatever the target's
@@ -1879,16 +1911,22 @@ class ServingEngine:
         model, draft = self.model, self._draft_model
         page, t_view, d_view = self.block_size, self._t_view, self.max_len
 
-        def stage(params, pool, toks, offset, p_len, row_bt, slot):
+        def stage(params, pool, toks, offset, p_len, row_bt, slot,
+                  moe=None):
             pv = _dec.PagedView(row_bt, page, t_view, floor=offset,
                                 ceil=p_len, qcap=p_len - 1)
             # per-slot (recurrent) state is carried from unit to unit in
             # the slot itself: the decode step leaves a prefilling slot's
-            # state alone (its row is not live)
+            # state alone (its row is not live).  ``moe``: the experts'
+            # counters of the request's units so far (None: the model has
+            # no experts), carried on to the final chunk, which hands them
+            # to the host
+            aux = None if moe is None else []
             _, pool = _dec._forward(
                 model, params, pool, toks, offset, paged=pv,
-                rows=_dec.RowView(slots=jnp.reshape(slot, (1,))))
-            return pool
+                rows=_dec.RowView(slots=jnp.reshape(slot, (1,))), aux=aux)
+            return pool, (None if moe is None
+                          else moe + _moe_unit_counters(aux))
 
         if draft is None:
             return jax.jit(stage, donate_argnums=(1,))
@@ -1918,16 +1956,18 @@ class ServingEngine:
         def final(params, dparams, pool, dpool, bt, dbt, tok, pos, act,
                   temp, topk, topp, keys, toks, slot, offset, p_len,
                   last_idx, row_bt, row_dbt, r_temp, r_topk, r_topp,
-                  r_key):
+                  r_key, moe=None):
             pv = _dec.PagedView(row_bt, page, t_view, floor=offset,
                                 ceil=p_len, qcap=p_len - 1)
+            aux = None if moe is None else []
             logits, pool = _dec._forward(
                 model, params, pool, toks, offset, paged=pv,
-                rows=_dec.RowView(slots=jnp.reshape(slot, (1,))))
+                rows=_dec.RowView(slots=jnp.reshape(slot, (1,))), aux=aux)
             first = _dec.sample_logits_batched(
                 logits[0, last_idx][None], p_len - 1, r_temp, r_key,
                 r_topk, r_topp)
-            out = [first, pool]
+            out = [first if moe is None else jnp.concatenate(
+                [first, moe + _moe_unit_counters(aux)]), pool]
             if draft is not None:
                 pv_d = _dec.PagedView(row_dbt, page, d_view, floor=offset,
                                       ceil=p_len, qcap=p_len - 1)
@@ -1951,11 +1991,11 @@ class ServingEngine:
 
         def run(params, pool, bt, tok, pos, act, temp, topk, topp, keys,
                 toks, slot, offset, p_len, last_idx, row_bt,
-                r_temp, r_topk, r_topp, r_key):
+                r_temp, r_topk, r_topp, r_key, moe=None):
             return final(params, None, pool, None, bt, None, tok, pos,
                          act, temp, topk, topp, keys, toks, slot, offset,
                          p_len, last_idx, row_bt, None, r_temp, r_topk,
-                         r_topp, r_key)
+                         r_topp, r_key, moe)
 
         return jax.jit(run, donate_argnums=(1, 2))
 
@@ -3036,6 +3076,7 @@ class ServingEngine:
             else:
                 job = _PrefillJob(h, bt=bt_d, dbt=dbt_d)
                 job.written = plan.matched
+                job.moe = self._moe_zero
         else:
             staging = init_cache(self.model, 1, self.max_len)
             d_staging = (init_cache(self._draft_model, 1, self.max_len)
@@ -3082,9 +3123,9 @@ class ServingEngine:
                             self.d_caches, toks_d, off_vec, plen_vec,
                             job.bt, job.dbt)
                     else:
-                        self.caches = self._stage_fn(width)(
+                        self.caches, job.moe = self._stage_fn(width)(
                             self.params, self.caches, toks_d, off_vec,
-                            plen_vec, job.bt, slot)
+                            plen_vec, job.bt, slot, job.moe)
                 elif self._draft_model is not None:
                     job.staging, job.d_staging = self._stage_fn(width)(
                         self.params, self._draft_params, job.staging,
@@ -3103,7 +3144,7 @@ class ServingEngine:
                         first = self._apply_state(self._final_fn(width)(
                             *self._prog_args(), toks_d, slot, off_vec,
                             plen_vec, real - 1, job.bt,
-                            *self._sampling_row(h)))
+                            *self._sampling_row(h), job.moe))
                 elif self.paged:  # rolling: staged chunks, block-table commit
                     if self._draft_model is not None:
                         first = self._apply_state(self._final_fn(width)(
@@ -3511,7 +3552,7 @@ class ServingEngine:
         self.stats["sampler_filter_steps"] += sample == "filter"
         with span("serve.decode_dispatch", active=len(entries), step=step,
                   attn=self._decode_attn, sample=sample,
-                  state="kv+recurrent" if self._recurrent else "kv"):
+                  state=self._state_kinds):
             if self._draft_model is not None:
                 # speculative round: k draft steps + one batched verify in
                 # ONE program; rows commit 1..spec_len+1 tokens each, packed
@@ -3555,6 +3596,11 @@ class ServingEngine:
                     self.stats["moe_experts_touched"] += int(touched)
                     self.stats["moe_load_max"] += int(fullest)
                     self.stats["moe_layer_steps"] += self._moe_layers
+                elif kind == "prefill" and self._moe_prefill:
+                    held, touched, _, units = vals[-4:]
+                    self.stats["moe_prefill_assignments_held"] += int(held)
+                    self.stats["moe_prefill_experts_touched"] += int(touched)
+                    self.stats["moe_prefill_layer_units"] += int(units)
                 for i, (slot, h) in enumerate(entries):
                     if h.finish is not None or self._handles[slot] is not h:
                         continue
@@ -4000,12 +4046,12 @@ class ServingEngine:
                                 *self._prog_args(), toks, self.num_slots,
                                 off, plen, 0, bt1, null_dbt[:1], *one))
                         else:
-                            self.caches = self._stage_fn(width)(
+                            self.caches, moe = self._stage_fn(width)(
                                 self.params, self.caches, toks, off, plen,
-                                bt1, self.num_slots)
+                                bt1, self.num_slots, self._moe_zero)
                             self._apply_state(self._final_fn(width)(
                                 *self._prog_args(), toks, self.num_slots,
-                                off, plen, 0, bt1, *one))
+                                off, plen, 0, bt1, *one, moe))
                         continue
                     staging = init_cache(self.model, 1, self.max_len)
                     if self._draft_model is not None:

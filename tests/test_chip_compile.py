@@ -16,6 +16,7 @@ here.
 """
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -287,20 +288,130 @@ def test_kda_decode_compiles_for_v5e(one_chip):
                 if " copy(" in line and state in line.split(" copy(")[0]]
 
 
-@pytest.mark.parametrize("m,k,n", [(1024, 4096, 2560), (1024, 1280, 4096),
-                                   (2048, 4096, 2560), (2048, 1280, 4096)],
-                         ids=lambda x: str(x))
-def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n):
-    """The experts' grouped matmuls (gate/up, down) over the 40 held experts
-    at the published widths: a decode step's 128 x 8 assignments and a
-    prefill unit's 256 x 8."""
-    from distkeras_tpu.ops.experts import grouped_matmul
+@pytest.mark.parametrize("m,k,n,e", [
+    (1024, 4096, 2560, 40), (1024, 1280, 4096, 40), (2048, 4096, 2560, 40),
+    (2048, 1280, 4096, 40),
+    # serve-context-nemotron3n: 64 held experts of 2,688 x 1,856, widths no
+    # usual tile divides; a decode step's 256 x 6 and a unit's 1,024 x 6
+    (1536, 2688, 1856, 64), (1536, 1856, 2688, 64), (6144, 2688, 1856, 64),
+    (6144, 1856, 2688, 64)], ids=lambda x: str(x))
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, e):
+    """The experts' grouped matmuls (in, out) over the held experts at the
+    published widths of both expert configurations: a decode step's
+    assignments and a prefill unit's.  Tiles stay whole multiples of 128 or
+    one whole width (``_tiling``): never 128 at a width of thousands."""
+    from distkeras_tpu.ops.experts import _tiling, grouped_matmul
     S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
     bf = jnp.bfloat16
+    assert min(_tiling(k, n)[1:]) >= 896
     text = jax.jit(functools.partial(grouped_matmul, kernel=True)).lower(
-        S((m, k), bf), S((40, k, n), bf), S((40,), jnp.int32)
+        S((m, k), bf), S((e, k, n), bf), S((e,), jnp.int32)
     ).compile().as_text()
     assert text.count(KERNEL) == 1
+
+
+# -- the state-space serving cell's programs at its published shapes ---------
+
+def test_ssd_decode_compiles_for_v5e(one_chip):
+    """The fused decode step of a Mamba-2 layer over the cell's 256 slots of
+    64 x 64 x 128 float32 state: one kernel, the donated state updated in
+    place (no copy of it anywhere in the program)."""
+    from distkeras_tpu.ops.ssd import kernel_tiles, ssd_decode
+    b, h, p, n, g = 256, 64, 64, 128, 8
+    assert kernel_tiles((b, h, p, n), jnp.float32)
+
+    def step(x, dt, a, bm, cm, state, live):
+        return ssd_decode(x, dt, a, bm, cm, state, live, interpret=False)
+
+    S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    f32 = jnp.float32
+    text = jax.jit(step, donate_argnums=(5,)).lower(
+        S((b, h, p), f32), S((b, h), f32), S((h,), f32), S((b, g, n), f32),
+        S((b, g, n), f32), S((b, h, p, n), f32),
+        S((b,), jnp.bool_)).compile().as_text()
+    assert text.count(KERNEL) == 1
+    state = f"[{b},{h},{p},{n}]"
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and state in line.split(" copy(")[0]]
+
+
+@pytest.fixture(scope="module")
+def nemotronh(one_chip):
+    """``serve-context-nemotron3n``'s model, its parameters and its paged
+    pool as shapes on the described chip (nothing is made)."""
+    from benchmarks.lib import manifest as mf, program_nemotronh
+    from distkeras_tpu.core import decode as dec
+    cfg = mf.load_json(os.path.join(mf.BENCH_DIR, "configs",
+                                    "nemotron3-nano-30b-a3b.json"))
+    eng = cfg["deployment"]["engine"]
+    model = program_nemotronh.build_model(cfg)
+    on_chip = lambda a, dt=None: jax.ShapeDtypeStruct(
+        a.shape, dt or a.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a, jnp.bfloat16),
+        jax.eval_shape(lambda k: model.init(k, (8,)), jax.random.PRNGKey(0)))
+    pool = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: dec.init_paged_arena(model, eng["kv_blocks"],
+                                     eng["block_size"],
+                                     num_slots=eng["num_slots"])))
+    return model, params, pool, eng
+
+
+@pytest.mark.parametrize("program", ["decode", "stage_1024"])
+def test_the_state_space_cells_programs_compile_for_v5e(
+        one_chip, nemotronh, monkeypatch, program):
+    """The decode step over 256 slots (4 ``ssd_decode``, 1 ``paged_decode``
+    at 16 query heads a KV head, 8 grouped matmuls) and a 1,024-token
+    prefill unit at a 9,216-position view (the chunked scan in XLA; 6
+    grouped matmuls: a stage unit's logits are dead, so the last layer's
+    experts are routed and counted but not computed), as ``ServingEngine``
+    builds them, at the published
+    widths with 64 held experts: they compile, and arguments and
+    temporaries fit a chip's 16 GB with room."""
+    from distkeras_tpu.core import decode as dec
+    # the dispatch rules ask the backend (the CPU here); the program is
+    # compiled for the chip, so they are given the chip's answer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model, params, pool, eng = nemotronh
+    page, view, slots = eng["block_size"], eng["max_len"], eng["num_slots"]
+    S = lambda shp, dt=jnp.int32: jax.ShapeDtypeStruct(shp, dt,
+                                                       sharding=one_chip)
+    tables = view // page
+
+    def decode(params, pool, bt, tok, pos, active):
+        aux = []
+        logits, pool = dec.decode_step(
+            model, params, pool, tok, pos,
+            paged=dec.PagedView(bt, page, view),
+            rows=dec.RowView(live=active), aux=aux)
+        return jnp.argmax(logits, -1), pool, sum(aux)
+
+    def stage(params, pool, toks, offset, p_len, row_bt, slot):
+        pv = dec.PagedView(row_bt, page, view, floor=offset, ceil=p_len,
+                           qcap=p_len - 1)
+        aux = []
+        _, pool = dec._forward(
+            model, params, pool, toks, offset, paged=pv,
+            rows=dec.RowView(slots=jnp.reshape(slot, (1,))), aux=aux)
+        return pool, sum(aux)
+
+    if program == "decode":
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, pool, S((slots, tables)), S((slots,)), S((slots,)),
+            S((slots,), jnp.bool_)).compile()
+        kernels = 13
+    else:
+        compiled = jax.jit(stage, donate_argnums=(1,)).lower(
+            params, pool, S((1, 1024)), S((1,)), S((1,)), S((1, tables)),
+            S(())).compile()
+        kernels = 6
+    text = compiled.as_text()
+    assert text.count(KERNEL) == kernels
+    for scope in ("ssm/ssm_core", "moe/moe_experts", "attn/attn_core"):
+        assert scope in text
+    assert ("ssd_decode" if program == "decode" else "ssd_chunk") in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
 
 
 # -- kernels inside shard_map on the 2x2 mesh --------------------------------

@@ -379,6 +379,7 @@ def kernels_lowered():
     from distkeras_tpu.ops.flash_attention import flash_attention
     from distkeras_tpu.ops.fused_ce import fused_softmax_cross_entropy
     from distkeras_tpu.ops.kda import kda_decode
+    from distkeras_tpu.ops.ssd import ssd_decode
 
     def both(q, logits, labels):
         attn = flash_attention(q, q, q, causal=True, interpret=False)
@@ -389,6 +390,11 @@ def kernels_lowered():
         return kda_decode(vec, vec, vec, vec, vec[..., 0], state,
                           jnp.ones((1,), bool), interpret=False)
 
+    def ssd_step(vec, state):
+        return ssd_decode(vec, vec[..., 0], vec[0, :, 0], vec[:, :1],
+                          vec[:, :1], state, jnp.ones((1,), bool),
+                          interpret=False)
+
     q = jax.ShapeDtypeStruct((2, 128, 2, 64), jnp.bfloat16)
     logits = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     labels = jax.ShapeDtypeStruct((256,), jnp.int32)
@@ -397,6 +403,8 @@ def kernels_lowered():
     return (jax.jit(jax.grad(both, argnums=(0, 1))).trace(
         q, logits, labels).lower(lowering_platforms=("tpu",)).as_text()
         + jax.jit(step).trace(vec, state).lower(
+            lowering_platforms=("tpu",)).as_text()
+        + jax.jit(ssd_step).trace(vec, state).lower(
             lowering_platforms=("tpu",)).as_text())
 
 
