@@ -794,9 +794,11 @@ def parallel_lm_4(cfg, seed, on_tpu):
 def state_space(cfg, seed, on_tpu):
     """A stack of one-part layers (Mamba-2, experts, attention) through the
     paged engine: the recurrence's three forms agree on the device, the
+    grouped matmul over a weight stored transposed is the dense product, the
     decode program holds the ``ssd_decode`` kernel, and what the engine
-    serves (a bucket prompt and a chunked one, slots reused) is what the
-    model's own full forward puts first."""
+    serves (a bucket prompt and a chunked one, slots reused; its expert
+    up-projections held transposed) is what the model's own full forward
+    puts first."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -804,6 +806,7 @@ def state_space(cfg, seed, on_tpu):
     from distkeras_tpu.core.model import FittedModel
     from distkeras_tpu.models import hybrid_lm
     from distkeras_tpu.ops import ssd
+    from distkeras_tpu.ops.experts import grouped_matmul
     from distkeras_tpu.serving import ServingEngine
 
     rng = np.random.default_rng(seed)
@@ -834,13 +837,30 @@ def state_space(cfg, seed, on_tpu):
           f"ssd_decode vs ssd_step: {kerr}; dead slot touched: "
           f"{not bool((sk[1] == s0[1]).all())}")
 
+    # an expert width of 1.5 lane tiles under a hidden size of two: the
+    # engine holds such an up-projection (4, 256, 192) transposed, and the
+    # grouped matmul reads it so (on the chip: the kernel's transpose_rhs)
+    hidden, width = 256, 192
+    sizes = [40, 0, 31, 9]
+    rows, w_up = nrm(sum(sizes) + 16, hidden), nrm(4, hidden, width)
+    got = grouped_matmul(rows, jnp.swapaxes(w_up, 1, 2),
+                         jnp.asarray(sizes, jnp.int32), transpose_rhs=True)
+    ends = np.cumsum([0] + sizes)
+    want = jnp.concatenate(
+        [rows[lo:hi] @ w_up[e] for e, (lo, hi) in enumerate(
+            zip(ends[:-1], ends[1:]))] + [jnp.zeros((16, width))])
+    gerr = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    check(gerr < 1e-2, f"grouped matmul over a transposed weight vs the "
+          f"dense products: {gerr} of the largest")
+
     config = dict(
         hybrid_override_pattern="ME*ME", num_hidden_layers=5,
-        hidden_size=256, vocab_size=512, layer_norm_epsilon=1e-5,
+        hidden_size=hidden, vocab_size=512, layer_norm_epsilon=1e-5,
         mamba_num_heads=8, mamba_head_dim=64, ssm_state_size=128,
         n_groups=2, conv_kernel=4, chunk_size=64,
         num_attention_heads=4, num_key_value_heads=2, head_dim=64,
-        n_routed_experts=8, num_experts_per_tok=2, moe_intermediate_size=128,
+        n_routed_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=width,
         n_shared_experts=1, moe_shared_expert_intermediate_size=256,
         routed_scaling_factor=2.5)
     model = hybrid_lm(config, compute_dtype="float32", held=(0, 4))
@@ -848,6 +868,8 @@ def state_space(cfg, seed, on_tpu):
     eng = ServingEngine(FittedModel(model, params), num_slots=2, max_len=256,
                         paged=True, block_size=16, kv_blocks=64,
                         prefill_chunk=64)
+    held_t = eng.stats["moe_up_projections_transposed"]
+    check(held_t == 2, f"{held_t} of 2 up-projections held transposed")
     found = require_kernels(
         kernel_names(eng._decode_fn.lower(
             eng.params, *eng._state_args()).as_text()),
@@ -871,6 +893,8 @@ def state_space(cfg, seed, on_tpu):
           "forward's best logit")
     return dict(ssd_chunk_vs_recurrence_rel_err=err,
                 ssd_decode_vs_step_abs_err=kerr, ssd_decode_kernel=found,
+                gmm_transposed_vs_dense_rel_err=gerr,
+                up_projections_transposed=held_t,
                 state_kinds=eng._state_kinds, served_token_worst_gap=worst,
                 slot_requests=eng.stats["slot_requests"],
                 prefill_chunks=eng.stats["prefill_chunks"])

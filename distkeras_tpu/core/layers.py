@@ -153,6 +153,15 @@ class Layer:
               rng=None):  # pragma: no cover - abstract
         raise NotImplementedError
 
+    def store_for_serving(self, params):
+        """``(params, n)``: this layer's parameters as a ``ServingEngine``
+        should hold them, and how many weights of them are held in another
+        form than ``init`` makes.  Idempotent; what comes back is read by
+        the layer's own forward and by nothing else (``get_weights``,
+        checkpoints and training keep ``init``'s form).  Most layers are
+        served as they are."""
+        return params, 0
+
     def __repr__(self):
         cfg = {k: v for k, v in self.get_config().items() if k != "kind"}
         args = ", ".join(f"{k}={v!r}" for k, v in cfg.items())
@@ -1165,7 +1174,16 @@ class SparseMoE(Layer):
     selection only), and the chosen experts' UNBIASED scores, renormalised
     to 1, times ``router_scale`` are the weights.  ``expert_form``
     ``"relu2"``: every expert (the shared one too) is ``relu(x W_up)^2
-    W_down``, no gate."""
+    W_down``, no gate.
+
+    Parameters: the held experts' up-projections are ``w_in`` of shape
+    ``(E, D, cols * F)`` (``cols`` 2 for a gated form, gate then up; 1 for
+    ``relu2``) and their down-projections ``w_out`` ``(E, F, D)``.  That is
+    what ``init`` makes and what ``get_weights`` / ``set_weights``,
+    checkpoints and training see.  ``store_for_serving`` alone replaces
+    ``w_in`` by its transpose ``w_in_t`` ``(E, cols * F, D)`` where the
+    chip would otherwise relay the weight before every grouped matmul
+    (``serves_transposed``); ``mix`` reads whichever it is given."""
 
     routes_tokens = True
     #: class-level defaults: configs written before these fields existed
@@ -1221,6 +1239,30 @@ class SparseMoE(Layer):
             params["shared_out"] = init_weight(k_so, (self.shared_dim, d))
         return params, tuple(in_shape)
 
+    @staticmethod
+    def serves_transposed(shape) -> bool:
+        """Whether an up-projection of ``shape`` ``(E, D, cols * F)`` is
+        served transposed.  A TPU keeps an array's minor-most axis in whole
+        tiles of 128 lanes and chooses which axis that is: where ``cols * F``
+        is not a whole number of them and ``D`` is, it keeps ``D`` minor-most
+        (Nemotron-3-Nano's ``(64, 2688, 1856)``), the grouped matmul reads
+        its weight row-major, and XLA copies the whole weight into that
+        layout before every call.  Stored ``(E, cols * F, D)`` the chip's
+        choice IS row-major (as ``w_out`` shows) and the kernel reads it in
+        place.  Any other shape (Solar-Open2's ``(40, 4096, 2560)``) is
+        row-major as it is."""
+        _, d, f = shape
+        return d % 128 == 0 and f % 128 != 0
+
+    def store_for_serving(self, params):
+        if "w_in_t" in params:
+            return params, 1
+        if not self.serves_transposed(params["w_in"].shape):
+            return params, 0
+        held = {k: v for k, v in params.items() if k != "w_in"}
+        held["w_in_t"] = jnp.swapaxes(params["w_in"], 1, 2)
+        return held, 1
+
     def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
               rng=None):
         return self.mix(params, x, compute_dtype=compute_dtype)[0]
@@ -1250,8 +1292,10 @@ class SparseMoE(Layer):
                     chosen, weights, self.held, live)
                 rows = flat.astype(compute_dtype)[token]
             with jax.named_scope("moe_experts"):
+                stored = "w_in_t" in params     # store_for_serving's form
                 h = ops.grouped_matmul(
-                    rows, params["w_in"].astype(compute_dtype), sizes)
+                    rows, params["w_in_t" if stored else "w_in"].astype(
+                        compute_dtype), sizes, transpose_rhs=stored)
                 h = _EXPERT_FORMS[self.expert_form][0](h).astype(
                     compute_dtype)
                 out = ops.grouped_matmul(
@@ -1281,7 +1325,7 @@ class HybridBlock(Layer):
     as their configs (the spec stays JSON-serialisable) and rebuilt on use.
     What the cached step and the serving engine need to know of a block they
     ask the block (``state_kind``, ``routes_tokens``, ``wants_token_mask``,
-    ``int8_weights``), never its class."""
+    ``int8_weights``, ``store_for_serving``), never its class."""
 
     #: ``core.quant.quantize_params`` finds matmul weights by
     #: ``TransformerBlock``'s names and would leave these as they are
@@ -1325,6 +1369,12 @@ class HybridBlock(Layer):
     @property
     def causal(self) -> bool:
         return True
+
+    def store_for_serving(self, params):
+        if not self.ffn_config:
+            return params, 0
+        ffn, n = self.ffn().store_for_serving(params["ffn"])
+        return {**params, "ffn": ffn}, n
 
     def init(self, rng, in_shape):
         k_m, k_f = jax.random.split(rng)

@@ -85,15 +85,21 @@ def _tiling(k: int, n: int):
     return _TILE_M, fit(k), fit(n)
 
 
-def grouped_matmul(x, w, group_sizes, kernel=None):
+def grouped_matmul(x, w, group_sizes, kernel=None, transpose_rhs=False):
     """``x[rows of group g] @ w[g]`` for every group, float32 out.  ``x``:
-    (M, K) sorted by group; ``w``: (G, K, N); ``group_sizes``: (G,) int32
-    summing to at most M (rows past the sum come back 0).  On a TPU this is
-    the Pallas grouped matmul of ``jax.experimental.pallas.ops.tpu.megablox``
-    — it visits (group, row-tile) pairs that hold rows and no others, so an
-    expert no token chose costs no weight read; elsewhere ``lax.ragged_dot``
-    (``kernel``: None asks the backend)."""
-    m = x.shape[0]
+    (M, K) sorted by group; ``w``: (G, K, N), or with ``transpose_rhs`` the
+    same matrices stored (G, N, K) — the form ``SparseMoE`` holds an
+    up-projection in for serving where the chip would keep (G, K, N) with
+    ``K`` minor-most and relay it before every call (PERF.md section 6,
+    PR 35); the tiles are chosen from (K, N) either way.  ``group_sizes``:
+    (G,) int32 summing to at most M (rows past the sum come back 0).  On a
+    TPU this is the Pallas grouped matmul of
+    ``jax.experimental.pallas.ops.tpu.megablox`` — it visits (group,
+    row-tile) pairs that hold rows and no others, so an expert no token
+    chose costs no weight read; elsewhere ``lax.ragged_dot`` (``kernel``:
+    None asks the backend)."""
+    m, k = x.shape
+    n = w.shape[1] if transpose_rhs else w.shape[2]
     if kernel is None:
         kernel = jax.default_backend() == "tpu"
     if kernel:
@@ -101,10 +107,11 @@ def grouped_matmul(x, w, group_sizes, kernel=None):
         pad = -m % _TILE_M      # whole row tiles; the added rows are past
         if pad:                 # the groups' sum and are never visited
             x = jnp.pad(x, ((0, pad), (0, 0)))
-        out = gmm(x, w, group_sizes, jnp.float32,
-                  _tiling(x.shape[1], w.shape[2]))[:m]
+        out = gmm(x, w, group_sizes, jnp.float32, _tiling(k, n),
+                  transpose_rhs=transpose_rhs)[:m]
     else:
-        out = jax.lax.ragged_dot(x, w, group_sizes,
-                                 preferred_element_type=jnp.float32)
+        out = jax.lax.ragged_dot(
+            x, jnp.swapaxes(w, 1, 2) if transpose_rhs else w, group_sizes,
+            preferred_element_type=jnp.float32)
     live = jnp.arange(m) < jnp.sum(group_sizes)
     return jnp.where(live[:, None], out, 0.0)

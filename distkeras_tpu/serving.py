@@ -914,9 +914,9 @@ class ServingEngine:
                  role: str = "unified",
                  tenants: Optional[List[TenantPolicy]] = None):
         if isinstance(model, FittedModel):
-            self.model, self.params = model.model, model.params
+            self.model, params = model.model, model.params
         else:
-            self.model, self.params = model
+            self.model, params = model
         _check_supported(self.model)
         if rolling:
             _validate_rolling(self.model)
@@ -1028,18 +1028,18 @@ class ServingEngine:
                     f"target and draft vocabularies differ: {tv} vs {dv} — "
                     f"draft proposals would be meaningless")
         # quantize weights ONCE at construction; attach_ps re-quantizes
-        # every pulled center through the same path.  The f32 skeleton
-        # (scalar zeros of the pre-quant dtypes) is what set_weights maps
-        # a pulled flat weight list onto before re-quantization
+        # every pulled center through the same path.  The skeleton (scalar
+        # zeros of the dtypes the parameters came in) is what set_weights
+        # maps a pulled flat weight list onto before the engine takes it
+        # for its own (re-quantization, the ``params`` setter)
+        self._fp_skel = tmap(
+            lambda x: np.zeros((), x.dtype if hasattr(x, "dtype")
+                               else np.asarray(x).dtype), params)
         if quantize is not None:
-            self._fp_skel = tmap(lambda x: np.zeros((), np.asarray(x).dtype),
-                                 self.params)
-            self.params = _quantize_weights(self.params, quantize)
+            params = _quantize_weights(params, quantize)
             if self._draft_params is not None:
                 self._draft_params = _quantize_weights(self._draft_params,
                                                        quantize)
-        else:
-            self._fp_skel = None
         self.num_slots = int(num_slots)
         if self.num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
@@ -1195,9 +1195,6 @@ class ServingEngine:
         #: "kernel" (ops.paged_attention, in place) or "gather" — settled
         #: where the program is built, reported on serve.decode_dispatch
         self._decode_attn = "gather"
-        # params live on device once: the decode loop must not re-ship
-        # the weights (or anything else) host→device per iteration
-        self.params = jax.device_put(self.params)
         self._dev_tok = jnp.zeros((self.num_slots,), jnp.int32)
         self._dev_pos = jnp.zeros((self.num_slots,), jnp.int32)
         self._dev_act = jnp.zeros((self.num_slots,), bool)
@@ -1367,7 +1364,11 @@ class ServingEngine:
             # unit) pairs summed over
             "moe_prefill_assignments_held": 0,
             "moe_prefill_experts_touched": 0, "moe_prefill_layer_units": 0,
+            # expert up-projections held transposed, in the layout the
+            # grouped matmul reads (set by the ``params`` setter)
+            "moe_up_projections_transposed": 0,
         }
+        self.params = params
         if self.paged:
             # a recurrent layer's state is not in the blocks: a matched
             # prefix would skip tokens that state has to see, so the radix
@@ -3847,6 +3848,28 @@ class ServingEngine:
         """The :class:`EngineDead` that killed this engine, or None."""
         return self._dead
 
+    @property
+    def params(self):
+        """The weights as the engine holds them: on the device, once (the
+        decode loop ships nothing host→device per iteration), each layer's
+        in the form its ``store_for_serving`` gives — an expert layer's
+        up-projection transposed where the grouped matmul would otherwise
+        read it through a relayout copy
+        (``stats["moe_up_projections_transposed"]`` counts them).  ASSIGN
+        parameters in the model's own layout (``Sequential.init``'s, what
+        ``set_weights`` makes) or as read from an engine: construction, the
+        parameter-server reload and ``respawn_clone`` all come through this
+        one setter, and the form given in is not kept."""
+        return self._params
+
+    @params.setter
+    def params(self, params):
+        stored = [layer.store_for_serving(p)
+                  for layer, p in zip(self.model.layers, params)]
+        self._params = jax.device_put([p for p, _ in stored])
+        self.stats["moe_up_projections_transposed"] = sum(
+            n for _, n in stored)
+
     def respawn_clone(self) -> "ServingEngine":
         """A fresh engine over the same model/params and knobs — new KV
         slot pool, empty queue, fresh stats (the ``EngineSupervisor``
@@ -3871,12 +3894,11 @@ class ServingEngine:
             paged=self.paged, block_size=self.block_size,
             kv_blocks=self.kv_blocks, role=self.role,
             tenants=tenant_pols or None)
-        # quantized clones re-quantize idempotently; the f32 skeleton the
-        # hot-reload path maps pulled weights onto carries over as-is
-        # (the clone's params are already quantized, so it could not
-        # rebuild the pre-quant dtypes itself)
-        if self._fp_skel is not None:
-            eng._fp_skel = self._fp_skel
+        # quantized clones re-quantize and re-store idempotently; the
+        # skeleton the hot-reload path maps pulled weights onto carries over
+        # as-is (the clone's params are already quantized and stored for
+        # serving, so it could not rebuild the model's own layout itself)
+        eng._fp_skel = self._fp_skel
         if self._ps_addr is not None:
             eng.attach_ps(*self._ps_addr, every=self._reload_every,
                           retry_policy=self._reload_policy,
@@ -4223,20 +4245,16 @@ class ServingEngine:
                 networking.send_opcode(self._reload_sock, b"p")
                 msg = networking.recv_data(self._reload_sock,
                                            pool=self._reload_pool)
+            # the skeleton maps the flat wire list back onto the model's
+            # own pytree; the pulled center then takes the SAME path the
+            # constructor's parameters took — never raw fp32 weights into a
+            # quantized engine, and the ``params`` setter keeps them in the
+            # serving form and device-resident (the decode loop's
+            # zero-upload contract must survive a reload)
+            fresh = self.model.set_weights(self._fp_skel, msg["weights"])
             if self.quantize is not None:
-                # re-quantize the pulled center through the SAME path the
-                # constructor used — never swap raw fp32 weights into a
-                # quantized engine (the f32 skeleton maps the flat wire
-                # list back onto the pre-quant pytree first)
-                fresh = self.model.set_weights(self._fp_skel,
-                                               msg["weights"])
-                self.params = _quantize_weights(fresh, self.quantize)
-            else:
-                self.params = self.model.set_weights(self.params,
-                                                     msg["weights"])
-            # keep the weights device-resident: the decode loop's
-            # zero-upload contract must survive a reload
-            self.params = jax.device_put(self.params)
+                fresh = _quantize_weights(fresh, self.quantize)
+            self.params = fresh
             self.stats["weight_reloads"] += 1
             self.stats["reloads"] += 1
             clock = msg.get("clock") if isinstance(msg, dict) else None

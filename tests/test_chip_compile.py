@@ -60,6 +60,14 @@ def _compiled_text(fn, *structs):
     return jax.jit(fn).lower(*structs).compile().as_text()
 
 
+def _copies_of(text, *shapes):
+    """The program's ``copy`` instructions whose result has one of
+    ``shapes``: a whole array relaid before something reads it."""
+    names = ["[" + ",".join(map(str, shp)) + "]" for shp in shapes]
+    return [line for line in text.splitlines() if " copy(" in line
+            and any(n in line.split(" copy(")[0] for n in names)]
+
+
 # -- flash attention ---------------------------------------------------------
 
 FLASH_SHAPES = [  # (B, S, H, Dh), dtype
@@ -283,31 +291,40 @@ def test_kda_decode_compiles_for_v5e(one_chip):
         vec, vec, vec, vec, S((b, h), f32), S((b, h, d, d), f32),
         S((b,), jnp.bool_)).compile().as_text()
     assert text.count(KERNEL) == 1
-    state = f"[{b},{h},{d},{d}]"
-    assert not [line for line in text.splitlines()
-                if " copy(" in line and state in line.split(" copy(")[0]]
+    assert not _copies_of(text, (b, h, d, d))
 
 
-@pytest.mark.parametrize("m,k,n,e", [
-    (1024, 4096, 2560, 40), (1024, 1280, 4096, 40), (2048, 4096, 2560, 40),
-    (2048, 1280, 4096, 40),
+@pytest.mark.parametrize("m,k,n,e,stored", [
+    (1024, 4096, 2560, 40, "kn"), (1024, 1280, 4096, 40, "kn"),
+    (2048, 4096, 2560, 40, "kn"), (2048, 1280, 4096, 40, "kn"),
     # serve-context-nemotron3n: 64 held experts of 2,688 x 1,856, widths no
     # usual tile divides; a decode step's 256 x 6 and a unit's 1,024 x 6
-    (1536, 2688, 1856, 64), (1536, 1856, 2688, 64), (6144, 2688, 1856, 64),
-    (6144, 1856, 2688, 64)], ids=lambda x: str(x))
-def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, e):
+    (1536, 2688, 1856, 64, "kn"), (1536, 1856, 2688, 64, "kn"),
+    (6144, 2688, 1856, 64, "kn"), (6144, 1856, 2688, 64, "kn"),
+    # its up-projections as the engine holds them: (E, N, K)
+    (1536, 2688, 1856, 64, "nk"), (6144, 2688, 1856, 64, "nk")],
+    ids=lambda x: str(x))
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, e, stored):
     """The experts' grouped matmuls (in, out) over the held experts at the
     published widths of both expert configurations: a decode step's
     assignments and a prefill unit's.  Tiles stay whole multiples of 128 or
-    one whole width (``_tiling``): never 128 at a width of thousands."""
+    one whole width (``_tiling``): never 128 at a width of thousands.  One
+    kernel, and the whole weight is copied into another layout first exactly
+    where ``SparseMoE.serves_transposed`` says the model's own form would
+    be: never in the form the engine holds."""
+    from distkeras_tpu.core.layers import SparseMoE
     from distkeras_tpu.ops.experts import _tiling, grouped_matmul
     S = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
     bf = jnp.bfloat16
     assert min(_tiling(k, n)[1:]) >= 896
-    text = jax.jit(functools.partial(grouped_matmul, kernel=True)).lower(
-        S((m, k), bf), S((e, k, n), bf), S((e,), jnp.int32)
-    ).compile().as_text()
+    text = jax.jit(functools.partial(
+        grouped_matmul, kernel=True, transpose_rhs=stored == "nk")).lower(
+        S((m, k), bf), S((e, k, n) if stored == "kn" else (e, n, k), bf),
+        S((e,), jnp.int32)).compile().as_text()
     assert text.count(KERNEL) == 1
+    relaid = bool(_copies_of(text, (e, k, n), (e, n, k)))
+    assert relaid == (stored == "kn" and SparseMoE.serves_transposed(
+        (e, k, n)))
 
 
 # -- the state-space serving cell's programs at its published shapes ---------
@@ -330,15 +347,14 @@ def test_ssd_decode_compiles_for_v5e(one_chip):
         S((b, g, n), f32), S((b, h, p, n), f32),
         S((b,), jnp.bool_)).compile().as_text()
     assert text.count(KERNEL) == 1
-    state = f"[{b},{h},{p},{n}]"
-    assert not [line for line in text.splitlines()
-                if " copy(" in line and state in line.split(" copy(")[0]]
+    assert not _copies_of(text, (b, h, p, n))
 
 
 @pytest.fixture(scope="module")
 def nemotronh(one_chip):
-    """``serve-context-nemotron3n``'s model, its parameters and its paged
-    pool as shapes on the described chip (nothing is made)."""
+    """``serve-context-nemotron3n``'s model, its parameters AS THE ENGINE
+    HOLDS THEM (every layer's ``store_for_serving``) and its paged pool as
+    shapes on the described chip (nothing is made)."""
     from benchmarks.lib import manifest as mf, program_nemotronh
     from distkeras_tpu.core import decode as dec
     cfg = mf.load_json(os.path.join(mf.BENCH_DIR, "configs",
@@ -348,8 +364,11 @@ def nemotronh(one_chip):
     on_chip = lambda a, dt=None: jax.ShapeDtypeStruct(
         a.shape, dt or a.dtype, sharding=one_chip)
     params = jax.tree_util.tree_map(
-        lambda a: on_chip(a, jnp.bfloat16),
-        jax.eval_shape(lambda k: model.init(k, (8,)), jax.random.PRNGKey(0)))
+        lambda a: on_chip(a, jnp.bfloat16), jax.eval_shape(
+            lambda k: [layer.store_for_serving(p)[0] for layer, p in zip(
+                model.layers, model.init(k, (8,)))], jax.random.PRNGKey(0)))
+    assert [p["ffn"]["w_in_t"].shape for p in params
+            if "ffn" in p] == [(64, 1856, 2688)] * 4
     pool = jax.tree_util.tree_map(on_chip, jax.eval_shape(
         lambda: dec.init_paged_arena(model, eng["kv_blocks"],
                                      eng["block_size"],
@@ -366,8 +385,11 @@ def test_the_state_space_cells_programs_compile_for_v5e(
     grouped matmuls: a stage unit's logits are dead, so the last layer's
     experts are routed and counted but not computed), as ``ServingEngine``
     builds them, at the published
-    widths with 64 held experts: they compile, and arguments and
-    temporaries fit a chip's 16 GB with room."""
+    widths with 64 held experts: they compile, arguments and temporaries
+    fit a chip's 16 GB with room, and NO expert weight is copied into
+    another layout on its way to the grouped matmul (638 MB a layer in every
+    run before PR 35, which was the decode program's 0.70 GB of
+    temporaries)."""
     from distkeras_tpu.core import decode as dec
     # the dispatch rules ask the backend (the CPU here); the program is
     # compiled for the chip, so they are given the chip's answer
@@ -410,8 +432,11 @@ def test_the_state_space_cells_programs_compile_for_v5e(
     for scope in ("ssm/ssm_core", "moe/moe_experts", "attn/attn_core"):
         assert scope in text
     assert ("ssd_decode" if program == "decode" else "ssd_chunk") in text
+    assert not _copies_of(text, (64, 2688, 1856), (64, 1856, 2688))
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12e9
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 0.2e9
 
 
 # -- kernels inside shard_map on the 2x2 mesh --------------------------------

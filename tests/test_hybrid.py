@@ -247,6 +247,103 @@ def test_dispatch_sorts_held_assignments_and_counts_them():
     assert float(jnp.abs(weight[3:]).max()) == 0.0
 
 
+# -- an up-projection in the layout the grouped matmul reads -------------------
+
+@pytest.mark.parametrize("shape,transposed", [
+    ((64, 2688, 1856), True),     # Nemotron-3-Nano: 1,856 is 14.5 lane tiles
+    ((40, 4096, 2560), False),    # Solar-Open2: row-major as it is
+    ((64, 1856, 2688), False),    # the shape of a w_out, which the chip
+    ((4, 64, 32), False),         # keeps row-major; the tiny widths of both
+    ((4, 64, 64), False),         # configurations
+    ((4, 128, 32), True), ((4, 128, 96), True), ((4, 128, 192), True),
+    ((4, 128, 256), False), ((2, 256, 128), False)], ids=str)
+def test_the_shape_says_whether_an_up_projection_is_served_transposed(
+        shape, transposed):
+    assert SparseMoE.serves_transposed(shape) is transposed
+    e, d, f = shape
+    if e > 4:                     # shapes alone: nothing that size is made
+        return
+    moe = SparseMoE(e, 2, f, expert_form="relu2")
+    params, _ = moe.init(jax.random.PRNGKey(0), (3, d))
+    assert params["w_in"].shape == shape        # the contract init keeps
+    held, n = moe.store_for_serving(params)
+    assert n == int(transposed)
+    assert ("w_in_t" in held, "w_in" in held) == (transposed, not transposed)
+    if not transposed:
+        assert held is params
+    # the block hands it through, and a layer without experts has nothing
+    block = HybridBlock(ffn=moe)
+    stored, n = block.store_for_serving({"norm2": {}, "ffn": params})
+    assert n == int(transposed) and set(stored["ffn"]) == set(held)
+    assert HybridBlock(mixer=GatedAttention(2, 8)).store_for_serving(
+        {"mixer": {}}) == ({"mixer": {}}, 0)
+
+
+@pytest.mark.parametrize("case", ["all_held", "a_strict_share", "token_mask"])
+@pytest.mark.parametrize("form", ["gated_silu", "relu2"])
+def test_mix_on_stored_parameters_is_mix_on_the_models_own(form, case):
+    """The same assignments through ``(E, cols F, D)`` read transposed as
+    through ``(E, D, cols F)``: equal sums in another order of storage, and
+    storing twice is storing once."""
+    experts, d, f = 8, 128, 48
+    held = (2, 4) if case == "a_strict_share" else (0, experts)
+    moe = SparseMoE(experts, 3, f, held=held, shared_dim=32,
+                    expert_form=form)
+    params, _ = moe.init(jax.random.PRNGKey(1), (5, d))
+    cols = 2 if form == "gated_silu" else 1
+    assert params["w_in"].shape == (held[1], d, cols * f)
+    stored, n = moe.store_for_serving(params)
+    assert n == 1 and "w_in" not in stored
+    assert stored["w_in_t"].shape == (held[1], cols * f, d)
+    again, n2 = moe.store_for_serving(stored)
+    assert n2 == 1 and again is stored
+    rng = np.random.default_rng(3)
+    u = jnp.asarray(rng.normal(size=(2, 21, d)), jnp.float32)
+    mask = (jnp.asarray(rng.random((2, 21)) < 0.6)
+            if case == "token_mask" else None)
+    want, c0 = moe.mix(params, u, compute_dtype=jnp.float32, token_mask=mask)
+    got, c1 = moe.mix(stored, u, compute_dtype=jnp.float32, token_mask=mask)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert c0.tolist() == c1.tolist()
+    assert 0 < int(c0[0]) < 2 * 21 * 3 or case == "all_held"
+    # the layer's own terms are in it: without the experts it reads other
+    flat = dict(stored, w_in_t=jnp.zeros_like(stored["w_in_t"]))
+    y0, _ = moe.mix(flat, u, compute_dtype=jnp.float32, token_mask=mask)
+    assert float(jnp.abs(y0 - want).max()) > 1e-3
+
+
+def test_the_grouped_matmul_reads_either_form():
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.normal(size=(40, 16)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(3, 16, 24)), jnp.float32)
+    sizes = jnp.asarray([7, 0, 20], jnp.int32)
+    want = xops.grouped_matmul(x, w, sizes)
+    got = xops.grouped_matmul(x, jnp.swapaxes(w, 1, 2), sizes,
+                              transpose_rhs=True)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_allclose(want[:7], x[:7] @ w[0], atol=1e-5)
+    assert float(jnp.abs(got[27:]).max()) == 0.0
+
+
+def test_solars_engine_holds_its_up_projections_as_they_are(built):
+    """Tiny or published, Solar's widths are row-major on the chip as they
+    are: no leaf of the engine's is another array than the one given."""
+    _, _, _, model, params = built
+    eng = engine_of(built)
+    assert eng.stats["moe_up_projections_transposed"] == 0
+    assert jax.tree_util.tree_structure(eng.params) == \
+        jax.tree_util.tree_structure(list(params))
+    published = program_solar.build_model(mf.load_json(os.path.join(
+        mf.BENCH_DIR, "configs", "solar-open2-250b.json")))
+    shapes = jax.eval_shape(lambda k: published.init(k, (8,)),
+                            jax.random.PRNGKey(0))
+    stored = [layer.store_for_serving(p)
+              for layer, p in zip(published.layers, shapes)]
+    assert sum(n for _, n in stored) == 0
+    assert {p["ffn"]["w_in"].shape for p, _ in stored
+            if "ffn" in p} == {(40, 4096, 2560)}
+
+
 # -- the engine: two kinds of state in one manager ---------------------------
 
 def served_gaps(built, prompt, tokens):

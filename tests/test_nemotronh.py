@@ -37,7 +37,7 @@ from distkeras_tpu import metrics
 from distkeras_tpu.core import decode as dec
 from distkeras_tpu.core.layers import (HybridBlock, Mamba2Mixer,
                                        MultiHeadAttention, SparseMoE)
-from distkeras_tpu.core.model import FittedModel
+from distkeras_tpu.core.model import FittedModel, serialize_model
 from distkeras_tpu.models import hybrid_lm
 from distkeras_tpu.ops import experts as xops
 from distkeras_tpu.ops import ssd
@@ -443,6 +443,108 @@ def test_identical_prompts_share_nothing_and_answer_alike(built):
     assert list(h1.tokens) == list(h2.tokens) == list(h3.tokens)
     assert eng.stats["prefix_hits"] == 0
     assert eng.stats["prefill_tokens"] == 3 * 48
+
+
+# -- up-projections in the layout the grouped matmul reads ----------------------
+
+@pytest.fixture(scope="module")
+def built_wide():
+    """The tiny model at a hidden size of 128 lanes: its up-projections
+    ``(4, 128, 32)`` are of the kind the engine holds transposed, as the
+    published ``(64, 2688, 1856)`` are (``SparseMoE.serves_transposed``)."""
+    cfg = tiny_cfg()
+    cfg["hidden_size"] = 128
+    w = make_weights(cfg, 11, "float32")
+    return (cfg, dims(cfg), w, program_nemotronh.build_model(cfg),
+            program_nemotronh.to_program_layout(w))
+
+
+def _other_weights(built_wide):
+    """``built_wide`` with another draw of the weights."""
+    cfg = built_wide[0]
+    return built_wide[:4] + (program_nemotronh.to_program_layout(
+        make_weights(cfg, 12, "float32")),)
+
+
+def _built(built_wide):
+    return engine_of(built_wide)
+
+
+def _respawned(built_wide):
+    return engine_of(built_wide).respawn_clone()
+
+
+def _assigned(built_wide):
+    eng = engine_of(_other_weights(built_wide))
+    eng.params = built_wide[4]      # the model's own layout, as a tool has it
+    return eng
+
+
+def _reloaded(built_wide):
+    """Built on other weights, then one pull of ``built_wide``'s from a live
+    parameter server (the flat wire list is in the model's own layout), and
+    a clone of that engine after a pull of its own: the skeleton a pull is
+    mapped onto carries over."""
+    from distkeras_tpu.parameter_servers import (DeltaParameterServer,
+                                                 SocketParameterServer)
+    _, _, _, model, params = built_wide
+    ps = SocketParameterServer(DeltaParameterServer(
+        serialize_model(model, params)))
+    ps.start()
+    try:
+        eng = engine_of(_other_weights(built_wide))
+        eng.attach_ps("127.0.0.1", ps.port, every=10 ** 6)
+        clone = eng.respawn_clone()
+        for e in (eng, clone):
+            e._pull_weights()
+            assert e.stats["weight_reloads"] == 1
+            e._reload_sock.close()
+        np.testing.assert_array_equal(eng.params[2]["ffn"]["w_in_t"],
+                                      clone.params[2]["ffn"]["w_in_t"])
+        return clone
+    finally:
+        ps.stop()
+
+
+@pytest.mark.parametrize("door", [_built, _respawned, _assigned, _reloaded],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_every_door_to_the_engines_parameters_stores_them_for_serving(
+        built_wide, door):
+    """Construction, ``respawn_clone``, plain assignment and the
+    parameter-server reload: the engine holds every expert layer's
+    up-projection transposed and no other form of it, says so in ``stats``,
+    and serves the full forward's tokens on the model's own parameters."""
+    _, d, _, model, params = built_wide
+    eng = door(built_wide)
+    n_moe = d["kinds"].count("experts")
+    assert eng.stats["moe_up_projections_transposed"] == n_moe > 0
+    for layer, p, q in zip(model.layers, params, eng.params):
+        if isinstance(layer, HybridBlock) and layer.routes_tokens:
+            assert "w_in" not in q["ffn"]
+            assert q["ffn"]["w_in_t"].shape == (d["held"], d["expert_dim"],
+                                                d["hidden"])
+            np.testing.assert_array_equal(
+                q["ffn"]["w_in_t"], jnp.swapaxes(p["ffn"]["w_in"], 1, 2))
+    ps = prompts(21, [37, 9])
+    hs = [eng.submit(p, 10) for p in ps]
+    eng.run_until_idle()
+    fitted = FittedModel(model, params)
+    for p, h in zip(ps, hs):
+        want = np.asarray(fitted.generate(p[None], 10))
+        assert list(h.tokens) == want[0, len(p):].tolist()
+        assert float(served_gaps(built_wide, p, h.tokens).max()) <= TOL
+    # what the caller handed in is what it was
+    assert params[2]["ffn"]["w_in"].shape == (d["held"], d["hidden"],
+                                              d["expert_dim"])
+
+
+def test_the_tiny_widths_are_served_as_they_are(built):
+    """A hidden size of 64 is not a whole lane tile: nothing is transposed,
+    and the engine holds the very leaves it was given."""
+    eng = engine_of(built)
+    assert eng.stats["moe_up_projections_transposed"] == 0
+    assert eng.params[2]["ffn"]["w_in"].shape == built[4][2]["ffn"][
+        "w_in"].shape
 
 
 @pytest.mark.parametrize("kw,word", [
