@@ -12,10 +12,12 @@ of the window is written again here.  ``tools/read_limits_solar.py`` and
 (``serve_window``, ``score``, ``build_engine``, ``Tracked``, ``offer_open``,
 ``wait_all``).
 
-What is added: two lines of the run's log that the cell's table in PERF.md
-holds (how many requests the window held, and the share of the window's decode
-steps that shared their iteration with a prefill unit), and ``ttft_p95_ms``
-among them, printed whether or not the manifest lists it.
+What is added: two lines of the run's log that the cell's tables in PERF.md
+hold: how many requests the window held with the share of its token gaps that
+carry a prefill unit (from the stamps, and from the engine's counters: which
+class of gap ``itl_p95_ms`` reads), and ``serve_engine``'s ``stalls`` line
+(whose clock stood still in a run that reads far off).  ``ttft_p95_ms`` is on
+the window's own ``end_to_end`` line whether or not the manifest lists it.
 """
 
 from __future__ import annotations
@@ -23,6 +25,7 @@ from __future__ import annotations
 from typing import Dict
 
 from benchmarks.lib import harness, manifest as mf, program_nemotronh
+from benchmarks.lib.stats import share_over_pct
 
 _sh = mf.load_driver("serve_hybrid")        # this driver's own copy
 Tracked, offer_open, wait_all = _sh.Tracked, _sh.offer_open, _sh.wait_all
@@ -73,14 +76,48 @@ check, precision_below_stated = _sh.check, _sh.precision_below_stated
 
 
 def serve_window(ctx: harness.RunContext) -> Window:
-    w = _sh.serve_window(ctx)
+    # the window keeps its stamps to itself; what it offers the engine is
+    # seen here on the way through, for the two lines below that need them
+    seen: Dict = {}
+
+    def offer(engine, items, t_close, at):
+        seen.update(items=items, t_close=t_close)
+        return offer_open(engine, items, t_close, at)
+    _sh.offer_open = offer
+    try:
+        w = _sh.serve_window(ctx)
+    finally:
+        _sh.offer_open = offer_open
+    items, t_close = seen["items"], seen["t_close"]
+    t_open = t_close - ctx.seconds
+    window = [it for it in items if t_open <= it.due < t_close]
+    # serve_engine's line: where a run reads far off, whose clock stood
+    # still: the generator's worst lag and when, and the longest span of the
+    # window in which the engine emitted no token
+    late = max((it for it in window if it.submitted is not None),
+               key=lambda it: it.submitted - it.due, default=None)
+    beats = sorted({t for it in items for t in it.stamps
+                    if t_open <= t < t_close})
+    quiet = max(zip(beats[1:], beats), key=lambda ab: ab[0] - ab[1],
+                default=None)
+    ctx.log(stalls="serve_nemotronh",
+            gen_lag_max_ms=late and 1000 * (late.submitted - late.due),
+            gen_lag_max_at_s=late and late.due - t_open,
+            engine_quiet_max_ms=quiet and 1000 * (quiet[0] - quiet[1]),
+            engine_quiet_max_at_s=quiet and quiet[1] - t_open)
+    gaps = [b - a for it in window if it.ok
+            for a, b in zip(it.stamps, it.stamps[1:])]
     c = w.records["window_counters"]
     units = c["prefill_chunks"] + c["prefill_batches"]
     ctx.log(driver="serve_nemotronh", window_requests=w.attempted,
             prefill_units=units, decode_steps=w.records["decode_steps"],
-            # an iteration spends at most one prefill unit
-            # (prefills_per_step 1) before its decode step: the share of the
-            # window's row-gaps that carry one
+            # which class of token gap itl_p95_ms reads (PERF.md section 4):
+            # the share of the window's token gaps longer than twice their
+            # median, the gaps that carry a prefill unit beside the step ...
+            gaps_over_2x_p50_pct=share_over_pct(gaps, 2.0),
+            # ... and the engine's count of it, by iteration and not by
+            # row: an iteration spends at most one prefill unit
+            # (prefills_per_step 1) before its decode step
             gaps_with_prefill_unit_pct=100.0 * units
             / max(w.records["decode_steps"], 1),
             prefill_tokens=c["prefill_tokens"],

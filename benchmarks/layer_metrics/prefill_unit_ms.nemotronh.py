@@ -19,10 +19,22 @@ def read(records, trace, env):
     programs = env["traffic"].get("prefill_programs")
     if not programs:
         return None
+    import bisect
     from benchmarks.lib import trace as T
     from benchmarks.lib.stats import median
     plane = trace.devices[0]
     runs = T.module_runs(plane, trace.window, programs)
     if not runs:
         return None
-    return 1000.0 * median([T.ops_inside(plane, [r]) for r in runs])
+    # ``T.ops_inside`` a run, for all runs in one pass over the operations
+    # (a pass a run was quadratic in the span's length); the runs of one
+    # chip's programs are disjoint
+    runs = sorted(runs)
+    starts = [s for s, _ in runs]
+    inside = [0] * len(runs)
+    for s, e, n in plane.ops:
+        if T.is_leaf(n):
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and e <= runs[i][1]:
+                inside[i] += e - s
+    return 1000.0 * median([ns / 1e9 for ns in inside])

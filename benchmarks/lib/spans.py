@@ -189,11 +189,12 @@ class Spans:
         compiles = [(s, e, COMPILE_SPAN) for s, e in T.union(
             (c.start, c.end) for c in self._by_name.get(COMPILE_SPAN, []))]
         total: Dict[str, int] = collections.defaultdict(int)
-        for gap in gaps:
-            left = [gap]
-            for cover in (segments, compiles):
-                left = _share_out(left, cover, total)
-            total[UNATTRIBUTED] += sum(e - s for s, e in left)
+        # all gaps in one pass a cover: a busy chip has a gap after every
+        # operation, and a pass a gap was quadratic in the span's length
+        left = gaps
+        for cover in (segments, compiles):
+            left = _share_out(left, cover, total)
+        total[UNATTRIBUTED] += sum(e - s for s, e in left)
         return {k: v / 1e9 for k, v in total.items() if v}
 
     def compiles(self, window: Interval) -> List[Tuple[Span, str]]:

@@ -38,7 +38,7 @@ it differs on a measurable share of tokens, which ``tests/`` counts).
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Iterator
 
 import jax
 import jax.numpy as jnp
@@ -124,9 +124,13 @@ def _ends(key, hidden: int, vocab: int, dtype):
             "head": normal((hidden, vocab))}
 
 
-def make_weights(cfg: Dict, seed: int, dtype: str = "bfloat16") -> Dict:
-    """The weights of ``cfg`` from ``seed`` on the default device, in
-    ``dtype`` (the configuration's ``precision.params``)."""
+def weight_parts(cfg: Dict, seed: int,
+                 dtype: str = "bfloat16") -> Iterator[Dict]:
+    """The weights one part at a time, each made when it is asked for: first
+    ``{"embed", "final_norm", "head"}``, then a layer's dict after a layer's
+    dict.  A caller that lays each part out before it asks for the next
+    (``program_nemotronh.build_engine``) never holds two forms of the whole
+    model; the draws do not depend on who asks, or when."""
     d = dims(cfg)
     dt = jnp.dtype(dtype)
     key = jax.random.PRNGKey(fold_seed(seed))
@@ -134,9 +138,16 @@ def make_weights(cfg: Dict, seed: int, dtype: str = "bfloat16") -> Dict:
              d["m_heads"], d["inner"], d["conv_dim"], d["conv"],
              d["experts"], d["held"], d["expert_dim"], d["shared_dim"],
              d["layers"])
-    w = _ends(jax.random.fold_in(key, 0), d["hidden"], d["vocab"], dt)
-    w["layers"] = []
+    yield _ends(jax.random.fold_in(key, 0), d["hidden"], d["vocab"], dt)
     for i, kind in enumerate(d["kinds"]):
-        layer = _layer(jax.random.fold_in(key, i + 1), kind, shape, dt)
-        w["layers"].append(dict(layer, kind=kind))
+        yield dict(_layer(jax.random.fold_in(key, i + 1), kind, shape, dt),
+                   kind=kind)
+
+
+def make_weights(cfg: Dict, seed: int, dtype: str = "bfloat16") -> Dict:
+    """The weights of ``cfg`` from ``seed`` on the default device, in
+    ``dtype`` (the configuration's ``precision.params``)."""
+    parts = weight_parts(cfg, seed, dtype)
+    w = next(parts)
+    w["layers"] = list(parts)
     return w

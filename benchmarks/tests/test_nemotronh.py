@@ -468,6 +468,87 @@ def test_a_narrower_recurrent_state_is_counted(served_window):
     assert drv.precision_below_stated(engine, ctx.cfg) == 2 * 4
 
 
+# -- the engine is handed the form it holds ---------------------------------------
+
+def test_the_engine_is_built_without_the_originals_beside_their_transposes():
+    """``program_nemotronh.build_engine`` lays each layer out as it is drawn
+    and hands the engine ``store_for_serving``'s form: the same engine as one
+    built from a ``FittedModel`` of the model's own layout (PR 35's way), and
+    no expert layer's ``w_in`` alive beside its ``w_in_t`` when it returns.
+    At a hidden size the rule engages for (128 lanes; the rehearsal's 64
+    does not)."""
+    import gc
+    import jax
+    from benchmarks.lib import program_nemotronh as P
+    from distkeras_tpu.core.model import FittedModel
+    from distkeras_tpu.serving import ServingEngine
+    cfg = dict(mf.resolve_sizes(mf.Manifest().config(CONFIG), True),
+               hidden_size=128)
+    d = C.dims(cfg)
+    original = (d["held"], d["hidden"], d["expert_dim"])
+    experts = d["kinds"].count("experts")
+
+    def originals_alive():
+        gc.collect()
+        return sum(a.shape == original for a in jax.live_arrays())
+
+    before = originals_alive()
+    engine = P.build_engine(cfg, 21)
+    assert originals_alive() == before
+    assert engine.stats["moe_up_projections_transposed"] == experts == 4
+    blocks = [p["ffn"] for p in engine.params if "ffn" in p]
+    assert all("w_in" not in b and b["w_in_t"].shape == (
+        d["held"], d["expert_dim"], d["hidden"]) for b in blocks)
+    old = ServingEngine(FittedModel(P.build_model(cfg),
+                                    P.program_params(cfg, 21)),
+                        **cfg["deployment"]["engine"])
+    assert old.stats["moe_up_projections_transposed"] == experts
+    for mine, theirs in zip(jax.tree_util.tree_leaves(engine.params),
+                            jax.tree_util.tree_leaves(old.params)):
+        np.testing.assert_array_equal(np.asarray(mine), np.asarray(theirs))
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, d["vocab"], n).astype(np.int32)
+               for n in (9, 40, 70)]           # a bucket, and chunked units
+    served = []
+    for eng in (engine, old):
+        handles = [eng.submit(p, 12) for p in prompts]
+        eng.run_until_idle()
+        served.append([list(h.tokens) for h in handles])
+    assert served[0] == served[1] and all(len(t) == 12 for t in served[0])
+
+
+def test_the_share_of_values_over_so_many_medians():
+    from benchmarks.lib.stats import share_over_pct
+    gaps = [7.0] * 17 + [29.0, 30.0, 31.0]          # 15 % carry a unit
+    assert share_over_pct(gaps, 2.0) == pytest.approx(15.0)
+    assert share_over_pct([7.0, 8.0, 14.0], 2.0) == 0.0   # 14 is not OVER 16
+    # where most gaps carry a unit the median is such a gap: near nothing
+    assert share_over_pct([30.0] * 12 + [7.0] * 8, 2.0) == 0.0
+    assert share_over_pct([], 2.0) == 0.0
+
+
+def test_the_sweep_prints_the_share_of_gaps_that_carry_a_prefill_unit():
+    """``tools/sweep_serve.py`` in rehearsal: a rung's line holds
+    ``gaps_over_2x_p50_pct`` (the class of token gap that carries a prefill
+    unit, from the stamps) and the engine's own count of it, both shares."""
+    import subprocess
+    import sys
+    tool = os.path.join(os.path.dirname(mf.__file__), os.pardir, "tools",
+                        "sweep_serve.py")
+    p = subprocess.run(
+        [sys.executable, tool, "--workload", CELL, "--rates", "8",
+         "--seconds", "2", "--rehearse"], capture_output=True, text=True,
+        timeout=600, env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode == 0, p.stderr[-2000:]
+    rung = next(json.loads(l) for l in p.stdout.splitlines()
+                if l.startswith('{"rate"'))
+    assert rung["rate"] == 8.0 and rung["ok"] == rung["requests"] > 0
+    for key in ("gaps_over_2x_p50_pct", "gaps_with_prefill_unit_pct"):
+        assert 0.0 <= rung[key] <= 100.0
+    assert "0.6 x the knee" in open(tool).read()
+    assert "0.8 x the knee" not in open(tool).read()
+
+
 # -- the command ---------------------------------------------------------------
 
 @pytest.mark.parametrize("trace", ["0", "1"])
@@ -498,6 +579,11 @@ def test_rehearsal_of_the_cell(trace):
     assert mine["window_requests"] == out["attempted"]
     assert mine["prefill_units"] > 0 and mine["prefill_tokens"] > 0
     assert mine["gaps_with_prefill_unit_pct"] > 0
+    assert 0.0 <= mine["gaps_over_2x_p50_pct"] <= 100.0
+    stalls = next(json.loads(l) for l in lines
+                  if '"stalls": "serve_nemotronh"' in l)
+    assert 0 <= stalls["engine_quiet_max_ms"] < 3000
+    assert stalls["gen_lag_max_ms"] is not None
 
 
 def test_the_parent_under_these_files_fails_at_once(tmp_path):

@@ -4,10 +4,16 @@ arrival rates of ``--seconds`` each, the same mix at each.
     python benchmarks/tools/sweep_serve.py --workload serve-chat-gpt2m \\
         --rates 4,6,8,10,12,14,18 --seconds 15 --seed 1
 
-The knee is the highest rate at which nothing is shed and the backlog does not
-grow (what is still unfinished when arrivals stop is no more than at the rate
-below).  The cell's fixed rate is 0.8 x the knee, written into its traffic file
-by hand with this table in PERF.md.  Not part of any measurement."""
+The rule in use (PERF.md section 4; PRs 32, 34, 37): the knee is the highest
+rung at which at most 2 requests stand queued when arrivals stop
+(``queued_at_close``), and the cell's fixed rate is 0.6 x the knee, rounded
+down to a multiple of 0.5 and written into its traffic file by hand with this
+table in PERF.md.  ``gaps_over_2x_p50_pct`` is the share of a rung's token gaps
+longer than twice its median gap: the class of gap that carries a prefill unit
+beside the decode step, from the stamps alone.  ``itl_p95_ms`` reads that class
+where the share is 10 % or above and a decode-only gap where it is 2.5 % or
+below; between the two the percentile sits on the edge and the rate is not
+admissible.  Not part of any measurement."""
 
 from __future__ import annotations
 
@@ -30,7 +36,7 @@ def main() -> int:
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args()
     from benchmarks.lib import harness, manifest as mf
-    from benchmarks.lib.stats import median, percentile
+    from benchmarks.lib.stats import median, percentile, share_over_pct
     from benchmarks.lib.traffic import generate
     _, ctx, dev = harness.open_run(args.workload, args.seed, args.seconds,
                                    rehearse=args.rehearse)
@@ -67,6 +73,8 @@ def main() -> int:
                        if start <= t < t_close)
             after = engine.stats
             steps = after["decode_steps"] - before["decode_steps"]
+            units = sum(after.get(k, 0) - before.get(k, 0)
+                        for k in ("prefill_chunks", "prefill_batches"))
             print(json.dumps(dict(
                 rate=rate, requests=len(items), ok=len(ok),
                 shed=after["requests_rejected"] - before["requests_rejected"],
@@ -77,6 +85,12 @@ def main() -> int:
                 ttft_p95_ms=1000 * percentile(ttft, 95),
                 itl_p50_ms=1000 * median(gaps),
                 itl_p95_ms=1000 * percentile(gaps, 95),
+                gaps_over_2x_p50_pct=share_over_pct(gaps, 2.0),
+                # the engine's own count of the same class, by iteration and
+                # not by row (the driver's log line has it under this name):
+                # it still tells where the median gap itself carries a unit
+                # and the share above reads near nothing
+                gaps_with_prefill_unit_pct=100.0 * units / max(steps, 1),
                 decode_steps_per_s=steps / (drained - start),
                 occupancy_pct=100.0 * (after["active_slot_steps"]
                                        - before["active_slot_steps"])
