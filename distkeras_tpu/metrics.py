@@ -17,7 +17,17 @@ only at exit rides on the next span of the same request.  The names below
 are the contract that ``docs/serving.md`` and the benchmark's readers
 (``benchmarks/lib/spans.py``) point at.
 
-Serving (``serving.py``; the engine's thread unless said):
+Serving (``serving.py``; the engine's thread unless said).  The loop's own
+phases (``serve.iteration`` and, inside it or between two of them,
+``serve.reap``, ``qos``, ``schedule``, ``decode_dispatch``, ``fetch``,
+``emit``, ``reload``, ``publish``, ``idle_wait``) are opened by ONE helper,
+``EngineAccount.phase(name, **fields)`` (``engine.account.phase("reap")``),
+which opens the span below AND adds the phase's ``time.perf_counter()``
+seconds to the engine's own account, whether a session is open or not: span
+and account share their boundaries by construction.  The other spans
+(``submit``, ``admit``, ``retire``, ``warmup``, and a ``publish`` outside the
+loop's own) are plain ``span(...)``s; ``prefill_unit`` is opened by
+``EngineAccount.unit(**fields)``, which also counts it and its seconds.
 
 ===================== ======================================================
 ``serve.submit``      ``submit``/``submit_prefilled`` on the CALLER's
@@ -29,6 +39,15 @@ Serving (``serving.py``; the engine's thread unless said):
 ``serve.reap``        ``_reap``: cancelled / expired requests retired
 ``serve.qos``         ``_balance_qos``, only when it runs
 ``serve.schedule``    ``_schedule_prefills``
+``serve.hold``        the instant ``_schedule_prefills`` stops admitting
+                      with a request still queued: ``reason``
+                      (``no_slot``: every slot taken; ``no_blocks``: the
+                      head's block chain does not fit the pool even after
+                      eviction; ``budget``: the iteration's
+                      ``prefills_per_step`` units are spent), ``queued``
+                      (the queue's depth, read without the lock); at most
+                      one an iteration; the account's ``held`` counts the
+                      same instants
 ``serve.admit``       one admission (block plan, radix match, slot take):
                       ``rid``
 ``serve.prefill_unit`` one dispatched prefill work unit: ``rid`` (first of
@@ -71,6 +90,59 @@ Serving (``serving.py``; the engine's thread unless said):
 
 ``rid`` is ``RequestHandle.id``: the spans of one request share it
 (submit → admit → prefill_unit... → retire).
+
+**The engine's account** (``ServingEngine.account``, an ``EngineAccount``;
+always on, no flag; written by the engine's thread alone, without a lock, in
+fixed memory; ``snapshot()`` gives a plain JSON-able dict from any thread).
+It is the spans' twin over the WHOLE life of the engine, where a profiler
+session holds a few seconds:
+
+===================== ======================================================
+``loop_s``            first iteration's start to the latest stamp's end
+``iterations``        ``step()`` calls
+``phase_s``           seconds by phase (keys as they occur: ``reap`` ...
+                      ``idle_wait``), each the sum of its spans'
+                      durations; they sum to ``loop_s`` less the loop's own
+                      few lines.  A phase opened inside another (the QoS
+                      pass flushing the pipeline before a swap-out) is the
+                      outer phase's time: every second is counted once
+``prefill_unit``      ``{n, s}``: prefill units dispatched and their host
+                      seconds (arrays built, uploads, the launch), part of
+                      ``phase_s["schedule"]``
+``classes``           iterations by what they dispatched: ``decode``,
+                      ``decode+prefill``, ``prefill``, ``none``, each
+                      ``{n, s}``
+``step_carries_prefill_pct``  of the iterations that dispatched a decode
+                      step, the per cent that dispatched a prefill unit
+                      too: which class of token gap a percentile reads
+                      (10 or more: step + unit; 2.5 or less: step)
+``gap_ms``            the token gap from inside: from one decode (or
+                      ``spec``) step's ``serve.emit`` to the next step's,
+                      once for every row the later step held, in a
+                      histogram of fixed edges (a factor 2**(1/8) apart,
+                      0.25 ms to 4 s) a class: ``step``, or ``step+unit``
+                      if a prefill unit was dispatched between the two
+                      steps' dispatches; ``{n, p50, p95}`` a class and
+                      over ``all``, interpolated inside a bucket (within
+                      9 %).  A step after an iteration that dispatched no
+                      step (the pool ran empty) starts anew
+``slowest``           the 8 longest iterations, longest first: ``it``,
+                      ``at`` (``time.perf_counter()`` at its start, the
+                      clock of ``RequestHandle``'s stamps), ``wall_ms``,
+                      ``cpu_ms`` (``time.thread_time()`` of the engine's
+                      thread since the iteration before ended: its wait
+                      between two costs none), ``class``, ``phase`` (the
+                      one that took most of it), ``phase_ms``, ``active``.
+                      ``wall_ms`` far above ``cpu_ms`` with ``phase`` not
+                      ``fetch``/``idle_wait``: the thread was off the CPU
+                      (the machine stood still, or another thread held
+                      the interpreter); ``fetch``: the device or the
+                      runtime; else, ``cpu_ms`` near ``wall_ms``: host
+                      code, and ``phase`` says which
+``held``              why the queue's head was left waiting, by
+                      ``serve.hold``'s ``reason``: ``{n, s}``, iterations
+                      and the seconds of those iterations
+===================== ======================================================
 
 Counters of a model of hybrid blocks (``ServingEngine.stats``; the decode
 step's program hands them back behind its tokens, so they cost no device
@@ -122,6 +194,8 @@ from __future__ import annotations
 
 import contextlib
 import json
+import math
+import threading
 import time
 from typing import IO, Any, Dict, List, Optional
 
@@ -215,3 +289,267 @@ def span(name: str, **fields):
     recorded only while a profiler session is open and shares the device
     trace's timeline.  The module docstring lists the program's spans."""
     return jax.profiler.TraceAnnotation(name, **fields)
+
+
+# -- the serving engine's own account ------------------------------------------
+# token gaps are counted in buckets a factor 2**(1/8) apart: bucket 0 holds
+# what is under _GAP_LO_MS, bucket i (1.._GAP_BUCKETS) the gaps from
+# _GAP_LO_MS * 2**((i - 1) / 8) up, the last bucket what is over 4 s
+_GAP_LO_MS = 0.25
+_GAP_PER_OCTAVE = 8
+_GAP_BUCKETS = 14 * _GAP_PER_OCTAVE          # 0.25 ms * 2**14 = 4.096 s
+_SLOWEST = 8
+_CLASSES = ("decode", "decode+prefill", "prefill", "none")
+_now = time.perf_counter
+
+
+def _note(name: str, fields: Dict[str, Any]):
+    """The span ``name``, or nothing while no profiler session is open
+    (where a ``TraceAnnotation`` records nothing and still costs its
+    making): the account's stamps run in every iteration of every run."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name, **fields)
+    return None
+
+
+def _gap_edge(i: int) -> float:
+    """Lower edge, in ms, of gap bucket ``i``."""
+    return 0.0 if i <= 0 else _GAP_LO_MS * 2.0 ** (
+        (min(i, _GAP_BUCKETS + 1) - 1) / _GAP_PER_OCTAVE)
+
+
+def _gap_percentiles(hist: List[int]) -> Dict[str, Any]:
+    """``{n, p50, p95}`` of a gap histogram, linear inside a bucket."""
+    n = sum(hist)
+    out: Dict[str, Any] = {"n": n, "p50": None, "p95": None}
+    for key, q in (("p50", 0.50), ("p95", 0.95)):
+        rank, seen = q * n, 0
+        for i, c in enumerate(hist):
+            if c and seen + c >= rank:
+                lo, hi = _gap_edge(i), _gap_edge(i + 1)
+                out[key] = lo + (hi - lo) * (rank - seen) / c
+                break
+            seen += c
+    return out
+
+
+class _Stamp:
+    """One open phase of the engine's loop: the span (``None`` while no
+    profiler session is open) and the clock."""
+    __slots__ = ("acct", "name", "note", "t0")
+
+    def __init__(self, acct: "EngineAccount", name: str, note):
+        self.acct, self.name, self.note = acct, name, note
+
+    def __enter__(self):
+        if self.note is not None:
+            self.note.__enter__()
+        self.acct._depth += 1
+        self.t0 = _now()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = _now()
+        acct = self.acct
+        acct._depth -= 1
+        if not acct._depth:      # inside another phase: the outer one's time
+            acct.phase_s[self.name] = (acct.phase_s.get(self.name, 0.0)
+                                       + t1 - self.t0)
+            acct._last = t1
+        if self.note is not None:
+            self.note.__exit__(*exc)
+        return False
+
+
+class _Unit(_Stamp):
+    """``serve.prefill_unit``: counted with its seconds, inside
+    ``schedule``'s."""
+    __slots__ = ()
+
+    def __enter__(self):
+        if self.note is not None:
+            self.note.__enter__()
+        self.t0 = _now()
+        return self
+
+    def __exit__(self, *exc):
+        acct = self.acct
+        acct.prefill_unit[0] += 1
+        acct.prefill_unit[1] += _now() - self.t0
+        acct._units_since_step += 1
+        if self.note is not None:
+            self.note.__exit__(*exc)
+        return False
+
+
+class _Iteration(_Stamp):
+    """``serve.iteration``: one ``step()``."""
+    __slots__ = ()
+
+    def __enter__(self):
+        if self.note is not None:
+            self.note.__enter__()
+        self.t0 = _now()
+        self.acct._begin(self.t0)
+        return self
+
+    def __exit__(self, *exc):
+        self.acct._end(self.t0, _now())
+        if self.note is not None:
+            self.note.__exit__(*exc)
+        return False
+
+
+class EngineAccount:
+    """The serving engine's own account of every iteration, tracing on or
+    off (the module docstring lists ``snapshot()``'s keys).  One stamp feeds
+    the span and the account: ``iteration``, ``phase`` and ``unit`` open the
+    ``serve.*`` span of that name with its fields and add the elapsed
+    ``time.perf_counter()`` seconds here.  The engine's thread is the only
+    writer and takes no lock; memory is fixed (two histograms, eight kept
+    iterations, a few dicts whose keys are the phases, classes and
+    reasons)."""
+
+    def __init__(self):
+        self.iterations = 0
+        self.phase_s: Dict[str, float] = {}
+        self.prefill_unit: List[float] = [0, 0.0]            # n, seconds
+        self.classes = {c: [0, 0.0] for c in _CLASSES}
+        self.gaps = {c: [0] * (_GAP_BUCKETS + 2)
+                     for c in ("step", "step+unit")}
+        self.held: Dict[str, List[float]] = {}
+        self.slowest: List[Dict[str, Any]] = []
+        self._first: Optional[float] = None    # the first iteration's start
+        self._last = 0.0                       # the latest stamp's end
+        self._depth = 0                        # phases open
+        self._thread = 0                       # the thread that iterates
+        self._cpu = 0.0                        # its CPU time, last iteration's end
+        # the open iteration
+        self._it = self._active = 0
+        self._at_start: Dict[str, float] = {}
+        self._units_at_start = 0
+        self._decoded = False
+        self._held: Optional[str] = None
+        # the token gap: the last step emitted, and which dispatched steps
+        # had a prefill unit dispatched since the step before (the
+        # lookahead keeps one step in flight: eight places are plenty)
+        self._emit_step = -1
+        self._emit_at = 0.0
+        self._units_since_step = 0
+        self._carries = [False] * 8
+
+    # -- stamps ----------------------------------------------------------------
+    def iteration(self, it: int, active: int) -> _Iteration:
+        self._it, self._active = it, active
+        return _Iteration(self, "iteration", _note(
+            "serve.iteration", {"it": it, "active": active}))
+
+    def phase(self, name: str, **fields) -> _Stamp:
+        """``with account.phase("fetch", step=step):`` is the span
+        ``serve.fetch`` and ``phase_s["fetch"]``'s seconds."""
+        return _Stamp(self, name, _note("serve." + name, fields))
+
+    def unit(self, **fields) -> _Unit:
+        return _Unit(self, "prefill_unit",
+                     _note("serve.prefill_unit", fields))
+
+    def hold(self, reason: str, queued: int) -> None:
+        """The scheduler stopped admitting with ``queued`` requests
+        waiting: the span ``serve.hold``, and this iteration under
+        ``held[reason]``."""
+        self._held = reason
+        note = _note("serve.hold", {"reason": reason, "queued": queued})
+        if note is not None:
+            with note:
+                pass
+
+    def decode_step(self, step: int) -> None:
+        """Decode step ``step`` is being dispatched."""
+        self._decoded = True
+        self._carries[step & 7] = self._units_since_step > 0
+        self._units_since_step = 0
+
+    def step_emitted(self, step: int, rows: int, at: float) -> None:
+        """Step ``step``'s token loop opened at ``at`` with ``rows`` rows:
+        if the step before was the last one emitted, each row waited
+        ``at`` less that step's instant for this token."""
+        if step == self._emit_step + 1:
+            ms = (at - self._emit_at) * 1e3
+            i = (0 if ms < _GAP_LO_MS else min(1 + int(
+                _GAP_PER_OCTAVE * math.log2(ms / _GAP_LO_MS)),
+                _GAP_BUCKETS + 1))
+            self.gaps["step+unit" if self._carries[step & 7]
+                      else "step"][i] += rows
+        self._emit_step, self._emit_at = step, at
+
+    # -- an iteration's ends -----------------------------------------------------
+    def _begin(self, t0: float) -> None:
+        if self._first is None:
+            self._first = t0
+        ident = threading.get_ident()
+        if ident != self._thread:            # the loop's (new) thread
+            self._thread = ident
+            self._cpu = time.thread_time()
+        self.iterations += 1
+        self._at_start = self.phase_s.copy()
+        self._units_at_start = self.prefill_unit[0]
+        self._decoded = False
+        self._held = None
+
+    def _end(self, t0: float, t1: float) -> None:
+        wall = t1 - t0
+        self._last = t1
+        cpu0, self._cpu = self._cpu, time.thread_time()
+        unit = self.prefill_unit[0] > self._units_at_start
+        if self._decoded:
+            cls = "decode+prefill" if unit else "decode"
+        else:
+            cls = "prefill" if unit else "none"
+            self._emit_step = -1     # the pool ran empty: no gap across it
+        entry = self.classes[cls]
+        entry[0] += 1
+        entry[1] += wall
+        if self._held is not None:
+            entry = self.held.setdefault(self._held, [0, 0.0])
+            entry[0] += 1
+            entry[1] += wall
+        kept = self.slowest
+        if len(kept) < _SLOWEST or wall * 1e3 > kept[-1]["wall_ms"]:
+            before = self._at_start
+            spent = {k: v - before.get(k, 0.0)
+                     for k, v in self.phase_s.items()}
+            top = max(spent, key=spent.get, default=None)
+            kept.append({
+                "it": self._it, "at": t0, "wall_ms": wall * 1e3,
+                "cpu_ms": (self._cpu - cpu0) * 1e3, "class": cls,
+                "phase": top, "phase_ms": spent.get(top, 0.0) * 1e3,
+                "active": self._active})
+            kept.sort(key=lambda e: -e["wall_ms"])
+            del kept[_SLOWEST:]
+
+    # -- the reader's side --------------------------------------------------------
+    def snapshot(self) -> Dict[str, Any]:
+        """The account as a plain dict (``json.dumps`` takes it), from any
+        thread; taken while the engine runs it may trail it by a stamp."""
+        classes = {c: {"n": n, "s": s}
+                   for c, (n, s) in self.classes.items()}
+        steps = classes["decode"]["n"] + classes["decode+prefill"]["n"]
+        gaps = {c: list(h) for c, h in self.gaps.items()}
+        both = [a + b for a, b in zip(*gaps.values())]
+        return {
+            "loop_s": (0.0 if self._first is None
+                       else self._last - self._first),
+            "iterations": self.iterations,
+            "phase_s": dict(self.phase_s),
+            "prefill_unit": {"n": self.prefill_unit[0],
+                             "s": self.prefill_unit[1]},
+            "classes": classes,
+            "step_carries_prefill_pct": (
+                100.0 * classes["decode+prefill"]["n"] / steps
+                if steps else None),
+            "gap_ms": {**{c: _gap_percentiles(h) for c, h in gaps.items()},
+                       "all": _gap_percentiles(both)},
+            "slowest": [dict(e) for e in list(self.slowest)],
+            "held": {r: {"n": n, "s": s}
+                     for r, (n, s) in list(self.held.items())},
+        }

@@ -109,7 +109,7 @@ from .core.decode import (_check_supported, _context_limit,
                           _validate_rolling, _validate_sampling,
                           _validate_stopping, _vocab_size, init_cache)
 from .core.model import FittedModel, Sequential
-from .metrics import span
+from .metrics import EngineAccount, span
 
 logger = logging.getLogger("distkeras_tpu.serving")
 
@@ -1190,6 +1190,13 @@ class ServingEngine:
         self._buckets = _pow2_buckets(self._chunk_width)
         self._pending: "collections.deque" = collections.deque()
         self._iterations = 0  # step() calls so far (serve.iteration's `it`)
+        #: the loop's own account of every iteration (metrics.EngineAccount:
+        #: seconds by phase, iterations by class, the token gap, the slowest
+        #: iterations, why the queue's head waited); its stamps open the
+        #: loop's spans, so it is on whether a profiler session is or not.
+        #: NOT a key of ``stats``: the pair's and the router's merges sum
+        #: numbers and concatenate lists
+        self.account = EngineAccount()
         self._prefilling: Dict[int, _PrefillJob] = {}
         #: what the decode program's attention reads K and V through:
         #: "kernel" (ops.paged_attention, in place) or "gather" — settled
@@ -2592,29 +2599,37 @@ class ServingEngine:
         ``_active``, the trie counter) or an already-synchronised stats
         counter — stale-by-one is fine for routing."""
         with span("serve.publish"):
-            prev = self._load_snapshot
-            stats = self.stats
-            with self._qlock:
-                qi = self._q_int
-            self._load_snapshot = {
-                "queue_depth": (prev["queue_depth"] if qd is None
-                                else int(qd)),
-                "slots_free": len(self._free),
-                "slots_total": self.num_slots,
-                "active": int(self._active.sum()),
-                "trie_blocks": (self._pool.trie_nodes if self.paged else 0),
-                "queue_capacity": self.queue_capacity,
-                "max_len": self.max_len,
-                "draining": (prev["draining"] if draining is None
-                             else bool(draining)),
-                "dead": prev["dead"] if dead is None else bool(dead),
-                "prefix_hit_tokens": stats["prefix_hit_tokens"],
-                "prefill_tokens": stats["prefill_tokens"],
-                "tokens_generated": stats["tokens_generated"],
-                "requests_completed": stats["requests_completed"],
-                "requests_failed": stats["requests_failed"],
-                "queued_interactive": qi,
-            }
+            self._set_load(qd, draining, dead)
+
+    def _set_load(self, qd: Optional[int] = None,
+                  draining: Optional[bool] = None,
+                  dead: Optional[bool] = None) -> None:
+        """``_publish_load`` without its span: ``step()``'s closing publish
+        is a phase of the loop and opens ``serve.publish`` through the
+        account."""
+        prev = self._load_snapshot
+        stats = self.stats
+        with self._qlock:
+            qi = self._q_int
+        self._load_snapshot = {
+            "queue_depth": (prev["queue_depth"] if qd is None
+                            else int(qd)),
+            "slots_free": len(self._free),
+            "slots_total": self.num_slots,
+            "active": int(self._active.sum()),
+            "trie_blocks": (self._pool.trie_nodes if self.paged else 0),
+            "queue_capacity": self.queue_capacity,
+            "max_len": self.max_len,
+            "draining": (prev["draining"] if draining is None
+                         else bool(draining)),
+            "dead": prev["dead"] if dead is None else bool(dead),
+            "prefix_hit_tokens": stats["prefix_hit_tokens"],
+            "prefill_tokens": stats["prefill_tokens"],
+            "tokens_generated": stats["tokens_generated"],
+            "requests_completed": stats["requests_completed"],
+            "requests_failed": stats["requests_failed"],
+            "queued_interactive": qi,
+        }
 
     def load(self) -> Dict[str, Any]:
         """Cheap read-only load snapshot for routing decisions: queue
@@ -2814,9 +2829,12 @@ class ServingEngine:
                 if not admitted:
                     with self._qlock:
                         self._q_push(h, front=True)
+                    self._hold("no_blocks")
                     break
                 budget -= 1
                 did = True
+            else:
+                self._hold("budget" if self._free else "no_slot")
             return did
         for slot in list(self._prefilling):
             if budget <= 0:
@@ -2846,6 +2864,7 @@ class ServingEngine:
                         self._q_push(h, front=True)
                     if interactive:
                         self._int_blocked = True
+                    self._hold("no_blocks")
                     break
             budget -= 1
             did = True
@@ -2857,9 +2876,21 @@ class ServingEngine:
                     plans[h.id] = plan
                     self._pool.publish(plan, h.prompt)
                 batch.append(h)
+        else:
+            self._hold("budget" if len(self._free) > len(batch)
+                       else "no_slot")
         if batch:
             self._batch_prefill(batch, plans)
         return did
+
+    def _hold(self, reason: str) -> None:
+        """``_schedule_prefills`` stopped admitting: if a request is still
+        queued, say why its head waits (the span ``serve.hold`` and the
+        account's ``held``).  The depth is read without the lock: a submit
+        that lands in this instant is counted an iteration late."""
+        queued = self._qdepth
+        if queued:
+            self.account.hold(reason, queued)
 
     # ------------------------------------------------- paged admission
     def _admit_blocks(self, h: RequestHandle) -> Optional[_BlockPlan]:
@@ -2980,9 +3011,10 @@ class ServingEngine:
                               []).append(h)
         for width, group in groups.items():
             hit = sum(matched(h) for h in group)
-            with span("serve.prefill_unit", rid=group[0].id,
-                      tokens=sum(len(h.prompt) for h in group) - hit,
-                      kind="bucket", width=width, hit=hit):
+            with self.account.unit(
+                    rid=group[0].id,
+                    tokens=sum(len(h.prompt) for h in group) - hit,
+                    kind="bucket", width=width, hit=hit):
                 nb = self.prefills_per_step
                 prompts = np.zeros((nb, width), np.int32)
                 match = np.zeros((nb,), np.int32)
@@ -3105,8 +3137,9 @@ class ServingEngine:
         else:
             width, real, final = self._bucket_of(remaining), remaining, True
         hit, job.hit = job.hit, 0
-        with span("serve.prefill_unit", rid=h.id, tokens=real,
-                  kind="final" if final else "chunk", width=width, hit=hit):
+        with self.account.unit(
+                rid=h.id, tokens=real, kind="final" if final else "chunk",
+                width=width, hit=hit):
             toks = np.zeros((1, width), np.int32)
             toks[0, :real] = h.prompt[offset:offset + real]
             toks_d = self._put(toks)
@@ -3502,20 +3535,21 @@ class ServingEngine:
         prefill-only iteration leaves the counter parked and must not
         re-pull on every pass."""
         self._iterations += 1
-        with span("serve.iteration", it=self._iterations,
-                  active=int(np.count_nonzero(self._active))):
+        phase = self.account.phase
+        with self.account.iteration(self._iterations,
+                                    int(np.count_nonzero(self._active))):
             self.last_beat = time.monotonic()
             steps_before = self.stats["decode_steps"]
-            with span("serve.reap"):
+            with phase("reap"):
                 did = self._reap()
             if self._can_preempt:
                 with self._qlock:
                     qos_work = bool(self._preempt_ids or self._suspended
                                     or self._q_int)
                 if qos_work or self._int_blocked:
-                    with span("serve.qos"):
+                    with phase("qos"):
                         did = self._balance_qos() or did
-            with span("serve.schedule"):
+            with phase("schedule"):
                 did = self._schedule_prefills() or did
             if self.role == "prefill":
                 # no token loop at all: drain every dispatched prefill NOW
@@ -3524,7 +3558,8 @@ class ServingEngine:
                 # lookahead entry out of the pipeline)
                 if self._pending:
                     did = self._drain_pending(flush=True) or did
-                self._publish_load()
+                with phase("publish"):
+                    self._set_load()
                 return did
             if self._active.any():
                 self._decode_once()
@@ -3534,9 +3569,10 @@ class ServingEngine:
             if (self._reload_every
                     and self.stats["decode_steps"] > steps_before
                     and self.stats["decode_steps"] % self._reload_every == 0):
-                with span("serve.reload"):
+                with phase("reload"):
                     self._pull_weights()
-            self._publish_load()
+            with phase("publish"):
+                self._set_load()
             return did
 
     def _decode_once(self) -> None:
@@ -3551,9 +3587,10 @@ class ServingEngine:
         sample = _sampler_work(entries)
         self.stats["sampler_draw_steps"] += sample != "greedy"
         self.stats["sampler_filter_steps"] += sample == "filter"
-        with span("serve.decode_dispatch", active=len(entries), step=step,
-                  attn=self._decode_attn, sample=sample,
-                  state=self._state_kinds):
+        self.account.decode_step(step)
+        with self.account.phase("decode_dispatch", active=len(entries),
+                                step=step, attn=self._decode_attn,
+                                sample=sample, state=self._state_kinds):
             if self._draft_model is not None:
                 # speculative round: k draft steps + one batched verify in
                 # ONE program; rows commit 1..spec_len+1 tokens each, packed
@@ -3587,10 +3624,12 @@ class ServingEngine:
         keep = 0 if flush else 1
         while len(self._pending) > keep:
             kind, arr, entries, step = self._pending.popleft()
-            with span("serve.fetch", step=step):
+            with self.account.phase("fetch", step=step):
                 vals = self._fetch(arr)
-            with span("serve.emit", kind=kind, rows=len(entries),
-                      step=step):
+            with self.account.phase("emit", kind=kind, rows=len(entries),
+                                    step=step) as stamp:
+                if kind != "prefill":
+                    self.account.step_emitted(step, len(entries), stamp.t0)
                 if kind == "decode" and len(vals) > self.num_slots:
                     held, touched, fullest = vals[self.num_slots:]
                     self.stats["moe_assignments_held"] += int(held)
@@ -4142,7 +4181,7 @@ class ServingEngine:
         try:
             while self._running:
                 if not self.step():
-                    with span("serve.idle_wait"), self._qlock:
+                    with self.account.phase("idle_wait"), self._qlock:
                         self._have_work.wait_for(
                             lambda: self._qdepth > 0 or bool(self._preempt_ids)
                             or not self._running,

@@ -19,6 +19,9 @@ continuation lengths) in two modes:
 comparison continuous batching must beat at ≥ 4 concurrent requests
 (tests/test_serving_bench.py asserts it).
 
+After the closed-loop run ``main`` prints the engine's own account of its
+loop (``"mode": "account"``: ``ServingEngine.account.snapshot()``).
+
 Run:  JAX_PLATFORMS=cpu python examples/loadgen.py [--requests 24]
       [--slots 4] [--concurrency 8] [--qps-sweep 20,50,100]
 """
@@ -685,6 +688,17 @@ def fleet_report(router, closed: Dict[str, Any]) -> Dict[str, Any]:
     }
 
 
+def account_report(engine) -> Dict[str, Any]:
+    """Each engine's own account of its loop (``engine.account.snapshot()``:
+    seconds by phase, iterations by what they carried, the token gap from
+    inside, the slowest iterations, why the queue's head waited —
+    docs/serving.md, "The engine's own account"); one entry an engine of
+    a pair or a fleet, whose merged ``stats`` do not hold it."""
+    engines = getattr(engine, "engines", None) or [engine]
+    return {"mode": "account",
+            "engines": [e.account.snapshot() for e in engines]}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=24)
@@ -859,6 +873,7 @@ def main():
                                  deadline_s=args.deadline)
         print(json.dumps({"mode": "closed_loop",
                           "concurrency": args.concurrency, **closed}))
+        print(json.dumps(account_report(engine)))
         if args.spec_draft is not None:
             print(json.dumps({
                 "mode": "spec", "spec_draft": args.spec_draft,

@@ -16,7 +16,6 @@ The invariants pinned here are the engine's whole contract:
    row for row.
 """
 
-import contextlib
 import re
 import threading
 
@@ -551,8 +550,6 @@ def test_a_retired_sampling_slot_costs_later_greedy_steps_nothing(
     sampler (all-zero temperatures: no draw, no sort), to the host's
     counters and to the ``serve.decode_dispatch`` span, and every request's
     tokens are ``generate``'s."""
-    from distkeras_tpu import serving
-
     temps, spans = [], []
     real = decode.sample_logits_batched
 
@@ -561,15 +558,16 @@ def test_a_retired_sampling_slot_costs_later_greedy_steps_nothing(
                            temperature)
         return real(logits, positions, temperature, rngs, top_k, top_p)
 
-    @contextlib.contextmanager
-    def record(name, **fields):
-        spans.append((name, fields))
-        yield
-
     monkeypatch.setattr(decode, "sample_logits_batched", spy)
-    monkeypatch.setattr(serving, "span", record)
     kw = dict(paged=True, block_size=4) if paged else {}
     eng = ServingEngine(fitted, num_slots=3, max_len=24, **kw)
+    stamp = eng.account.phase      # the loop's phases open their spans here
+
+    def record(name, **fields):
+        spans.append(("serve." + name, fields))
+        return stamp(name, **fields)
+
+    monkeypatch.setattr(eng.account, "phase", record)
     long_a = eng.submit(PROMPT, 14)
     nucleus = eng.submit(PROMPT[::-1].copy(), 3, temperature=0.7, top_p=0.9,
                          seed=11)

@@ -12,6 +12,7 @@ module-scoped fixtures of this one file.
 import collections
 import contextlib
 import glob
+import json
 import os
 import re
 import time
@@ -24,7 +25,8 @@ import pytest
 from distkeras_tpu import ADAG, Dataset, metrics
 from distkeras_tpu.core.model import FittedModel
 from distkeras_tpu.models import mnist_mlp, transformer_lm
-from distkeras_tpu.serving import ServingEngine, TenantPolicy
+from distkeras_tpu.router import ServingRouter
+from distkeras_tpu.serving import DisaggPair, ServingEngine, TenantPolicy
 
 Span = collections.namedtuple("Span", "start end name thread fields")
 
@@ -133,6 +135,7 @@ SERVE_SPANS = {
     "serve.reap": (),
     "serve.qos": (),
     "serve.schedule": (),
+    "serve.hold": ("reason", "queued"),
     "serve.admit": ("rid",),
     "serve.prefill_unit": ("rid", "tokens", "kind", "width", "hit"),
     "serve.decode_dispatch": ("active", "step", "attn", "sample", "state"),
@@ -158,6 +161,7 @@ def test_engine_run_yields_the_span_with_its_fields(serve_trace, name):
 PARENTS = {
     "serve.reap": "serve.iteration", "serve.qos": "serve.iteration",
     "serve.schedule": "serve.iteration", "serve.admit": "serve.schedule",
+    "serve.hold": "serve.schedule",
     "serve.prefill_unit": "serve.schedule",
     "serve.decode_dispatch": "serve.iteration",
     "serve.fetch": "serve.iteration", "serve.emit": "serve.iteration",
@@ -308,6 +312,208 @@ def test_no_session_no_trace_file(tmp_path, monkeypatch):
         with metrics.span("serve.iteration", it=0):
             pass
     assert not os.listdir(tmp_path)
+
+
+# -- the engine's own account (metrics.EngineAccount) ---------------------------
+
+def inline(eng, requests):
+    """``requests`` (prompt, steps) through ``eng`` on this thread: the
+    same iterations every time."""
+    handles = [eng.submit(p, n) for p, n in requests]
+    eng.run_until_idle()
+    assert all(h.finish == "length" for h in handles)
+    return eng.account.snapshot()
+
+
+def counts(snap):
+    """What of a snapshot is the same from run to run of one workload."""
+    return dict(
+        iterations=snap["iterations"], phases=sorted(snap["phase_s"]),
+        units=snap["prefill_unit"]["n"],
+        classes={c: v["n"] for c, v in snap["classes"].items()},
+        gaps={c: v["n"] for c, v in snap["gap_ms"].items()},
+        held={r: v["n"] for r, v in snap["held"].items()},
+        slowest=len(snap["slowest"]))
+
+
+@pytest.fixture(scope="module")
+def alone():
+    """The long prompt alone, then the short ones, on this thread, no
+    session open."""
+    return inline(engine(), [PROMPTS[1]]), inline(engine(), PROMPTS)
+
+
+def test_the_phases_sum_to_the_loops_wall_time():
+    eng = engine()
+    serve(eng)
+    snap = eng.account.snapshot()
+    assert set(snap["phase_s"]) >= {"reap", "schedule", "decode_dispatch",
+                                    "fetch", "emit", "publish", "idle_wait"}
+    assert snap["iterations"] == eng._iterations
+    assert 0.9 * snap["loop_s"] <= sum(snap["phase_s"].values()) \
+        <= snap["loop_s"]
+    # the iterations' own seconds are the loop's less its waits between them
+    inside = sum(v["s"] for v in snap["classes"].values())
+    assert inside + snap["phase_s"]["idle_wait"] <= snap["loop_s"]
+    assert inside >= sum(v for k, v in snap["phase_s"].items()
+                         if k != "idle_wait")
+    # a unit's host side is part of the schedule pass
+    assert 0 < snap["prefill_unit"]["s"] <= snap["phase_s"]["schedule"]
+
+
+@pytest.mark.parametrize("cls", ["decode", "decode+prefill", "prefill",
+                                 "none"])
+def test_every_class_of_iteration_is_counted(alone, cls):
+    snap = alone[0]
+    # 40 tokens in chunks of 16: two units with no row live, the final one
+    # starts the row and its first step; 3 more steps (the last the
+    # lookahead's junk); then nothing is dispatched: one call drains the
+    # junk step, one finds nothing to do
+    want = {"prefill": 2, "decode+prefill": 1, "decode": 3, "none": 2}
+    assert snap["classes"][cls]["n"] == want[cls]
+    assert (snap["classes"][cls]["s"] > 0) == (want[cls] > 0)
+    assert snap["iterations"] == sum(want.values())
+    assert snap["step_carries_prefill_pct"] == pytest.approx(25.0)
+    assert snap["prefill_unit"]["n"] == 3
+
+
+def test_the_token_gap_is_counted_a_row_and_a_class(alone):
+    gaps = alone[1]["gap_ms"]
+    steps = alone[1]["classes"]
+    # every decode step but the first after an empty pool closes a gap for
+    # each row it held; a step whose iteration dispatched a unit is its own
+    # class
+    assert gaps["all"]["n"] == gaps["step"]["n"] + gaps["step+unit"]["n"] > 0
+    assert gaps["step+unit"]["n"] > 0
+    assert gaps["all"]["n"] < 2 * (steps["decode"]["n"]
+                                   + steps["decode+prefill"]["n"])
+    for c in gaps.values():
+        assert 0 < c["p50"] <= c["p95"]
+
+
+@pytest.mark.parametrize("ms", [0.1, 0.3, 2.5, 33.0, 127.0, 5000.0])
+def test_gap_percentiles_lie_within_a_bucket_of_the_gap(ms):
+    acct = metrics.EngineAccount()
+    for step in range(1, 41):
+        acct.decode_step(step)
+        acct.step_emitted(step, 3, step * ms / 1e3)
+    got = acct.snapshot()["gap_ms"]
+    assert got["step"]["n"] == got["all"]["n"] == 39 * 3
+    assert got["step+unit"] == {"n": 0, "p50": None, "p95": None}
+    for q in ("p50", "p95"):
+        if ms < 0.25:
+            assert 0 <= got["all"][q] <= 0.25
+        elif ms > 4096:
+            assert got["all"][q] == 4096
+        else:
+            assert ms / 2 ** 0.125 <= got["all"][q] <= ms * 2 ** 0.125
+
+
+def test_a_slow_phase_heads_the_slowest_iterations():
+    eng = engine()
+    # every program compiled (warmup() leaves out the one a retirement
+    # runs), then an account of the second request alone
+    inline(eng, [PROMPTS[0]])
+    eng.account = metrics.EngineAccount()
+    eng.start()
+    try:
+        h = eng.submit(*PROMPTS[0])
+        h.set_listener(lambda: len(h.tokens) == 3 and time.sleep(0.03))
+        assert h.wait(120)
+    finally:
+        eng.stop()
+    snap = eng.account.snapshot()
+    top = snap["slowest"][0]
+    # the listener runs in the token loop, asleep: off the CPU, in `emit`
+    assert top["phase"] == "emit" and top["phase_ms"] >= 30
+    assert top["wall_ms"] >= top["phase_ms"] and top["cpu_ms"] < 15
+    assert top["class"] == "decode" and top["active"] == 1
+    assert top["it"] > 1 and snap["slowest"][1]["wall_ms"] < 30
+    assert len(snap["slowest"]) <= 8
+    assert [e["wall_ms"] for e in snap["slowest"]] == sorted(
+        (e["wall_ms"] for e in snap["slowest"]), reverse=True)
+
+
+HELD = {
+    # one slot: the second and third wait for it
+    "no_slot": (dict(num_slots=1), 3),
+    # free slots, one unit an iteration: the third waits for the second's
+    "budget": (dict(num_slots=4, prefills_per_step=1), 3),
+    # a pool of one slot's blocks: 36 positions take 5 of its 8
+    "no_blocks": (dict(num_slots=2, kv_blocks=8, prefills_per_step=2), 2),
+}
+
+
+@pytest.mark.parametrize("reason", sorted(HELD))
+def test_held_says_why_the_queues_head_waited(reason):
+    kw, n = HELD[reason]
+    eng = ServingEngine(FittedModel(*lm()), max_len=64, paged=True,
+                        block_size=8, prefill_chunk=16, **kw)
+    snap = inline(eng, [((np.arange(10) + 11 * i) % 64, 26)
+                        for i in range(n)])
+    assert set(snap["held"]) == {reason}
+    held = snap["held"][reason]
+    assert 0 < held["n"] < snap["iterations"]
+    assert 0 < held["s"] < snap["loop_s"]
+    if reason == "budget":
+        assert held["n"] == 2       # one admission an iteration: 2, then 1
+
+
+@pytest.fixture(scope="module")
+def alone_traced(tmp_path_factory):
+    with metrics.trace(str(tmp_path_factory.mktemp("account_trace"))):
+        return inline(engine(), PROMPTS)
+
+
+def test_the_account_is_the_same_with_a_session_open_and_closed(
+        alone, alone_traced):
+    assert counts(alone_traced) == counts(alone[1])
+    # two slots, one unit an iteration, three requests
+    assert set(counts(alone[1])["held"]) == {"budget", "no_slot"}
+
+
+def test_snapshot_is_plain_json(alone):
+    for snap in alone:
+        assert json.loads(json.dumps(snap)) == snap
+    assert set(alone[1]) == {
+        "loop_s", "iterations", "phase_s", "prefill_unit", "classes",
+        "step_carries_prefill_pct", "gap_ms", "slowest", "held"}
+    assert set(alone[1]["slowest"][0]) == {
+        "it", "at", "wall_ms", "cpu_ms", "class", "phase", "phase_ms",
+        "active"}
+    # an engine that never ran
+    assert json.dumps(metrics.EngineAccount().snapshot())
+
+
+@pytest.mark.parametrize("front", ["pair", "router"])
+def test_merged_stats_are_as_before_and_hold_no_account(front):
+    """``DisaggPair.stats`` / ``ServingRouter.stats`` sum numbers and
+    concatenate lists of ``engine.stats``: the account is an attribute of
+    each engine, never a key of ``stats``."""
+    def eng(role):
+        return ServingEngine(FittedModel(*lm()), num_slots=2, max_len=64,
+                             paged=True, block_size=8, role=role)
+    if front == "pair":
+        whole = DisaggPair([eng("prefill")], decode=eng("decode"),
+                           poll_s=0.005)
+    else:
+        whole = ServingRouter([eng("unified"), eng("unified")])
+    with whole:
+        assert whole.submit(*PROMPTS[0]).wait(120)
+    merged = whole.stats
+    for e in whole.engines:
+        assert "account" not in e.stats
+        assert set(e.stats) <= set(merged)
+        for k, v in e.stats.items():
+            if isinstance(v, list):
+                assert isinstance(merged[k], list), k
+            elif isinstance(v, (int, float)) and not isinstance(v, bool):
+                assert isinstance(merged[k], (int, float)), k
+        assert e.account.snapshot()["iterations"] > 0
+    assert merged["requests_completed"] == 1
+    assert merged["decode_steps"] == sum(
+        e.stats["decode_steps"] for e in whole.engines)
+    assert len(merged["slot_requests"]) == 4
 
 
 # -- scopes and kernel names in the compiled programs ---------------------------
