@@ -76,7 +76,7 @@ TINY = dict(
 REQUIRED_KERNELS = {
     # 8 x 1,024 x 50,257 logits: the kernel's side of the loss's rule
     # (``core.losses.fused_ce_applies``)
-    "SingleTrainer step": ("flash_fwd", "flash_dq", "flash_dkv",
+    "SingleTrainer step": ("flash_fwd", "flash_bwd",
                            "fused_ce_fwd", "fused_ce_bwd"),
     "ParallelTransformerLM step": ("fused_ce_fwd", "fused_ce_bwd"),
     "long-context forward": ("flash_fwd",),
@@ -578,6 +578,7 @@ def kernels(cfg, seed, on_tpu):
     from distkeras_tpu.core.decode import generate
     from distkeras_tpu.core.optimizers import get_schedule
     from distkeras_tpu.models import transformer_lm
+    from distkeras_tpu.ops import flash_attention as flash_ops
     from distkeras_tpu.ops.attention import attention
     from distkeras_tpu.ops.fused_ce import fused_softmax_cross_entropy
     from distkeras_tpu.parallel.pp_transformer import PipelineTransformerLM
@@ -621,6 +622,25 @@ def kernels(cfg, seed, on_tpu):
               f"fwd {fwd}, bwd {bwd}")
         worst = max(worst, fwd)
     out["flash_vs_xla_max_abs_err"] = worst
+
+    # every shape above takes the one-kernel backward (its dq accumulator
+    # fits); the two-pass pair, which longer sequences fall back to, on the
+    # same residuals: the same three gradients
+    q, k, v = qkv((2, 256, 4, 64))
+    g = qkv((2, 256, 4, 64))[0]
+    static, tiles, kv_tiles = flash_ops._resolve(q, True, None, None, None,
+                                                 not on_tpu, None)
+    check(flash_ops._backward_plan(256, 64, 2, True, None)
+          == flash_ops.ONE_KERNEL, "S=256 does not take the one kernel")
+    o, lse = flash_ops._flash_forward(q, k, v, tiles=tiles, **static)
+    one, two = (flash_ops._flash_backward(
+        q, k, v, o, lse, g, tiles=tiles, kv_tiles=kv_tiles, plan=plan,
+        **static) for plan in (flash_ops.ONE_KERNEL, flash_ops.TWO_PASS))
+    err = max(float(np.max(np.abs(f32(a) - f32(b))))
+              for a, b in zip(one, two))
+    check(err < 0.05, f"one-kernel flash backward vs the two-pass pair: "
+          f"{err}")
+    out["flash_bwd_vs_two_pass_max_abs_err"] = err
 
     # paged decode kernel vs the gather path it stands in for, on one arena:
     # ragged rows (one position, a page, a page + 1, the whole view), a dead

@@ -180,8 +180,11 @@ matmuls), ``moe_combine``, ``moe_shared``;
 ``loss``, ``optimizer``, ``commit`` (train step and the SPMD round);
 ``kv_write``, ``kv_gather``, ``sample`` (decode step; on the kernel path
 ``kv_gather`` holds only the row lengths' preparation).  Pallas kernels
-carry the names in ``KERNEL_NAMES``: ``flash_fwd``/``flash_dq``/
-``flash_dkv``, ``fused_ce_fwd``/``fused_ce_bwd``, ``paged_decode``
+carry the names in ``KERNEL_NAMES``: ``flash_fwd`` and the backward's
+``flash_bwd`` (one kernel for dq, dk and dv, wherever its whole-S dq
+accumulator fits VMEM: the name in a trace says which form engaged) or
+``flash_dq``/``flash_dkv`` (the two-pass pair of the sequences where it does
+not), ``fused_ce_fwd``/``fused_ce_bwd``, ``paged_decode``
 (under ``attn_core`` of the paged single-token step), ``kda_decode``
 (under ``kda_core`` of the same step) and ``ssd_decode`` (under
 ``ssm_core``).  The experts' grouped matmul is
@@ -204,7 +207,7 @@ import jax
 #: ``name=`` of every ``pallas_call`` in ``ops/``: what a trace, an HLO dump,
 #: ``chip_smoke.py``'s ``require_kernels`` and ``tests/test_tracing.py`` look
 #: the kernels up by.
-KERNEL_NAMES = ("flash_fwd", "flash_dq", "flash_dkv",
+KERNEL_NAMES = ("flash_fwd", "flash_bwd", "flash_dq", "flash_dkv",
                 "fused_ce_fwd", "fused_ce_bwd", "paged_decode", "kda_decode",
                 "ssd_decode")
 

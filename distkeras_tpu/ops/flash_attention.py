@@ -23,7 +23,9 @@ MXU:
    chunks the diagonal or the window's edge crosses.  f32 VMEM scratch
    accumulators carry across the inner grid dimension and the output block
    is written on its last step, so no kernel holds a whole (S, D) operand
-   unless S fits one tile — which is what bounds sequence length;
+   unless S fits one tile (the one-kernel backward's dq accumulator is the
+   exception, taken only where it fits) — which is what bounds sequence
+   length;
  - tiles are chosen from what the call shows (``_tiles``: S, head_dim,
    itemsize, causal, window) inside a VMEM budget; an explicit
    ``block_q``/``block_k`` is honoured as given (one chunk per grid step
@@ -34,15 +36,32 @@ MXU:
    statistics the backward needs (inside the kernels per-row statistics
    live broadcast over a 128-lane trailing dim, the TPU-tileable layout;
    that copy never leaves VMEM);
- - backward is the two-pass recompute schedule over the saved
-   (q, k, v, lse) and Δ = rowsum(dO ∘ O), computed once per call by XLA —
-   no (S×S) intermediate is ever materialized:
-     * dq kernel, grid (B·H, S/rows, S/span): recompute
-       p = exp(q·kᵀ·scale − lse), accumulate dq += (p ∘ (dO·vᵀ − Δ))·k;
-     * dk/dv kernel, grid (B·H, S/rows, S/span) with k/v as the outer
-       tile, in TRANSPOSED space — scores are (keys, queries), so lse and Δ
-       are used as the lane-dense rows they are stored as and
-       dv += pᵀ·dO, dk += dsᵀ·q need no transposed operand;
+ - backward recomputes p from the saved (q, k, v, lse) and
+   Δ = rowsum(dO ∘ O), computed once per call by XLA — no (S×S)
+   intermediate is ever materialized.  It runs in TRANSPOSED space, grid
+   (B·H, S/rows, S/span) with k/v as the outer tile: scores are
+   (keys, queries), so lse and Δ are used as the lane-dense rows they are
+   stored as and dv += pᵀ·dO, dk += dsᵀ·q need no transposed operand.
+   Two forms, chosen by ``_backward_plan`` from the call's shapes alone:
+     * ONE kernel (``flash_bwd``): per visible chunk one recompute of
+       p = exp(k·qᵀ·scale − lse) and ds = p ∘ (v·dOᵀ − Δ) feeds dv, dk AND
+       dq[chunk] += dsᵀ·k — five matmuls, one ``exp``.  dq accumulates in
+       an f32 VMEM scratch that holds the whole (S, Dh) of the batch·head
+       across its k/v tiles and is written out on the head's last step;
+     * the two-pass pair, where that accumulator does not fit: a dq kernel
+       (``flash_dq``, q as the outer tile, dq += (p ∘ (dO·vᵀ − Δ))·k) and
+       the dk/dv kernel (``flash_dkv``) each recompute p — seven matmuls,
+       two ``exp``, nothing that grows with S in VMEM.
+   The rule: the one-kernel form is taken when, WITH the (rows, span) the
+   call gets anyway (``_tiles``' or the explicit blocks), ``_vmem_bytes``
+   plus the accumulator and the double-buffered dq block —
+   S × max(Dh, 128) × (4 + 2·itemsize) bytes, a 64-wide row pads to the
+   128 lanes — stays inside ``VMEM_BUDGET`` (24 MiB).  At the largest
+   tiles that is 1 KiB a position in bf16 over 6.9 MiB (Dh = 64) or
+   8.1 MiB (Dh = 128): every S up to 8,192 takes one kernel, 16,384 only
+   at Dh = 64 (or under a window short enough to cap the rows), 32,768
+   and longer never; f32 (1.5 KiB a position) up to 8,192.  No argument,
+   environment variable or model takes part;
    the softmax scale multiplies the f32 scores and, once, the finished dq
    and dk accumulators.
 
@@ -75,6 +94,7 @@ MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128  # TPU lane width: in VMEM, per-row stats are broadcast over it
 _NT = (((1,), (1,)), ((), ()))  # a · bᵀ
 _NN = (((1,), (0,)), ((), ()))  # a · b
+_TN = (((0,), (0,)), ((), ()))  # aᵀ · b
 
 # tile choice (``_tiles``): the budget is what the kernels may ask of VMEM
 # for their double-buffered blocks, scratch and score-tile temporaries
@@ -99,16 +119,20 @@ def _chunk(span: int) -> int:
 
 
 def _vmem_bytes(rows: int, span: int, chunk: int, d: int,
-                itemsize: int) -> int:
+                itemsize: int, dq_rows: int = 0) -> int:
     """What one kernel asks of VMEM (the dk/dv kernel, the largest):
     double-buffered blocks of the outer tile (k, v in; dk, dv out) and the
     inner one (q, dO, two statistics rows), the f32 accumulators and
     statistics scratch, and the (rows, chunk) f32 score-tile temporaries
-    (s, p, dp, ds and the operand-dtype copies of p and ds)."""
+    (s, p, dp, ds and the operand-dtype copies of p and ds).  ``dq_rows``:
+    the one-kernel backward also holds that many rows (the whole S) of dq,
+    as its f32 accumulator and its double-buffered output block, each row
+    padded to the 128 lanes."""
     blocks = 2 * (4 * rows + 2 * span) * d * itemsize + 2 * 2 * 8 * span * 4
     scratch = rows * (2 * d + 2 * _LANES) * 4
     temps = rows * chunk * (4 * 4 + 2 * itemsize)
-    return blocks + scratch + temps
+    dq = dq_rows * max(d, _LANES) * (4 + 2 * itemsize)
+    return blocks + scratch + temps + dq
 
 
 def _tiles(s: int, d: int, itemsize: int, causal: bool,
@@ -136,6 +160,22 @@ def _tiles(s: int, d: int, itemsize: int, causal: bool,
                            itemsize) <= VMEM_BUDGET:
                 return rows, span
     return _divisors(s, _LANES)[-1], _divisors(s, _LANES)[-1]
+
+
+ONE_KERNEL, TWO_PASS = ("flash_bwd",), ("flash_dq", "flash_dkv")
+
+
+def _backward_plan(s: int, d: int, itemsize: int, causal: bool,
+                   window: Optional[int],
+                   tiles: Optional[Tuple[int, int]] = None):
+    """The kernels a call's backward runs, by name: ``ONE_KERNEL`` where
+    the whole-S dq accumulator fits the VMEM budget beside the (rows, span)
+    the dk/dv schedule has anyway (``tiles``: explicit blocks; ``_tiles``'
+    otherwise — they are never shrunk to make room), else ``TWO_PASS``."""
+    rows, span = tiles or _tiles(s, d, itemsize, causal, window)
+    fits = _vmem_bytes(rows, span, _chunk(span), d, itemsize,
+                       dq_rows=s) <= VMEM_BUDGET
+    return ONE_KERNEL if fits else TWO_PASS
 
 
 def _rep(x, n: int):
@@ -315,18 +355,31 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
-                dv_ref, dk_scr, dv_scr, *, scale: float, causal: bool,
-                window: Optional[int], rows: int, span: int, chunk: int):
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
+                scale: float, causal: bool, window: Optional[int], rows: int,
+                span: int, chunk: int):
+    # outputs/scratch: [dq_ref,] dk_ref, dv_ref, dk_scr, dv_scr[, dq_scr] —
+    # with dq this is the one-kernel backward: dq_ref is the whole (S, Dh)
+    # of the batch·head, dq_scr its f32 accumulator across the k/v tiles
+    if len(rest) == 6:
+        dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, dq_scr = rest
+    else:
+        (dk_ref, dv_ref, dk_scr, dv_scr), dq_ref, dq_scr = rest, None, None
     # transposed space: a score tile is (keys, queries), so the per-query
     # statistics are the (1, chunk) lane-dense rows they are stored as
     ki, qj = pl.program_id(1), pl.program_id(2)
     k0, q0 = ki * rows, qj * span
+    last_q = qj == pl.num_programs(2) - 1
 
     @pl.when(qj == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    if dq_ref is not None:
+        @pl.when((ki == 0) & (qj == 0))
+        def _init_dq():
+            dq_scr[...] = jnp.zeros_like(dq_scr)
 
     def step(c, masked):
         qs = pl.ds(_offset(c, chunk, span), chunk)
@@ -341,17 +394,26 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
             pt.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
         dpt = jax.lax.dot_general(v_ref[0], do, _NT,
                                   preferred_element_type=jnp.float32)
-        dst = pt * (dpt - delta_ref[0, :, qs])
+        dst = (pt * (dpt - delta_ref[0, :, qs])).astype(q.dtype)
         dk_scr[...] += jax.lax.dot_general(
-            dst.astype(q.dtype), q, _NN, preferred_element_type=jnp.float32)
+            dst, q, _NN, preferred_element_type=jnp.float32)
+        if dq_ref is not None:
+            at = pl.multiple_of(q0 + qs.start, chunk)
+            dq_scr[pl.ds(at, chunk), :] += jax.lax.dot_general(
+                dst, k_ref[0], _TN, preferred_element_type=jnp.float32)
 
     _walk(step, *_q_chunks(k0, rows, q0, chunk, span // chunk, causal,
                            window))
 
-    @pl.when(qj == pl.num_programs(2) - 1)
+    @pl.when(last_q)
     def _finalize():
         dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+    if dq_ref is not None:
+        @pl.when(last_q & (ki == pl.num_programs(1) - 1))
+        def _finalize_dq():
+            dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
 def _fold(t):
@@ -366,9 +428,11 @@ def _unfold(t, b: int):
 
 
 def _call(kernel, name, tiles, q, *, scale, causal, window, interpret,
-          **pallas):
-    """The part of a ``pallas_call`` the three kernels share: the grid, the
-    static parameters, the compiler's VMEM allowance."""
+          outer="parallel", **pallas):
+    """The part of a ``pallas_call`` the kernels share: the grid, the
+    static parameters, the compiler's VMEM allowance.  ``outer``: the outer
+    tile's dimension is ``"arbitrary"`` where an accumulator crosses it
+    (the one-kernel backward's dq)."""
     rows, span, chunk = tiles
     bh, s, _ = q.shape
     return pl.pallas_call(
@@ -376,7 +440,7 @@ def _call(kernel, name, tiles, q, *, scale, causal, window, interpret,
                           rows=rows, span=span, chunk=chunk),
         grid=(bh, s // rows, s // span),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", outer, "arbitrary"),
             vmem_limit_bytes=VMEM_BUDGET + (8 << 20)),
         interpret=interpret, name=name, **pallas)
 
@@ -427,10 +491,13 @@ def _flash_forward(q, k, v, *, scale: float, causal: bool,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "window", "tiles", "kv_tiles", "interpret"))
+    "scale", "causal", "window", "tiles", "kv_tiles", "plan", "interpret"))
 def _flash_backward(q, k, v, out, lse, g, *, scale: float, causal: bool,
-                    window: Optional[int], tiles, kv_tiles,
+                    window: Optional[int], tiles, kv_tiles, plan,
                     interpret: bool):
+    """``plan``: ``_backward_plan``'s answer for the call — ONE_KERNEL on
+    ``kv_tiles``, or TWO_PASS (the dq kernel on ``tiles``, dk/dv on
+    ``kv_tiles``)."""
     b, s, h, d = q.shape
     qf, kf, vf, gf = _fold(q), _fold(k), _fold(v), _fold(g)
     # Δ = rowsum(dO ∘ O), once per call, stored as lse is
@@ -439,27 +506,37 @@ def _flash_backward(q, k, v, out, lse, g, *, scale: float, causal: bool,
     shared = dict(scale=scale, causal=causal, window=window,
                   interpret=interpret)
 
-    rows = tiles[0]
-    q_spec, k_spec, q_row, _ = _specs(tiles, s, d, causal, window, True)
-    dq = _call(_dq_kernel, "flash_dq", tiles, qf,
-               out_shape=out_struct(qf.shape, q.dtype, qf),
-               in_specs=[q_spec, k_spec, k_spec, q_spec, q_row, q_row],
-               out_specs=q_spec,
-               scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
-                               pltpu.VMEM((rows, _LANES), jnp.float32),
-                               pltpu.VMEM((rows, _LANES), jnp.float32)],
-               **shared)(qf, kf, vf, gf, lse, delta)
+    # under shard_map the outputs vary as their primals do: dq as q, dk/dv
+    # as k/v
+    dq_shape = out_struct(qf.shape, q.dtype, qf)
+    one = plan == ONE_KERNEL
+    if not one:
+        rows = tiles[0]
+        q_spec, k_spec, q_row, _ = _specs(tiles, s, d, causal, window, True)
+        dq = _call(_dq_kernel, "flash_dq", tiles, qf, out_shape=dq_shape,
+                   in_specs=[q_spec, k_spec, k_spec, q_spec, q_row, q_row],
+                   out_specs=q_spec,
+                   scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
+                                   pltpu.VMEM((rows, _LANES), jnp.float32),
+                                   pltpu.VMEM((rows, _LANES), jnp.float32)],
+                   **shared)(qf, kf, vf, gf, lse, delta)
 
+    # the dk/dv schedule; the one kernel adds dq's whole-S output block
+    # (first) and accumulator (last) to it; either plan names it last
     rows = kv_tiles[0]
     k_spec, q_spec, _, q_row = _specs(kv_tiles, s, d, causal, window, False)
-    dk, dv = _call(_dkv_kernel, "flash_dkv", kv_tiles, qf,
-                   out_shape=(out_struct(kf.shape, k.dtype, kf),
-                              out_struct(vf.shape, v.dtype, vf)),
-                   in_specs=[q_spec, k_spec, k_spec, q_spec, q_row, q_row],
-                   out_specs=(k_spec, k_spec),
-                   scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32),
-                                   pltpu.VMEM((rows, d), jnp.float32)],
-                   **shared)(qf, kf, vf, gf, lse, delta)
+    whole = pl.BlockSpec((1, s, d), lambda bh, i, j: (bh, 0, 0))
+    res = _call(_dkv_kernel, plan[-1], kv_tiles, qf,
+                outer="arbitrary" if one else "parallel",
+                out_shape=(dq_shape,) * one + (
+                    out_struct(kf.shape, k.dtype, kf),
+                    out_struct(vf.shape, v.dtype, vf)),
+                in_specs=[q_spec, k_spec, k_spec, q_spec, q_row, q_row],
+                out_specs=(whole,) * one + (k_spec, k_spec),
+                scratch_shapes=[pltpu.VMEM((rows, d), jnp.float32)] * 2
+                + [pltpu.VMEM((s, d), jnp.float32)] * one,
+                **shared)(qf, kf, vf, gf, lse, delta)
+    dq, dk, dv = res if one else (dq, *res)
     return _unfold(dq, b), _unfold(dk, b), _unfold(dv, b)
 
 
@@ -467,7 +544,8 @@ def _resolve(q, causal, scale, block_q, block_k, interpret, window):
     """nondiff_argnums hand each custom_vjp entry point the raw argument
     values, so defaults resolve in one place for primal/fwd/bwd alike:
     the static parameters of the jitted kernels' wrappers, with the tiles
-    of the q-outer kernels and of the dk/dv kernel as (rows, span, chunk)."""
+    of the q-outer kernels and of the k/v-outer schedule (dk/dv, or the
+    one-kernel backward) as (rows, span, chunk)."""
     window = validate_window(window, causal)
     s, d = q.shape[1], q.shape[3]
     if scale is None:
@@ -519,8 +597,10 @@ def _bwd(causal, scale, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse = res
     static, tiles, kv_tiles = _resolve(q, causal, scale, block_q, block_k,
                                        interpret, window)
+    plan = _backward_plan(q.shape[1], q.shape[3], q.dtype.itemsize, causal,
+                          static["window"], kv_tiles[:2])
     return _flash_backward(q, k, v, out, lse, g, tiles=tiles,
-                           kv_tiles=kv_tiles, **static)
+                           kv_tiles=kv_tiles, plan=plan, **static)
 
 
 flash_attention.defvjp(_fwd, _bwd)

@@ -248,3 +248,135 @@ def test_tile_function_refuses_what_the_dispatcher_refuses(monkeypatch):
         else:
             with pytest.raises(ValueError, match="not tileable"):
                 flash_ops._tiles(s, 64, 2, True, None)
+
+
+# -- the backward's two forms (``_backward_plan``) ----------------------------
+
+ENVELOPE = {  # (B, S, H, Dh), kv heads, causal, window, dtype, (bq, bk)
+    "causal-16x16": ((2, 64, 2, 16), None, True, None, "float32", (16, 16)),
+    "full-16x16": ((2, 64, 2, 16), None, False, None, "float32", (16, 16)),
+    "window5-16x16": ((1, 64, 2, 8), None, True, 5, "float32", (16, 16)),
+    "window40-16x32": ((1, 64, 2, 8), None, True, 40, "float32", (16, 32)),
+    "causal-32x16-bf16": ((1, 64, 2, 16), None, True, None, "bfloat16",
+                          (32, 16)),
+    "one-block-s16": ((1, 16, 2, 16), None, True, None, "float32",
+                      (128, 128)),
+    "one-block-s112": ((1, 112, 2, 64), None, True, None, "bfloat16", None),
+    "gqa-s128": ((1, 128, 4, 64), 2, True, None, "bfloat16", None),
+    "s384-d64-bf16": ((1, 384, 2, 64), None, True, None, "bfloat16", None),
+    "s384-window256": ((1, 384, 1, 64), None, True, 256, "bfloat16", None),
+    "s640-d128-f32": ((1, 640, 1, 128), None, True, None, "float32", None),
+    "s640-full-d64": ((1, 640, 1, 64), None, False, None, "bfloat16", None),
+    "s640-128x128": ((1, 640, 1, 64), None, True, None, "bfloat16",
+                     (128, 128)),
+    "cell-s1024-d64": ((1, 1024, 1, 64), None, True, None, "bfloat16", None),
+}
+
+
+@pytest.mark.parametrize("case", list(ENVELOPE))
+def test_one_kernel_backward_matches_oracle_and_two_pass(case, monkeypatch):
+    """The one-kernel backward (what ``_backward_plan`` gives every shape
+    here) against the XLA oracle at the parity tests' tolerances AND against
+    the two-pass pair on the same residuals: dk and dv to the bit (the same
+    code computes them), dq within f32 summation order (a rounding step of
+    the operand dtype where an f32 sum lands on either side of one)."""
+    shape, kv_heads, causal, window, dtype, blocks = ENVELOPE[case]
+    b, s, h, d = shape
+    q, k, v = (t.astype(dtype) for t in rand_qkv(s + d, b=b, s=s, h=h, d=d))
+    if kv_heads:
+        k, v = k[:, :, :kv_heads], v[:, :, :kv_heads]
+    ct = jax.random.normal(jax.random.PRNGKey(s), q.shape).astype(dtype)
+    bq, bk = blocks or (None, None)
+    plans = []
+    chosen = flash_ops._backward_plan
+
+    def flash(q, k, v):
+        if kv_heads:  # the dispatcher repeats K/V up to H; XLA sums back
+            return attention_ops.attention(q, k, v, causal=causal,
+                                           window=window, impl="pallas")
+        return flash_attention(q, k, v, causal, None, bq, bk, True, window)
+
+    def grads(attn, plan=None):
+        def planned(*a):
+            plans.append(chosen(*a))
+            return plan or plans[-1]
+        monkeypatch.setattr(flash_ops, "_backward_plan", planned)
+        return jax.vjp(attn, q, k, v)[1](ct)
+
+    one = grads(flash)
+    two = grads(flash, flash_ops.TWO_PASS)
+    want = grads(lambda q, k, v: attention_ops.attention(
+        q, k, v, causal=causal, window=window, impl="xla"))
+    assert plans == [flash_ops.ONE_KERNEL] * 2
+    bf16 = dtype == "bfloat16"
+    for name, a, b2, w in zip(("dq", "dk", "dv"), one, two, want):
+        assert a.dtype == b2.dtype == q.dtype and a.shape == w.shape
+        a, b2, w = (np.asarray(t, dtype=np.float32) for t in (a, b2, w))
+        np.testing.assert_allclose(a, w, atol=0.06 if bf16 else 2e-4,
+                                   err_msg=f"{name} vs the oracle")
+        if name == "dq":
+            np.testing.assert_allclose(
+                a, b2, rtol=2 ** -7 if bf16 else 1e-5,
+                atol=1e-3 if bf16 else 1e-5, err_msg="dq vs two-pass")
+        else:
+            np.testing.assert_array_equal(a, b2, err_msg=f"{name} vs "
+                                          "two-pass")
+
+
+PLANS = {  # (S, Dh, itemsize, causal, window) -> one kernel?
+    "cell": ((1024, 64, 2, True, None), True),
+    "s128k-d128": ((131072, 128, 2, True, None), False),
+    "s8192-d128-bf16": ((8192, 128, 2, True, None), True),
+    "s8192-d128-f32": ((8192, 128, 4, True, None), True),
+    "s8192-d128-full": ((8192, 128, 2, False, None), True),
+    # either side of the threshold at the largest tiles, Dh = 128 in bf16:
+    # 8.1 MiB of tiles + 1 KiB a position against 24 MiB
+    "s15360-d128": ((15360, 128, 2, True, None), True),
+    "s16384-d128": ((16384, 128, 2, True, None), False),
+    # a window of 512 caps the rows at 256: smaller tiles, so it fits
+    "s16384-d128-window512": ((16384, 128, 2, True, 512), True),
+    "s32768-d128-window512": ((32768, 128, 2, True, 512), False),
+    # Dh = 64 pads to the same 1 KiB a position beside smaller tiles
+    "s16384-d64": ((16384, 64, 2, True, None), True),
+    "s24576-d64": ((24576, 64, 2, True, None), False),
+    "s16384-d64-f32": ((16384, 64, 4, True, None), False),
+}
+
+
+@pytest.mark.parametrize("case", list(PLANS))
+def test_backward_plan(case):
+    """``_backward_plan`` alone: the one kernel exactly where the whole-S dq
+    accumulator and its double-buffered block fit the budget beside the
+    tiles ``_tiles`` picks (never smaller ones), the pair elsewhere."""
+    (s, d, itemsize, causal, window), one = PLANS[case]
+    plan = flash_ops._backward_plan(s, d, itemsize, causal, window)
+    assert plan == (flash_ops.ONE_KERNEL if one else flash_ops.TWO_PASS)
+    rows, span = flash_ops._tiles(s, d, itemsize, causal, window)
+    need = (flash_ops._vmem_bytes(rows, span, flash_ops._chunk(span), d,
+                                  itemsize)
+            + s * max(d, 128) * (4 + 2 * itemsize))
+    assert one == (need <= flash_ops.VMEM_BUDGET)
+
+
+def test_backward_plan_over_the_lengths():
+    """Every tileable length: up to 8,192 one kernel at Dh <= 128 in either
+    dtype, from 32,768 the pair; explicit blocks are taken as given (small
+    ones leave room a long sequence's accumulator still has to fit)."""
+    for s in LENGTHS:
+        for d in (64, 128):
+            for itemsize in (2, 4):
+                for causal, window in ((False, None), (True, None),
+                                       (True, 512)):
+                    plan = flash_ops._backward_plan(s, d, itemsize, causal,
+                                                    window)
+                    if s <= 8192:
+                        assert plan == flash_ops.ONE_KERNEL, (s, d, itemsize)
+                    if s >= 32768:
+                        assert plan == flash_ops.TWO_PASS, (s, d, itemsize)
+    small = (128, 128)
+    assert flash_ops._backward_plan(16384, 128, 2, True, None,
+                                    small) == flash_ops.ONE_KERNEL
+    assert flash_ops._backward_plan(32768, 128, 2, True, None,
+                                    small) == flash_ops.TWO_PASS
+    assert flash_ops.ONE_KERNEL + flash_ops.TWO_PASS == (
+        "flash_bwd", "flash_dq", "flash_dkv")

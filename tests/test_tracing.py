@@ -587,10 +587,13 @@ def kernels_lowered():
     from distkeras_tpu.ops.kda import kda_decode
     from distkeras_tpu.ops.ssd import ssd_decode
 
-    def both(q, logits, labels):
-        attn = flash_attention(q, q, q, causal=True, interpret=False)
+    def both(q, long, logits, labels):
+        # ``q``'s backward is the one kernel; ``long``'s dq accumulator
+        # does not fit VMEM, so its backward is the two-pass pair
+        attn = sum(flash_attention(t, t, t, causal=True, interpret=False)
+                   .astype(jnp.float32).sum() for t in (q, long))
         ce = fused_softmax_cross_entropy(logits, labels, interpret=False)
-        return attn.astype(jnp.float32).sum() + ce.sum()
+        return attn + ce.sum()
 
     def step(vec, state):
         return kda_decode(vec, vec, vec, vec, vec[..., 0], state,
@@ -602,12 +605,13 @@ def kernels_lowered():
                           interpret=False)
 
     q = jax.ShapeDtypeStruct((2, 128, 2, 64), jnp.bfloat16)
+    long = jax.ShapeDtypeStruct((1, 16384, 1, 128), jnp.bfloat16)
     logits = jax.ShapeDtypeStruct((256, 512), jnp.float32)
     labels = jax.ShapeDtypeStruct((256,), jnp.int32)
     vec = jax.ShapeDtypeStruct((1, 8, 128), jnp.float32)
     state = jax.ShapeDtypeStruct((1, 8, 128, 128), jnp.float32)
-    return (jax.jit(jax.grad(both, argnums=(0, 1))).trace(
-        q, logits, labels).lower(lowering_platforms=("tpu",)).as_text()
+    return (jax.jit(jax.grad(both, argnums=(0, 1, 2))).trace(
+        q, long, logits, labels).lower(lowering_platforms=("tpu",)).as_text()
         + jax.jit(step).trace(vec, state).lower(
             lowering_platforms=("tpu",)).as_text()
         + jax.jit(ssd_step).trace(vec, state).lower(
@@ -619,6 +623,37 @@ def kernels_lowered():
                                   if k != "paged_decode"])
 def test_the_kernels_carry_their_names(kernels_lowered, name):
     assert f'kernel_name = "{name}"' in kernels_lowered
+
+
+def test_the_train_step_holds_one_backward_kernel_a_layer(monkeypatch):
+    """The training cell's attention, (8, 1024, 12, 64) in bf16, lowered
+    for a TPU: the whole-S dq accumulator fits, so a layer's backward is
+    the ONE kernel and neither of the two-pass pair; the layers share one
+    lowering of each kernel (the wrappers are jitted) and each calls it
+    from under its own ``attn_core``."""
+    import optax
+    from distkeras_tpu.core.train import make_masked_step
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = transformer_lm(vocab_size=256, seq_len=1024, d_model=768,
+                           num_heads=12, num_layers=2, mlp_dim=256,
+                           compute_dtype="bfloat16")
+    params = jax.eval_shape(lambda k: model.init(k, (1024,)),
+                            jax.random.PRNGKey(0))
+    tx = optax.adam(1e-3)
+    step = make_masked_step(
+        model, "sparse_categorical_crossentropy_from_logits", tx)
+    x = jax.ShapeDtypeStruct((8, 1024), jnp.int32)
+    text = jax.jit(step).trace(
+        params, jax.eval_shape(tx.init, params), x, x,
+        jax.ShapeDtypeStruct((8,), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)).lower(
+            lowering_platforms=("tpu",)).as_text(debug_info=True)
+    count = lambda name: text.count(f'kernel_name = "{name}"')
+    assert count("flash_fwd") == count("flash_bwd") == 1
+    assert count("flash_dq") == count("flash_dkv") == 0
+    for block in ("block_0", "block_1"):
+        assert (f"transpose(jvp({block}))/attn/attn_core/"
+                "jit(_flash_backward)") in text
 
 
 def test_chip_smoke_requires_kernels_the_package_names():
