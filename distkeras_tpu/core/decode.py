@@ -32,9 +32,10 @@ tmap = jax.tree_util.tree_map
 
 from .layers import (Dense, Embedding, HybridBlock, LayerNormalization,
                      MultiHeadAttention, PositionalEmbedding, RMSNorm,
-                     TransformerBlock, _project, scope_names)
+                     TiedHead, TransformerBlock, _project, params_of,
+                     scope_names)
 
-_STATELESS = (LayerNormalization, RMSNorm, Dense)
+_STATELESS = (LayerNormalization, RMSNorm, Dense, TiedHead)
 #: the layers that keep per-request state.  What they keep is their mixer's
 #: ``state_kind`` — ``kv``: keys and values of every position (a dense slab,
 #: or rows of ``Hkv * Dh`` features in a paged arena); ``recurrent``: a
@@ -53,8 +54,8 @@ def _check_supported(model) -> None:
                 "decoding walks Embedding / PositionalEmbedding / "
                 "TransformerBlock / HybridBlock (a GatedAttention, "
                 "MultiHeadAttention, KimiDeltaAttention or Mamba2Mixer "
-                "mixer, a SparseMoE, or both) / "
-                "LayerNormalization / RMSNorm / Dense sequences "
+                "mixer, a SparseMoE or GatedMLP, or both) / "
+                "LayerNormalization / RMSNorm / Dense / TiedHead sequences "
                 "(transformer_lm and hybrid_lm)")
         if isinstance(layer, _BLOCKS) and not layer.causal:
             raise ValueError(
@@ -483,6 +484,7 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
     def attend(k, v, **where):
         with jax.named_scope("attn_core"):
             return dot_product_attention(q, k, v, causal=True,
+                                         scale=mha.score_scale,
                                          window=mha.attention_window,
                                          **where)
 
@@ -523,7 +525,7 @@ def _mha_forward(mha: MultiHeadAttention, params, h, cache, pos, cdtype,
             with jax.named_scope("attn_core"):
                 out = paged_decode_attention(
                     q[:, 0], new_cache["k"], new_cache["v"], paged.tables,
-                    lengths, bs)[:, None]
+                    lengths, bs, scale=mha.score_scale)[:, None]
         else:
             def view_of(name):  # each row's (view, ...) entries
                 return paged_gather(new_cache[name], paged.tables, bs, view)
@@ -733,13 +735,11 @@ def _forward(model, params, caches, toks, pos, rolling: bool = False,
     if any(isinstance(layer, _BLOCKS) and layer.wants_token_mask
            for layer in model.layers):
         token_mask = _token_mask(toks.shape[1], pos, paged, rows)
-    for layer, p, cache, scope in zip(model.layers, params, caches,
-                                      scope_names(model.layers)):
+    for i, (layer, p, cache, scope) in enumerate(zip(
+            model.layers, params, caches, scope_names(model.layers))):
         with jax.named_scope(scope):
             if isinstance(layer, Embedding):
-                # jnp.asarray: trained params may live as host numpy arrays
-                # (FittedModel), which tracer-indexing rejects
-                x = jnp.asarray(p["embedding"]).astype(cdtype)[toks]
+                x = layer.apply(p, toks, compute_dtype=cdtype)
             elif isinstance(layer, PositionalEmbedding):
                 if _per_row(pos) and toks.shape[1] == 1:
                     pe = jnp.asarray(p["embedding"])[pos]          # (B, D)
@@ -762,8 +762,9 @@ def _forward(model, params, caches, toks, pos, rolling: bool = False,
                     token_mask)
                 if aux is not None and counters is not None:
                     aux.append(counters)
-            else:  # norms / Dense: position-independent
-                x = layer.apply(p, x, compute_dtype=cdtype, train=False)
+            else:  # norms / Dense / TiedHead: position-independent
+                x = layer.apply(params_of(model.layers, params, i), x,
+                                compute_dtype=cdtype, train=False)
         new_caches.append(cache)
     return x.astype(jnp.float32), new_caches
 

@@ -556,6 +556,8 @@ class MultiHeadAttention(Layer):
     #: ``out * sigmoid(x @ wg)`` before the output projection
     #: (``GatedAttention`` sets it)
     output_gate: bool = False
+    #: what the scores are multiplied by; None = ``key_dim ** -0.5``
+    score_scale: Optional[float] = None
     #: what a cached step keeps for this mixer (``core/decode.py``)
     state_kind = "kv"
     #: the ``jax.named_scope`` a block runs this mixer under
@@ -566,12 +568,15 @@ class MultiHeadAttention(Layer):
                  num_kv_heads: Optional[int] = None,
                  attention_window: Optional[int] = None,
                  rope: bool = False, rope_theta: float = 10000.0,
-                 rope_scale: float = 1.0):
+                 rope_scale: float = 1.0,
+                 score_scale: Optional[float] = None):
         self.num_heads = int(num_heads)
         self.key_dim = int(key_dim)  # per-head dim
         self.causal = bool(causal)
         self.use_bias = bool(use_bias)
         self.attention_impl = attention_impl
+        if score_scale is not None:
+            self.score_scale = float(score_scale)
         if num_kv_heads is not None:
             self.num_kv_heads = int(num_kv_heads)
             if self.num_heads % self.num_kv_heads:
@@ -646,7 +651,7 @@ class MultiHeadAttention(Layer):
             q = apply_rope(q, pos, self.rope_theta, self.rope_scale)
             k = apply_rope(k, pos, self.rope_theta, self.rope_scale)
         with jax.named_scope("attn_core"):
-            out = attention(q, k, v,
+            out = attention(q, k, v, scale=self.score_scale,
                             causal=self.causal, impl=self.attention_impl,
                             window=self.attention_window,
                             segment_ids=segment_ids)
@@ -786,9 +791,15 @@ class TransformerBlock(Layer):
 
 
 class Embedding(Layer):
-    def __init__(self, input_dim: int, output_dim: int):
+    #: what a looked-up row is multiplied by (1: nothing is multiplied)
+    output_scale: float = 1.0
+
+    def __init__(self, input_dim: int, output_dim: int,
+                 output_scale: float = 1.0):
         self.input_dim = int(input_dim)
         self.output_dim = int(output_dim)
+        if output_scale != 1.0:
+            self.output_scale = float(output_scale)
 
     def init(self, rng, in_shape):
         params = {"embedding": 0.02 * jax.random.normal(
@@ -797,7 +808,42 @@ class Embedding(Layer):
 
     def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
               rng=None):
-        return params["embedding"].astype(compute_dtype)[x]
+        # jnp.asarray: trained params may live as host numpy arrays
+        # (FittedModel), which tracer-indexing rejects
+        rows = jnp.asarray(params["embedding"]).astype(compute_dtype)[x]
+        if self.output_scale == 1.0:
+            return rows
+        return (rows.astype(jnp.float32) * self.output_scale).astype(
+            compute_dtype)
+
+
+class TiedHead(Layer):
+    """The LM head of a model whose head IS its embedding table: ``logits =
+    x E^T / divisor`` with ``E`` the ``(V, D)`` table of layer ``tied_to``,
+    read as it lies (contracted over ``D``: no transposed copy is made or
+    kept).  The layer has NO parameters of its own (``init`` gives ``{}``),
+    so ``get_weights`` / ``set_weights``, checkpoints and a ``ServingEngine``
+    hold one table; ``Sequential.apply`` and the cached step hand it the
+    parameters of the layer it is tied to (``params_of``, which refuses an
+    index that is not an ``Embedding``)."""
+
+    def __init__(self, units: int, tied_to: int = 0, divisor: float = 1.0):
+        self.units = int(units)        # the table's rows
+        self.tied_to = int(tied_to)
+        self.divisor = float(divisor)
+
+    def init(self, rng, in_shape):
+        return {}, tuple(in_shape[:-1]) + (self.units,)
+
+    def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
+              rng=None):
+        table = jnp.asarray(params["embedding"]).astype(compute_dtype)
+        y = jax.lax.dot_general(
+            x.astype(compute_dtype).reshape(-1, x.shape[-1]), table,
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        if self.divisor != 1.0:
+            y = y / self.divisor
+        return y.reshape(x.shape[:-1] + (table.shape[0],))
 
 
 # ---------------------------------------------------------------------------
@@ -1155,6 +1201,39 @@ def _expert_mlp(x, w_in, w_out, form: str, compute_dtype):
     return _project(h.astype(compute_dtype), w_out, None, compute_dtype)
 
 
+class GatedMLP(Layer):
+    """A dense gated-SiLU MLP as a ``HybridBlock``'s feed-forward part:
+    ``(silu(g) * u) W_out`` with ``[g | u] = x W_in`` (``w_in`` ``(D, 2 F)``,
+    gate first; ``w_out`` ``(F, D)``), no biases.  Every token goes through
+    it: it routes nothing and has no counters."""
+
+    routes_tokens = False
+
+    def __init__(self, mlp_dim: int):
+        self.mlp_dim = int(mlp_dim)
+
+    def init(self, rng, in_shape):
+        d = in_shape[-1]
+        k_i, k_o = jax.random.split(rng)
+        return {"w_in": init_weight(k_i, (d, 2 * self.mlp_dim)),
+                "w_out": init_weight(k_o, (self.mlp_dim, d))}, \
+            tuple(in_shape)
+
+    def apply(self, params, x, *, compute_dtype=jnp.bfloat16, train=False,
+              rng=None):
+        return self.mix(params, x, compute_dtype=compute_dtype)[0]
+
+    def mix(self, params, x, *, compute_dtype=jnp.bfloat16, token_mask=None):
+        """``(y float32, None)``: a feed-forward part's contract
+        (``SparseMoE.mix``), without counters."""
+        with jax.named_scope("mlp"):
+            with jax.named_scope("mlp_in"):
+                h = _swiglu(_project(x, params["w_in"], None, compute_dtype))
+            with jax.named_scope("mlp_out"):
+                return _project(h.astype(compute_dtype), params["w_out"],
+                                None, compute_dtype), None
+
+
 class SparseMoE(Layer):
     """Sparse experts WITHOUT drops, told which experts it holds.
 
@@ -1319,9 +1398,11 @@ class HybridBlock(Layer):
     ffn(RMSNorm(h))``), or both in that order.  ``mixer`` is an attention
     layer (``GatedAttention``, a plain ``MultiHeadAttention``) or a linear
     recurrence (``KimiDeltaAttention``, ``Mamba2Mixer``); ``ffn`` a
-    ``SparseMoE``.  A block of ONE part is a layer of a stack whose layers
-    are a mixer OR a feed-forward part alone; without a mixer the block
-    keeps no per-request state (state kind ``none``).  The parts are kept
+    ``SparseMoE`` or a dense ``GatedMLP``; ``residual_multiplier`` scales
+    each part's output before it joins the stream.  A block of ONE part is
+    a layer of a stack whose layers are a mixer OR a feed-forward part
+    alone; without a mixer the block keeps no per-request state (state kind
+    ``none``).  The parts are kept
     as their configs (the spec stays JSON-serialisable) and rebuilt on use.
     What the cached step and the serving engine need to know of a block they
     ask the block (``state_kind``, ``routes_tokens``, ``wants_token_mask``,
@@ -1330,8 +1411,12 @@ class HybridBlock(Layer):
     #: ``core.quant.quantize_params`` finds matmul weights by
     #: ``TransformerBlock``'s names and would leave these as they are
     int8_weights = False
+    #: what each part's output is multiplied by before it joins the residual
+    #: stream (1: nothing is multiplied)
+    residual_multiplier: float = 1.0
 
-    def __init__(self, mixer=None, ffn=None, epsilon: float = 1e-5):
+    def __init__(self, mixer=None, ffn=None, epsilon: float = 1e-5,
+                 residual_multiplier: float = 1.0):
         def config(part):
             if part is None:
                 return None
@@ -1343,6 +1428,8 @@ class HybridBlock(Layer):
         self.mixer_config = config(mixer)
         self.ffn_config = config(ffn)
         self.epsilon = float(epsilon)
+        if residual_multiplier != 1.0:
+            self.residual_multiplier = float(residual_multiplier)
 
     def mixer(self) -> Optional[Layer]:
         return self.mixer_config and Layer.from_config(self.mixer_config)
@@ -1357,7 +1444,7 @@ class HybridBlock(Layer):
     @property
     def routes_tokens(self) -> bool:
         """The feed-forward part routes tokens to experts and returns
-        counters of it (``SparseMoE``)."""
+        counters of it (``SparseMoE``; a ``GatedMLP`` answers False)."""
         return bool(self.ffn_config) and self.ffn().routes_tokens
 
     @property
@@ -1404,18 +1491,40 @@ class HybridBlock(Layer):
         norm = RMSNorm(self.epsilon)
         mixer, ffn = self.mixer(), self.ffn()
         counters = None
+
+        def joins(x, h):
+            if self.residual_multiplier != 1.0:
+                h = h.astype(jnp.float32) * self.residual_multiplier
+            return x + h.astype(x.dtype)
+
         if mixer is not None:
             with jax.named_scope(mixer.scope):
                 h = norm.apply(params["norm1"], x,
                                compute_dtype=compute_dtype)
-                x = x + mix(mixer, params["mixer"], h).astype(x.dtype)
+                x = joins(x, mix(mixer, params["mixer"], h))
         if ffn is not None:
             h = norm.apply(params["norm2"], x, compute_dtype=compute_dtype)
             h, counters = ffn.mix(params["ffn"], h,
                                   compute_dtype=compute_dtype,
                                   token_mask=token_mask)
-            x = x + h.astype(x.dtype)
+            x = joins(x, h)
         return x, counters
+
+
+def params_of(layers: Sequence[Layer], params, i: int):
+    """The parameters layer ``i`` of a stack reads, for the training forward
+    and the cached step alike: its own, or, for a layer tied to another's
+    (``TiedHead.tied_to``), that layer's, which has to be an ``Embedding``:
+    an index that points elsewhere (a stack walked as a slice) is refused,
+    never read."""
+    j = getattr(layers[i], "tied_to", None)
+    if j is None:
+        return params[i]
+    if not (0 <= j < len(layers) and isinstance(layers[j], Embedding)):
+        raise ValueError(
+            f"layer {i} ({type(layers[i]).__name__}) is tied to layer {j}, "
+            f"which is not an Embedding of this stack")
+    return params[j]
 
 
 def scope_names(layers: Sequence[Layer]) -> List[str]:
@@ -1424,8 +1533,8 @@ def scope_names(layers: Sequence[Layer]) -> List[str]:
     them): ``embed`` for the token and position tables, ``block_<i>`` for
     the i-th ``TransformerBlock`` or ``HybridBlock``, and — in a stack that has blocks —
     ``final_norm`` for the normalization after the last one and ``lm_head``
-    for a closing ``Dense``.  Any other layer runs under its class name in
-    lower case."""
+    for a closing ``Dense`` or ``TiedHead``.  Any other layer runs under its
+    class name in lower case."""
     blocks = [i for i, l in enumerate(layers)
               if isinstance(l, (TransformerBlock, HybridBlock))]
     names = []
@@ -1437,7 +1546,8 @@ def scope_names(layers: Sequence[Layer]) -> List[str]:
         elif (blocks and i > blocks[-1]
               and isinstance(layer, (LayerNormalization, RMSNorm))):
             name = "final_norm"
-        elif blocks and i == len(layers) - 1 and isinstance(layer, Dense):
+        elif (blocks and i == len(layers) - 1
+              and isinstance(layer, (Dense, TiedHead))):
             name = "lm_head"
         else:
             name = type(layer).__name__.lower()

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .layers import Layer, scope_names
+from .layers import Layer, params_of, scope_names
 
 Params = Any
 
@@ -96,6 +96,9 @@ class Sequential:
             kw = ({"segment_ids": segment_ids}
                   if segment_ids is not None
                   and getattr(layer, "takes_segment_ids", False) else {})
+            # a layer tied to another's parameters (``TiedHead``) has none of
+            # its own and is handed that layer's
+            mine = params_of(self.layers, params, i)
             with jax.named_scope(scopes[i]):
                 if (train and stats_out is not None
                         and hasattr(layer, "apply_with_stats")):
@@ -103,7 +106,7 @@ class Sequential:
                         params[i], x, compute_dtype=cdtype, rng=sub)
                     stats_out[i] = new_stats
                 else:
-                    x = layer.apply(params[i], x, compute_dtype=cdtype,
+                    x = layer.apply(mine, x, compute_dtype=cdtype,
                                     train=train, rng=sub, **kw)
         return x
 

@@ -151,7 +151,10 @@ experts held here), ``moe_experts_touched`` (held experts that got one),
 ``moe_load_max`` (the fullest held expert's rows), each summed over expert
 layers and decode steps, ``moe_layer_steps`` the (layer, step) pairs summed
 over; ``recurrent_slots_cleared``, admissions whose slot's recurrent state
-was started from zero.  The benchmark's ``.hybrid`` readers read them.
+was started from zero; ``recurrent_state_bytes_moved``, live rows times a
+row's recurrent state (all recurrent layers), read and written, summed over
+decode steps.  The benchmark's ``.hybrid``, ``.nemotronh`` and ``.granite``
+readers read them.
 
 Training (``DistributedTrainer.train``, epoch and per-round paths):
 ``train.epoch`` (``epoch``; ``ce`` = ``kernel``/``xla``: whether the step's
@@ -176,7 +179,10 @@ unit, the ``kda_decode`` kernel in the decode step), ``kda_gate_out``, or
 ``ssd_decode`` kernel in the decode step), ``ssm_out`` (skip, gate, grouped
 norm, output projection); and, in a block that has a feed-forward part,
 ``moe`` ⊃ ``moe_route``, ``moe_dispatch``, ``moe_experts`` (the grouped
-matmuls), ``moe_combine``, ``moe_shared``;
+matmuls), ``moe_combine``, ``moe_shared`` (sparse experts), or ``mlp`` ⊃
+``mlp_in`` (gate and up, SiLU), ``mlp_out`` (a dense ``GatedMLP``);
+``lm_head`` holds the head's matmul whether the head has a kernel of its
+own (``Dense``) or reads the embedding table (``TiedHead``);
 ``loss``, ``optimizer``, ``commit`` (train step and the SPMD round);
 ``kv_write``, ``kv_gather``, ``sample`` (decode step; on the kernel path
 ``kv_gather`` holds only the row lengths' preparation).  Pallas kernels

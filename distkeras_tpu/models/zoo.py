@@ -11,7 +11,8 @@ from ..core import (Sequential, Dense, Conv2D, MaxPooling2D, Flatten, Reshape,
 from ..core.layers import (Embedding, PositionalEmbedding, TransformerBlock,
                            LayerNormalization, RMSNorm, GatedAttention,
                            KimiDeltaAttention, Mamba2Mixer,
-                           MultiHeadAttention, SparseMoE, HybridBlock)
+                           MultiHeadAttention, SparseMoE, GatedMLP,
+                           HybridBlock, TiedHead)
 
 
 def mnist_mlp(compute_dtype: str = "bfloat16") -> Sequential:
@@ -233,12 +234,87 @@ def _pattern_blocks(config: dict, held):
         yield HybridBlock(epsilon=eps, **parts[ch]())
 
 
+def _typed_blocks(config: dict):
+    """The blocks of a config of the ``granitemoehybrid`` kind: layer ``l``
+    is a mixer named by ``layer_types[l]`` (``mamba``: a Mamba-2 mixer from
+    the ``mamba_*`` keys; ``attention``: causal NoPE grouped-query attention
+    whose scores are multiplied by ``attention_multiplier``) THEN a dense
+    gated-SiLU MLP of ``shared_intermediate_size``, each part's output
+    times ``residual_multiplier`` before it joins the stream."""
+    types = list(config["layer_types"])
+    n = int(config["num_hidden_layers"])
+    if n > len(types):
+        raise ValueError(f"num_hidden_layers={n} but layer_types names "
+                         f"{len(types)} layers")
+    if int(config.get("num_local_experts", 0)):
+        raise ValueError("hybrid_lm builds a dense MLP in every block of a "
+                         "layer_types config (num_local_experts 0); routed "
+                         "experts beside the shared MLP are not written")
+    if config.get("position_embedding_type", "nope") != "nope":
+        raise ValueError(
+            "hybrid_lm builds NoPE attention (position_embedding_type "
+            f"'nope'); this config asks for "
+            f"{config['position_embedding_type']!r}")
+    for key in ("attention_bias", "mamba_proj_bias"):
+        if config.get(key, False):
+            raise ValueError(f"hybrid_lm builds bias-free projections; this "
+                             f"config sets {key}")
+    if not config.get("mamba_conv_bias", True):
+        raise ValueError("hybrid_lm builds the state-space convolution with "
+                         "its bias (mamba_conv_bias)")
+    if config.get("normalization_function", "rmsnorm") != "rmsnorm":
+        raise ValueError(
+            "hybrid_lm builds RMSNorm (normalization_function 'rmsnorm'); "
+            f"this config asks for {config['normalization_function']!r}")
+    if config.get("hidden_act", "silu") != "silu":
+        raise ValueError("hybrid_lm builds gated-SiLU MLPs and SiLU "
+                         "state-space layers (hidden_act 'silu'); this "
+                         f"config asks for {config['hidden_act']!r}")
+    d = int(config["hidden_size"])
+    m_heads, m_dim = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
+    if int(config["mamba_expand"]) * d != m_heads * m_dim:
+        raise ValueError(
+            f"mamba_expand x hidden_size = {int(config['mamba_expand']) * d} "
+            f"but mamba_n_heads x mamba_d_head = {m_heads * m_dim}")
+    eps = float(config["rms_norm_eps"])
+    heads = int(config["num_attention_heads"])
+    parts = {
+        "mamba": lambda: Mamba2Mixer(
+            m_heads, m_dim, int(config["mamba_d_state"]),
+            num_groups=int(config["mamba_n_groups"]),
+            conv_size=int(config["mamba_d_conv"]),
+            chunk_size=int(config["mamba_chunk_size"]), norm_eps=eps),
+        "attention": lambda: MultiHeadAttention(
+            heads, int(config.get("head_dim") or d // heads), causal=True,
+            use_bias=False, num_kv_heads=int(config["num_key_value_heads"]),
+            score_scale=config.get("attention_multiplier")),
+    }
+    for i, kind in enumerate(types[:n]):
+        if kind not in parts:
+            raise ValueError(f"layer_types[{i}] = {kind!r}: hybrid_lm builds "
+                             "'mamba' (Mamba-2) and 'attention'")
+        yield HybridBlock(
+            parts[kind](), GatedMLP(int(config["shared_intermediate_size"])),
+            epsilon=eps,
+            residual_multiplier=float(config.get("residual_multiplier", 1.0)))
+
+
 def hybrid_lm(config: dict, compute_dtype: str = "bfloat16",
               held=None) -> Sequential:
     """A decoder-only LM of hybrid blocks, built from the keys of a published
-    ``config.json``.  Two kinds of stack, told apart by the config's own
+    ``config.json``.  Three kinds of stack, told apart by the config's own
     keys:
 
+    - ``layer_types`` (``model_type`` ``granitemoehybrid``): every layer is
+      a mixer, ``mamba`` (``mamba_n_heads``, ``mamba_d_head``,
+      ``mamba_d_state``, ``mamba_n_groups``, ``mamba_d_conv``,
+      ``mamba_chunk_size``; ``mamba_expand``) or ``attention`` (NoPE
+      grouped-query, scores times ``attention_multiplier``), THEN a dense
+      gated-SiLU MLP (``shared_intermediate_size``); the embedding is
+      multiplied by ``embedding_multiplier``, each residual branch by
+      ``residual_multiplier``, the logits divided by ``logits_scaling``
+      (``_typed_blocks``, which also names what it refuses: routed experts,
+      a position signal, biases, another norm);
     - ``hybrid_override_pattern`` (``model_type`` ``nemotron_h``): every
       layer is ONE residual part, a Mamba-2 mixer (``mamba_num_heads``,
       ``mamba_head_dim``, ``ssm_state_size``, ``n_groups``, ``conv_kernel``,
@@ -257,24 +333,33 @@ def hybrid_lm(config: dict, compute_dtype: str = "bfloat16",
       ``moe_intermediate_size``, ``n_shared_experts``;
       ``first_k_dense_replace`` 0).
 
-    RMSNorm everywhere, no position signal, no biases, an untied head.
+    RMSNorm everywhere, no position signal, no biases.  The head is the
+    embedding table itself where the config says ``tie_word_embeddings``
+    (``TiedHead``: one table in the parameters), a ``Dense`` of its own
+    otherwise.
 
     ``held`` = (first, count): the experts whose weights live here, of the
     ``n_routed_experts`` the router scores — one chip's share of an
     expert-parallel deployment (default: all).  ``num_hidden_layers`` and
     ``vocab_size`` are taken as given, so a configuration cut in depth or to
     a slice of the vocabulary builds as it reads."""
-    if config.get("tie_word_embeddings", False):
-        raise ValueError("hybrid_lm builds an untied head; this config ties "
-                         "the embeddings")
-    d = int(config["hidden_size"])
-    if "hybrid_override_pattern" in config:
-        blocks = _pattern_blocks(config, held)
-        eps = float(config["layer_norm_epsilon"])
+    d, vocab = int(config["hidden_size"]), int(config["vocab_size"])
+    if "layer_types" in config:
+        blocks, eps_key = _typed_blocks(config), "rms_norm_eps"
+    elif "hybrid_override_pattern" in config:
+        blocks, eps_key = _pattern_blocks(config, held), "layer_norm_epsilon"
     else:
-        blocks = _interleaved_blocks(config, held)
-        eps = float(config["rms_norm_eps"])
-    layers = [Embedding(int(config["vocab_size"]), d), *blocks,
-              RMSNorm(eps), Dense(int(config["vocab_size"]), use_bias=False)]
+        blocks, eps_key = _interleaved_blocks(config, held), "rms_norm_eps"
+    divisor = float(config.get("logits_scaling", 1.0))
+    if config.get("tie_word_embeddings", False):
+        head = TiedHead(vocab, tied_to=0, divisor=divisor)
+    elif divisor != 1.0:
+        raise ValueError("hybrid_lm divides the logits (logits_scaling) of a "
+                         "tied head only; this config's head is untied")
+    else:
+        head = Dense(vocab, use_bias=False)
+    embed = Embedding(vocab, d, output_scale=float(
+        config.get("embedding_multiplier", 1.0)))
+    layers = [embed, *blocks, RMSNorm(float(config[eps_key])), head]
     return Sequential(layers, input_shape=(8,), compute_dtype=compute_dtype,
                       name="hybrid_lm")

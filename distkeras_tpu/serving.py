@@ -1137,6 +1137,15 @@ class ServingEngine:
                                            kv_dtype=kv_dtype)
             else:
                 self.d_caches = None
+        #: bytes of per-slot recurrent state ONE live row owns, over all
+        #: recurrent layers (0: the model keeps none): what a decode step
+        #: reads and writes for it
+        self._recurrent_row_bytes = sum(
+            leaf.nbytes // self.num_slots
+            for layer, c in zip(self.model.layers, self.caches)
+            if isinstance(layer, _dec._BLOCKS)
+            and layer.state_kind == "recurrent"
+            for leaf in jax.tree_util.tree_leaves(c))
         self._handles: List[Optional[RequestHandle]] = [None] * self.num_slots
         self._free: List[int] = list(range(self.num_slots - 1, -1, -1))
         self._positions = np.zeros((self.num_slots,), np.int32)
@@ -1365,6 +1374,9 @@ class ServingEngine:
             "moe_assignments_held": 0, "moe_experts_touched": 0,
             "moe_load_max": 0, "moe_layer_steps": 0,
             "recurrent_slots_cleared": 0,
+            # live rows x a row's recurrent state, read and written, summed
+            # over decode steps: the least bytes the state costs the steps
+            "recurrent_state_bytes_moved": 0,
             # the same two counts over PREFILL units (buckets, chunks and
             # final chunks of the paged pool), added when the unit's first
             # token is drained; moe_prefill_layer_units counts the (layer,
@@ -3608,6 +3620,8 @@ class ServingEngine:
                 self.params, *self._state_args())
             self._dev_tok = out
             self.stats["active_slot_steps"] += len(entries)
+            self.stats["recurrent_state_bytes_moved"] += (
+                2 * len(entries) * self._recurrent_row_bytes)
             # a model with expert layers hands back the tokens with its
             # counters behind them: still one fetch a step
             self._pending.append(("decode", packed[0] if packed else out,

@@ -453,6 +453,85 @@ def test_the_state_space_cells_programs_compile_for_v5e(
         assert mem.temp_size_in_bytes < 0.2e9
 
 
+# -- the tied head of the bursty chat cell reads the table where it lies -------
+
+@pytest.mark.parametrize("program", ["decode", "final_512"])
+def test_the_tied_head_copies_no_table_for_v5e(one_chip, monkeypatch,
+                                               program):
+    """``serve-chat-granite4hm``'s decode step over its 64 slots and a final
+    512-token prefill unit (the one prefill program that needs logits), as
+    ``ServingEngine`` builds them, at the published widths and the WHOLE
+    vocabulary, the first six layers of forty (five Mamba-2, one attention:
+    what the table meets does not grow with depth, the compile does): the
+    embedding looks rows up in the ``(100352, 2048)`` table and the head
+    contracts the same array over its 2,048, and NO copy of it, transposed or
+    not, is in the program (411 MB a step if there were); ONE table among
+    the arguments; the kernels are there (``ssd_decode`` at one group,
+    ``paged_decode`` at 4 query heads a KV head and a score scale of
+    1/64)."""
+    from benchmarks.lib import manifest as mf, program_granite
+    from distkeras_tpu.core import decode as dec
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = mf.load_json(os.path.join(mf.BENCH_DIR, "configs",
+                                    "granite-4.0-h-micro.json"))
+    eng = cfg["deployment"]["engine"]
+    model = program_granite.build_model(dict(cfg, num_hidden_layers=6))
+    on_chip = lambda a, dt=None: jax.ShapeDtypeStruct(
+        a.shape, dt or a.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a, jnp.bfloat16),
+        jax.eval_shape(lambda k: model.init(k, (8,)), jax.random.PRNGKey(0)))
+    v, d = 100352, 2048
+    assert [a.shape for a in jax.tree_util.tree_leaves(params)
+            if a.shape in ((v, d), (d, v))] == [(v, d)]
+    pool = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: dec.init_paged_arena(model, eng["kv_blocks"],
+                                     eng["block_size"],
+                                     num_slots=eng["num_slots"])))
+    page, view, slots = eng["block_size"], eng["max_len"], eng["num_slots"]
+    S = lambda shp, dt=jnp.int32: jax.ShapeDtypeStruct(shp, dt,
+                                                       sharding=one_chip)
+    tables = view // page
+
+    def decode(params, pool, bt, tok, pos, active):
+        logits, pool = dec.decode_step(
+            model, params, pool, tok, pos,
+            paged=dec.PagedView(bt, page, view),
+            rows=dec.RowView(live=active))
+        return jnp.argmax(logits, -1), pool
+
+    def final(params, pool, toks, offset, p_len, row_bt, slot, last):
+        pv = dec.PagedView(row_bt, page, view, floor=offset, ceil=p_len,
+                           qcap=p_len - 1)
+        logits, pool = dec._forward(
+            model, params, pool, toks, offset, paged=pv,
+            rows=dec.RowView(slots=jnp.reshape(slot, (1,))))
+        return jnp.argmax(logits[0, last]), pool
+
+    if program == "decode":
+        compiled = jax.jit(decode, donate_argnums=(1,)).lower(
+            params, pool, S((slots, tables)), S((slots,)), S((slots,)),
+            S((slots,), jnp.bool_)).compile()
+        kernels = 6                        # 5 ssd_decode, 1 paged_decode
+    else:
+        compiled = jax.jit(final, donate_argnums=(1,)).lower(
+            params, pool, S((1, 512)), S((1,)), S((1,)), S((1, tables)),
+            S(()), S(())).compile()
+        kernels = 0                        # the chunked scan is XLA's
+    text = compiled.as_text()
+    assert text.count(KERNEL) == kernels
+    for scope in ("ssm/ssm_core", "mlp/mlp_in", "mlp/mlp_out",
+                  "attn/attn_core", "lm_head"):
+        assert scope in text, scope
+    assert not _copies_of(text, (v, d), (d, v))
+    # nor a transposed table by another road
+    assert not [line for line in text.splitlines()
+                if " transpose(" in line
+                and f"[{d},{v}]" in line.split(" transpose(")[0]]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.0e9
+
+
 # -- kernels inside shard_map on the 2x2 mesh --------------------------------
 
 @pytest.fixture(scope="module")

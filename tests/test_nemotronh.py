@@ -640,10 +640,29 @@ def test_hybrid_lm_builds_the_published_pattern_and_the_cut():
     (dict(use_conv_bias=False), "use_conv_bias"),
     (dict(n_group=2), "expert groups"),
     (dict(norm_topk_prob=False), "norm_topk_prob"),
-    (dict(tie_word_embeddings=True), "untied"),
+    (dict(logits_scaling=8), "untied"),
 ], ids=lambda x: next(iter(x)) if isinstance(x, dict) else x)
 def test_what_the_pattern_builder_does_not_build_it_refuses_by_name(change,
                                                                     word):
     cfg = dict(mf.resolve_sizes(mf.load_json(CONFIG), True), **change)
     with pytest.raises(ValueError, match=word):
         hybrid_lm(cfg)
+
+
+def test_a_tied_pattern_config_is_built_with_one_table():
+    """``tie_word_embeddings`` was refused until the head could read the
+    embedding's table (``TiedHead``): the same pattern, tied, now holds no
+    head of its own, and its cached step is its full forward."""
+    from distkeras_tpu.core.layers import TiedHead
+    cfg = dict(mf.resolve_sizes(mf.load_json(CONFIG), True),
+               tie_word_embeddings=True)
+    model = hybrid_lm(cfg, compute_dtype="float32")
+    params = model.init(jax.random.PRNGKey(0), (8,))
+    assert isinstance(model.layers[-1], TiedHead) and params[-1] == {}
+    toks = jnp.asarray(prompts(3, [20])[0])[None]
+    want = model.apply(params, toks)
+    caches = dec.init_cache(model, 1, 32)
+    got, caches = dec._forward(model, params, caches, toks[:, :12], 0)
+    np.testing.assert_allclose(got, want[:, :12], atol=TOL)
+    step, _ = dec.decode_step(model, params, caches, toks[:, 12], 12)
+    np.testing.assert_allclose(step, want[:, 12], atol=TOL)
